@@ -40,8 +40,8 @@ the *runtime* counterpart, surfaced as ``Compile_WarmMiss_Count``
 
 LiveQuery kernels are deliberately NOT manifest entries: their query
 text is user input, so their trace surface is open by design. They warm
-through the shared persistent compilation cache instead
-(``serve/livequery.py`` ``KernelService(compile_conf=...)``).
+through the persistent compilation cache every ``FlowProcessor`` arms
+(``compile/aotcache.py``) instead.
 """
 
 from __future__ import annotations
@@ -98,8 +98,13 @@ def flow_config_hash(gui: dict) -> str:
 def lowering_digest(fn, avals, donate: Tuple[int, ...] = ()) -> str:
     """sha256 of the entry's lowered StableHLO text — the ground truth
     a shipped manifest is checked against (DX603). Tracing only: no
-    compile, no device execution."""
-    lowered = jax.jit(fn, donate_argnums=tuple(donate)).lower(*avals)
+    compile, no device execution. Lowered FOR the TPU, the platform
+    jobs run on, whatever platform this (design-time, usually
+    CPU-only) process has: a Pallas UDF lowers through Mosaic there
+    and has no CPU lowering at all."""
+    lowered = jax.jit(fn, donate_argnums=tuple(donate)).trace(*avals).lower(
+        lowering_platforms=("tpu",)
+    )
     return hashlib.sha256(lowered.as_text().encode()).hexdigest()
 
 

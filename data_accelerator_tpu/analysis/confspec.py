@@ -414,8 +414,6 @@ CONF_REGISTRY: Tuple[ConfKey, ...] = (
     # -- compile plane -----------------------------------------------------
     _K("compile.aot", "bool", "true", "compile", source="manual",
        description="ahead-of-time compile the flow step at host start"),
-    _K("compile.cachedir", "path", None, "compile", source="generation",
-       description="AOT executable cache directory (S650 embed)"),
     _K("compile.cacheurl", "url", None, "compile", source="generation",
        description="shared AOT cache object-store URL (S650 embed)"),
     _K("compile.manifest", "path", None, "compile", source="generation",

@@ -9,10 +9,9 @@ byte model against ``jax.eval_shape`` over the production lowering (and
 ``bench.py`` against the arrays a real batch materializes), so the model
 cannot silently drift from what the compiler actually builds.
 
-Documented in ANALYSIS.md ("Scaling model"): the ICI terms are the
-model VERDICT Weak #2 demanded — expected bytes over the chip
-interconnect per batch as a function of group cardinality and join
-fan-out, for the v5e-16 extrapolation.
+Documented in ANALYSIS.md ("Scaling model"): the ICI terms are
+expected bytes over the chip interconnect per batch as a function of
+group cardinality and join fan-out, for the v5e-16 extrapolation.
 
 Column widths (core/schema.py device encoding, x64 off):
 long/string/timestamp -> int32 (4 B), double -> float32 (4 B),

@@ -196,8 +196,8 @@ CODES: Dict[str, tuple] = {
               "expect full-capacity D2H under the mesh, or keep the flow single-chip until the sharded sized-transfer path exists"),
     "DX790": (SEV_ERROR, "mesh lowering failed or disagrees with the sharding model: the partition plan's closed-form collective bytes do not match what the SPMD partitioner emitted",
               "fix the statement per the lowering error, or regenerate after engine changes — the byte model must match the lowering exactly"),
-    "DX791": (SEV_WARNING, "mesh analysis unavailable or unvalidated: no concrete input schema, or fewer than two devices to lower the partition plan against",
-              "inline the input schema JSON; run under a multi-device backend (the CLI virtualizes CPU devices) to validate the model"),
+    "DX791": (SEV_WARNING, "mesh analysis unavailable or unvalidated: no concrete input schema, fewer than two devices to lower the partition plan against, or a Pallas-kernel stage on a backend that is not a TPU",
+              "inline the input schema JSON; run under a multi-device backend (the CLI virtualizes CPU devices) to validate the model; validate Pallas-kernel stages on a TPU host"),
     # -- pass 9: compile surface (analysis/compilecheck.py, the
     #    --compile tier: enumerate every jit entry point, lower each
     #    over eval_shape avals, prove the signature set finite and

@@ -531,6 +531,20 @@ def _cross_check(
             return _view.fn(t, jnp.asarray(0, jnp.int32),
                             jnp.asarray(0, jnp.int32))
 
+        if p is not None and p.unshardable_udfs \
+                and jax.default_backend() != "tpu":
+            # Mosaic compiles for the TPU only, and an interpreter
+            # build of the kernel is another program to the SPMD
+            # partitioner: this stage is checked where jobs run, or
+            # not at all — never against a stand-in
+            diags.append(make(
+                "DX791", view.name,
+                f"stage body not validated: its Pallas kernel UDF "
+                f"({'/'.join(p.unshardable_udfs)}) compiles on a TPU "
+                f"backend only, this process has "
+                f"{jax.default_backend()!r}",
+            ))
+            continue
         try:
             census = _lower_and_census(body, avals, (in_sh,), out_sh)
         except Exception as e:  # noqa: BLE001 — a lowering blowup is a finding

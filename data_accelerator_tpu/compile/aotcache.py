@@ -1,20 +1,23 @@
 """Persistent XLA compilation cache, optionally shared via objstore://.
 
-The runtime half of the zero-cold-start path (ISSUE: compile manifest +
-AOT warm): ``FlowProcessor._aot_warm`` compiles every manifest entry at
-init; with this cache enabled the compiles inside that warm resolve
-from serialized executables on disk — and, when a shared object store
-is configured, newly compiled entries are pushed back so the NEXT start
-(restart, preemption recovery, scale-out replica, a LiveQuery kernel
-pool on another box) deserializes instead of compiling.
+The runtime half of the zero-cold-start path: ``FlowProcessor._aot_warm``
+compiles every manifest entry at init; with this cache the compiles
+inside that warm (and any later first-dispatch compile) resolve from
+serialized executables on disk — and, when a shared object store is
+configured, newly compiled entries are pushed back so the NEXT start
+(restart, preemption recovery, scale-out replica) deserializes instead
+of compiling.
 
 Layering:
 
-- **local dir** (``datax.job.process.compile.cachedir``): jax's own
-  persistent compilation cache (``jax_compilation_cache_dir``), tuned
-  so every entry persists (no min-size/min-compile-time gating — a
-  restart should never recompile something this process already paid
-  for).
+- **local dir**: jax's own persistent compilation cache, at the ONE
+  directory ``resolve_cache_dir`` names — ``JAX_COMPILATION_CACHE_DIR``
+  when the operator set it (jax reads that variable itself; nothing
+  here overrides it), else ``<checkout>/.jax_cache``. The directory is
+  part of jax's cache key, so it must never move between starts: no
+  conf key, temp name, pid or timestamp may place it. Tuned so every
+  entry persists (no min-size/min-compile-time gating — a restart
+  should never recompile something this process already paid for).
 - **shared store** (``datax.job.process.compile.cacheurl``, an
   ``objstore://host:port/bucket/prefix`` URL): ``enable()`` pulls
   entries absent locally before arming the cache; ``push()`` uploads
@@ -23,42 +26,70 @@ Layering:
   computation fingerprint), so a stale entry can never be *loaded*
   wrongly, only ignored.
 
-File counting is at jax-cache-entry granularity (the ``*-cache``
-files; ``*-atime`` bookkeeping files are ignored), which is what the
-``Compile_Cache_{Hit,Miss}_Count`` metrics report.
+``Compile_Cache_{Hit,Miss}_Count`` report jax's own cache events
+(``/jax/compilation_cache/cache_hits`` / ``cache_misses``), counted
+process-wide by one ``jax.monitoring`` listener.
 """
 
 from __future__ import annotations
 
 import logging
 import os
+import threading
 from typing import List, Optional, Set, Tuple
 
 logger = logging.getLogger(__name__)
 
-
-def _reset_jax_cache() -> None:
-    """Drop jax's memoized cache object so a config change made after
-    earlier compiles (the normal case: the engine jits plenty before a
-    flow's cache conf is read) actually takes effect."""
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — private API; degrade to no cache
-        logger.warning("jax compilation-cache reset unavailable")
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
-def compile_conf_for(cache_dir: str,
-                     cache_url: Optional[str] = None) -> dict:
-    """The ``datax.job.process.compile.*`` conf keys that arm this
-    cache for a kernel pool — the one way LiveQuery surfaces (REST
-    kernel pool, serving-plane warm cache, one-box server) build their
-    shared compile conf, so the layers can't drift on key names."""
-    conf = {"datax.job.process.compile.cachedir": cache_dir}
-    if cache_url:
-        conf["datax.job.process.compile.cacheurl"] = cache_url
-    return conf
+def resolve_cache_dir() -> str:
+    """THE compile-cache directory of this process: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, that; else
+    ``<checkout>/.jax_cache`` (git-ignored)."""
+    return os.environ.get(CACHE_DIR_ENV) or os.path.join(
+        _CHECKOUT, ".jax_cache"
+    )
+
+
+class _CacheEvents:
+    """Monotonic process-wide totals of jax's persistent-cache events.
+    jax's listener registry is itself process-global and its events
+    carry no attribution, so one listener counts for everyone and each
+    ``PersistentCompileCache`` reports deltas against its own marks."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._registered = False
+        self.hits = 0
+        self.misses = 0
+
+    def _on_event(self, event: str, **_kwargs) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            with self._lock:
+                self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            with self._lock:
+                self.misses += 1
+
+    def ensure_registered(self) -> None:
+        with self._lock:
+            if self._registered:
+                return
+            self._registered = True
+        import jax.monitoring
+
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def totals(self) -> Tuple[int, int]:
+        with self._lock:
+            return self.hits, self.misses
+
+
+_EVENTS = _CacheEvents()
 
 
 def _parse_objstore_url(url: str) -> Tuple[str, str, str]:
@@ -77,15 +108,10 @@ def _parse_objstore_url(url: str) -> Tuple[str, str, str]:
 
 
 class PersistentCompileCache:
-    """One flow's compile-cache session: local jax cache dir + optional
-    shared objstore layer."""
+    """One flow's compile-cache session: jax's cache at the resolved
+    local dir + the optional shared objstore layer."""
 
-    def __init__(
-        self, cache_dir: Optional[str] = None,
-        cache_url: Optional[str] = None,
-    ):
-        if not cache_dir and not cache_url:
-            raise ValueError("cache_dir or cache_url required")
+    def __init__(self, cache_url: Optional[str] = None):
         self.url = cache_url
         self._client = None
         self._prefix = ""
@@ -96,19 +122,9 @@ class PersistentCompileCache:
             token = os.environ.get("DATAX_OBJSTORE_TOKEN")
             self._client = ObjectStoreClient(endpoint, bucket, token=token)
             self._prefix = prefix
-        if not cache_dir:
-            # deterministic local layer per shared prefix so co-located
-            # flows sharing a cacheurl also share the local dir
-            import hashlib
-            import tempfile
-
-            cache_dir = os.path.join(
-                tempfile.gettempdir(), "dxtpu-compile-cache",
-                hashlib.sha256(cache_url.encode()).hexdigest()[:16],
-            )
-        self.dir = cache_dir
+        self.dir = resolve_cache_dir()
         self._baseline: Set[str] = set()
-        self._prev_config: Optional[tuple] = None
+        self._seen = (0, 0)
 
     # -- local entries ---------------------------------------------------
     def _entries(self) -> List[str]:
@@ -120,42 +136,38 @@ class PersistentCompileCache:
         except OSError:
             return []
 
-    def file_count(self) -> int:
-        return len(self._entries())
-
     # -- lifecycle -------------------------------------------------------
     def enable(self) -> None:
         """Pull shared entries, then arm jax's persistent cache at the
-        local dir. Remembers the pre-existing config so ``disable()``
-        can restore it (tests; production leaves it armed so later
-        re-traces also persist)."""
+        resolved dir. Stays armed for the life of the process so later
+        re-traces also persist."""
         os.makedirs(self.dir, exist_ok=True)
         self.pull()
         import jax
+        from jax._src import compilation_cache
 
-        self._prev_config = (
-            jax.config.jax_compilation_cache_dir,
-            jax.config.jax_persistent_cache_min_entry_size_bytes,
-            jax.config.jax_persistent_cache_min_compile_time_secs,
-        )
-        jax.config.update("jax_compilation_cache_dir", self.dir)
+        if jax.config.jax_compilation_cache_dir != self.dir:
+            # JAX_COMPILATION_CACHE_DIR was not in jax's environment at
+            # import (jax reads the variable itself, and then this
+            # branch is skipped). jax memoizes "no cache" at its first
+            # compile; drop that so a dir set after earlier jits takes
+            # effect.
+            jax.config.update("jax_compilation_cache_dir", self.dir)
+            compilation_cache.reset_cache()
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _reset_jax_cache()
+        _EVENTS.ensure_registered()
+        self._seen = _EVENTS.totals()
         self._baseline = set(self._entries())
 
-    def disable(self) -> None:
-        """Restore the jax cache config captured by ``enable()``."""
-        if self._prev_config is None:
-            return
-        import jax
-
-        d, s, t = self._prev_config
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", s)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", t)
-        _reset_jax_cache()
-        self._prev_config = None
+    def take_counts(self) -> Tuple[int, int]:
+        """(hits, misses) jax's persistent cache recorded in this
+        process since ``enable()`` or the previous take — a hit is a
+        compile served from disk, a miss one compiled and written."""
+        hits, misses = _EVENTS.totals()
+        seen_h, seen_m = self._seen
+        self._seen = (hits, misses)
+        return hits - seen_h, misses - seen_m
 
     # -- shared layer ----------------------------------------------------
     def _key(self, fn: str) -> str:
@@ -197,17 +209,17 @@ class PersistentCompileCache:
         return n
 
     def push(self) -> int:
-        """Upload entries created since ``enable()`` (the compiles this
-        process actually paid for) and return how many there were —
-        the ``Compile_Cache_Miss_Count`` number. With no shared store
-        the new-entry count still reports (local misses)."""
+        """Upload entries created since ``enable()`` or the last push
+        (the compiles this process actually paid for); returns how
+        many were uploaded."""
+        if self._client is None:
+            return 0
         new = [fn for fn in self._entries() if fn not in self._baseline]
-        if self._client is not None:
-            for fn in new:
-                try:
-                    with open(os.path.join(self.dir, fn), "rb") as f:
-                        self._client.put(self._key(fn), f.read())
-                except Exception as e:  # noqa: BLE001 — best-effort
-                    logger.warning("compile-cache push %s failed: %s", fn, e)
+        for fn in new:
+            try:
+                with open(os.path.join(self.dir, fn), "rb") as f:
+                    self._client.put(self._key(fn), f.read())
+            except Exception as e:  # noqa: BLE001 — best-effort
+                logger.warning("compile-cache push %s failed: %s", fn, e)
         self._baseline |= set(new)
         return len(new)

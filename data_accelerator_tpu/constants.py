@@ -155,11 +155,10 @@ class MetricName:
         r"Pipeline_Stall_Ms",
         # sized output transfer (runtime/processor.py PendingBatch):
         # D2H bytes per batch, valid/transferred row ratio, and the
-        # async-copy-capability / sized-cap-overflow / slot-contention
-        # fallback counters
+        # sized-cap-overflow / slot-contention fallback counters
         r"Transfer_D2HBytes",
         r"Transfer_Efficiency",
-        r"Transfer_(AsyncCopyFallback|Overflow|SlotContended)_Count",
+        r"Transfer_(Overflow|SlotContended)_Count",
         # buffer sanitizer (runtime/sanitizer.py, armed via
         # process.debug.buffersanitizer): buffers guarded per collect,
         # and use-after-release detections — runtime DX805, the dynamic

@@ -121,11 +121,18 @@ _HLO_DTYPE_BYTES: Dict[str, int] = {
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
 }
 
+# one HLO instruction: `[ROOT] %name = <shape> <op>(operands...`. The
+# shape may be a tuple, and on the TPU backend carries tiling in its
+# layout (`s32[64]{0:T(128)}`, `pred[64]{0:T(512)(128)(4,1)}`) and
+# `/*index=5*/` markers inside long tuples — so it is matched lazily
+# up to the op token and cleaned afterwards, not spelled out
 _COLLECTIVE_RE = re.compile(
-    r"=\s*((?:\()?[a-z0-9\[\],{}\s]*?)\s*"
-    r"(all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
-    r"(?:-start|-done)?\("
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(?P<shape>.+?)\s+"
+    r"(?P<op>all-reduce|all-gather|all-to-all|collective-permute"
+    r"|reduce-scatter)(?P<phase>-start|-done)?\(",
+    re.MULTILINE,
 )
+_LAYOUT_RE = re.compile(r"\{[^{}]*\}|/\*.*?\*/")
 _SHAPE_RE = re.compile(r"([a-z]+[0-9]*)\[([0-9,]*)\]")
 
 
@@ -165,23 +172,25 @@ def collective_summary(compiled_hlo_text: str) -> MeshCollectives:
     """Parse a compiled module's HLO text into a collective census.
 
     Counts every all-reduce / all-gather / all-to-all /
-    collective-permute / reduce-scatter instruction (async start/done
-    pairs count once, on the start) and sums each instruction's result
-    shape bytes."""
+    collective-permute / reduce-scatter instruction and sums each
+    instruction's result shape bytes (every element of a tuple result:
+    XLA combines same-typed collectives into one tuple-shaped op).
+    Async pairs count once, on the ``-done``: its shape is the result
+    alone, where a ``-start`` returns operands, results and context
+    scalars together."""
     ops: Dict[str, Tuple[int, int]] = {}
     for m in _COLLECTIVE_RE.finditer(compiled_hlo_text):
-        shapes, op = m.group(1), m.group(2)
-        # async form: -done repeats the -start result; count the start
-        if m.group(0).rstrip("(").endswith("-done"):
+        if m.group("phase") == "-start":
             continue
         total = 0
-        for sm in _SHAPE_RE.finditer(shapes):
+        for sm in _SHAPE_RE.finditer(_LAYOUT_RE.sub("", m.group("shape"))):
             dt, dims = sm.group(1), sm.group(2)
             n_el = 1
             for d in dims.split(","):
                 if d:
                     n_el *= int(d)
             total += n_el * _HLO_DTYPE_BYTES.get(dt, 4)
+        op = m.group("op")
         c, b = ops.get(op, (0, 0))
         ops[op] = (c + 1, b + total)
     return MeshCollectives(ops)

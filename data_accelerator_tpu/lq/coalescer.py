@@ -121,7 +121,7 @@ class DispatchCoalescer:
         BEFORE this (``SessionManager.admit_execute``) — a rejected
         call never reaches a queue, so it can never consume a
         dispatch."""
-        sig = signature_for(session, query, self.cache.compile_conf)
+        sig = signature_for(session, query)
         call = PendingExec(
             session.id, session.tenant, query, max_rows,
             list(session.sample_rows), self.now(),

@@ -93,7 +93,6 @@ class LiveQueryService:
         self,
         conf=None,
         session_manager: Optional[SessionManager] = None,
-        compile_conf: Optional[Dict[str, str]] = None,
         store=None,
         now_fn=time.time,
         ticker: Optional[bool] = None,
@@ -114,7 +113,6 @@ class LiveQueryService:
         )
         self.cache = WarmKernelCache(
             budget_bytes=int(budget_mb) * 1024 * 1024 if budget_mb else None,
-            compile_conf=compile_conf,
             now_fn=now_fn,
         )
         self.coalescer = DispatchCoalescer(

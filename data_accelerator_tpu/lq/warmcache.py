@@ -70,7 +70,6 @@ def flow_hash_for(
     refdata_conf: Optional[Dict[str, str]] = None,
     udfs: Optional[dict] = None,
     debug: object = None,
-    compile_conf: Optional[Dict[str, str]] = None,
 ) -> str:
     """Digest of every session field that shapes the compiled trace."""
     h = hashlib.sha1()
@@ -83,14 +82,11 @@ def flow_hash_for(
         debug if isinstance(debug, (bool, type(None))) else sorted(
             dict(debug or {}).items()
         ),
-        sorted((compile_conf or {}).items()),
     ], default=str).encode())
     return h.hexdigest()[:16]
 
 
-def signature_for(session, query: str,
-                  compile_conf: Optional[Dict[str, str]] = None
-                  ) -> CompileSignature:
+def signature_for(session, query: str) -> CompileSignature:
     """The compile signature of one execute: session flow fields +
     the pow2 bucket its row count pads into + the normalized query."""
     from ..serve.livequery import _capacity_for
@@ -99,7 +95,6 @@ def signature_for(session, query: str,
         flow_hash=flow_hash_for(
             session.flow_name, session.schema_json, session.normalization,
             session.refdata_conf, session.udfs, session.debug,
-            compile_conf,
         ),
         row_bucket=_capacity_for(len(session.sample_rows)),
         query_shape=_normalize_query(query),
@@ -160,7 +155,6 @@ class WarmKernelCache:
     def __init__(
         self,
         budget_bytes: Optional[int] = None,
-        compile_conf: Optional[Dict[str, str]] = None,
         now_fn: Callable[[], float] = time.time,
     ):
         if budget_bytes is None:
@@ -168,7 +162,6 @@ class WarmKernelCache:
 
             budget_bytes = warm_kernel_cache_budget_bytes()
         self.budget_bytes = int(budget_bytes)
-        self.compile_conf = dict(compile_conf or {})
         self.now = now_fn
         self._entries: Dict[str, WarmKernel] = {}
         self._lock = threading.RLock()
@@ -182,8 +175,7 @@ class WarmKernelCache:
         """The signature's resident kernel, building one from the
         session's flow fields on miss. A miss for a signature seen
         before is a RE-WARM: the rebuild goes through the persistent
-        compile cache (``compile_conf``), so it deserializes instead of
-        re-tracing."""
+        compile cache, so it deserializes instead of re-tracing."""
         from ..serve.livequery import Kernel
 
         with self._lock:
@@ -202,7 +194,6 @@ class WarmKernelCache:
                     udfs=session.udfs,
                     refdata_conf=dict(session.refdata_conf or {}),
                     debug=session.debug,
-                    compile_conf=dict(self.compile_conf),
                 )
                 entry = WarmKernel(signature, kernel)
                 self._entries[signature.key] = entry

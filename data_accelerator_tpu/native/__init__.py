@@ -1,15 +1,19 @@
 from .decoder import (
     KAFKA_CODEC_NAMES,
+    NativeBuildError,
     NativeDecoder,
     PackedBufferPool,
+    load_library,
     native_available,
     native_crc32c,
 )
 
 __all__ = [
     "KAFKA_CODEC_NAMES",
+    "NativeBuildError",
     "NativeDecoder",
     "PackedBufferPool",
+    "load_library",
     "native_available",
     "native_crc32c",
 ]

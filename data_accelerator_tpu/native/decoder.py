@@ -4,7 +4,12 @@ The C++ library (``native/decoder.cpp``) replaces the role Spark's
 executor-side ``from_json`` plays in the reference
 (CommonProcessorFactory.scala:90-103): every event's JSON parse happens
 in native code straight into numpy buffers. The shared library builds
-lazily with g++ on first use and is cached next to the source.
+with g++ on first use into ``native/.build/`` under a name that is a
+hash of ``decoder.cpp`` and the compiler flags, so the library loaded
+is always the one built from the source in this checkout — a stale or
+foreign ``.so`` has a different name and is never looked at. A build
+that fails raises :class:`NativeBuildError` with the compiler's stderr;
+nothing on the served path decodes in Python instead.
 
 Three decode surfaces:
 
@@ -38,6 +43,7 @@ loop and sinks).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -55,10 +61,15 @@ _SRC = os.path.join(
     "native",
     "decoder.cpp",
 )
-_LIB_PATH = os.path.join(os.path.dirname(_SRC), "libdxdecoder.so")
+_BUILD_DIR = os.path.join(os.path.dirname(_SRC), ".build")
+_CXX = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread"]
 _build_lock = threading.Lock()
 _lib = None
 _lib_error: Optional[str] = None
+
+
+class NativeBuildError(RuntimeError):
+    """The native decoder could not be built from ``decoder.cpp``."""
 
 _CTYPE_NAME = {
     ColType.LONG: "long",
@@ -88,34 +99,56 @@ _KSTAT_OVERFLOW = 4
 _KSTAT_CODEC = 5
 
 
-def _build_library() -> Optional[str]:
-    if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(
-        _SRC
-    ):
-        return _LIB_PATH
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-        "-o", _LIB_PATH, _SRC,
-    ]
+def _build_library() -> str:
+    """Path of the library built from THIS checkout's ``decoder.cpp``
+    with ``_CXX``: ``native/.build/libdxdecoder-<hash>.so``. Compiles
+    it when absent (to a temp name, renamed into place, so concurrent
+    processes never load a half-written file)."""
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (subprocess.SubprocessError, FileNotFoundError) as e:
-        logger.warning("native decoder build failed: %s", e)
-        return None
-    return _LIB_PATH
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(
+                f.read() + "\0".join(_CXX).encode()
+            ).hexdigest()[:16]
+    except OSError as e:
+        raise NativeBuildError(f"native decoder source unreadable: {e}")
+    path = os.path.join(_BUILD_DIR, f"libdxdecoder-{digest}.so")
+    if os.path.exists(path):
+        return path
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{path}.{os.getpid()}.tmp"
+    try:
+        done = subprocess.run(
+            _CXX + ["-o", tmp, _SRC], capture_output=True, timeout=300,
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"native decoder build did not run: {e}")
+    if done.returncode != 0:
+        raise NativeBuildError(
+            f"native decoder build failed ({' '.join(_CXX)} {_SRC}, "
+            f"exit {done.returncode}):\n"
+            + done.stderr.decode("utf-8", "replace")
+        )
+    os.replace(tmp, path)
+    return path
 
 
-def _load():
+def load_library():
+    """The loaded library; raises :class:`NativeBuildError` (with the
+    compiler's stderr) when it cannot be built. The outcome is cached
+    for the process either way."""
     global _lib, _lib_error
-    if _lib is not None or _lib_error is not None:
+    if _lib is not None:
         return _lib
     with _build_lock:
-        if _lib is not None or _lib_error is not None:
+        if _lib is not None:
             return _lib
-        path = _build_library()
-        if path is None:
-            _lib_error = "build failed"
-            return None
+        if _lib_error is not None:
+            raise NativeBuildError(_lib_error)
+        try:
+            path = _build_library()
+        except NativeBuildError as e:
+            _lib_error = str(e)
+            raise
         lib = ctypes.CDLL(path)
         lib.dx_decoder_create.restype = ctypes.c_void_p
         lib.dx_decoder_create.argtypes = [ctypes.c_char_p]
@@ -165,16 +198,23 @@ def _load():
 
 
 def native_available() -> bool:
-    return _load() is not None
+    """Whether the library builds here — for callers to whom native
+    code is optional (test skips, the calibration probe, the wire
+    client's checksum). The served ingest path does not ask: it loads
+    the library and lets :class:`NativeBuildError` propagate."""
+    try:
+        load_library()
+    except NativeBuildError:
+        return False
+    return True
 
 
 def native_crc32c(data: bytes) -> Optional[int]:
     """CRC-32C via the native library (None when unavailable) — shared
     with the wire client so checksum math exists exactly once."""
-    lib = _load()
-    if lib is None:
+    if not native_available():
         return None
-    return int(lib.dx_crc32c(data, len(data)))
+    return int(load_library().dx_crc32c(data, len(data)))
 
 
 def _decode_threads(conf_threads: Optional[int] = None) -> int:
@@ -262,10 +302,7 @@ class NativeDecoder:
         dictionary: StringDictionary,
         threads: Optional[int] = None,
     ):
-        lib = _load()
-        if lib is None:
-            raise RuntimeError("native decoder unavailable (g++ build failed)")
-        self._lib = lib
+        self._lib = load_library()
         self.schema = schema
         self.dictionary = dictionary
         # conf'd shard count (datax.job.process.ingest.decoderthreads);
@@ -274,7 +311,7 @@ class NativeDecoder:
         desc = "".join(
             f"{c.name}\t{_CTYPE_NAME[c.ctype]}\n" for c in schema.columns
         )
-        self._d = lib.dx_decoder_create(desc.encode("utf-8"))
+        self._d = self._lib.dx_decoder_create(desc.encode("utf-8"))
         self._cols = list(schema.columns)
         self._synced = 0
         self.last_bad_timestamps = 0

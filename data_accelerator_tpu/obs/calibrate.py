@@ -20,8 +20,8 @@ more run-to-run stable than a mean):
 - **flops GFLOP/s**: one square f32 matmul (2*n^3 FLOPs).
 - **dispatch overhead µs**: a jitted scalar add, timed per blocking
   call — the fixed cost of getting ANY step onto the device and
-  learning it finished (on a split-host tunnel this includes the RTT,
-  which is exactly what a host-observed stage time contains too).
+  learning it finished (exactly what a host-observed stage time
+  contains too).
 - **d2h GB/s**: ``jax.device_get`` of the probe array.
 - **ici GB/s**: a psum across local devices (absent on 1-device hosts;
   the field is None and ICI latency terms fall back to the DX7xx wire
@@ -243,7 +243,7 @@ def calibrate(device=None) -> MachineProfile:
             logger.debug("ici probe unavailable: %s", e)
 
     # subtract the measured fixed dispatch cost from the bandwidth
-    # probes so a tunnel RTT doesn't masquerade as low bandwidth
+    # probes so the handshake doesn't masquerade as low bandwidth
     def bw(nb: float, s: float) -> float:
         return nb / max(s - tick_s, 1e-9) / 1e9
 

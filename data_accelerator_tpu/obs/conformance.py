@@ -114,10 +114,10 @@ DEFAULT_STAGE_TIME_RATIO_HIGH = 10.0
 # predicted ms below which DX520/DX521 decline to judge a stage: a
 # sub-millisecond roofline prediction means fixed host-side costs the
 # device model deliberately does not cover (row materialization, GIL
-# scheduling, tunnel RTT) dominate the observation, and any ratio
-# against it is noise, not drift — the missing-prediction posture
-# (silence) applies. An explicit conformance.latency PIN is always
-# judged: the operator asserted the number.
+# scheduling, the completion handshake) dominate the observation, and
+# any ratio against it is noise, not drift — the missing-prediction
+# posture (silence) applies. An explicit conformance.latency PIN is
+# always judged: the operator asserted the number.
 DEFAULT_STAGE_TIME_FLOOR_MS = 1.0
 # observed live HBM peak / the DX2xx modeled footprint above which
 # DX522 fires — the byte model is exact (tier-1 asserts model ==

@@ -61,8 +61,7 @@ class HealthState:
         # sustained-stall readiness threshold: the smoothed
         # Pipeline_Stall_Ms above this means the pipeline is saturated
         # or wedged, not merely overlapping (default: 10 batch
-        # intervals, floored at 10 s so split-host tunnel RTTs and
-        # normal overlap never trip it)
+        # intervals, floored at 10 s so normal overlap never trips it)
         self.stall_fail_ms = (
             stall_fail_ms if stall_fail_ms is not None
             else max(10_000.0, 10.0 * batch_interval_s * 1000.0)
@@ -448,7 +447,6 @@ class ObservabilityServer:
                         )
                         return
                     payload = {
-                        "available": obs.profiler.available,
                         "active": obs.profiler.active(),
                         "captures": obs.profiler.captures_count,
                     }
@@ -468,14 +466,10 @@ class ObservabilityServer:
                         404, b'{"error": "not found"}', "application/json"
                     )
                     return
-                if obs.profiler is None or not obs.profiler.available:
+                if obs.profiler is None:
                     self._send(
                         501,
-                        json.dumps({
-                            "error": "jax profiler unavailable "
-                                     "(surface disabled or backend "
-                                     "without profiler support)",
-                        }).encode(),
+                        b'{"error": "profiler surface disabled"}',
                         "application/json",
                     )
                     return

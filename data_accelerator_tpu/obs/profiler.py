@@ -17,9 +17,9 @@ and the capture lands beside the flight recorder —
 - ``python -m data_accelerator_tpu.obs profile <url>`` drives it from
   a terminal; captures open in tensorboard/xprof.
 
-No-op posture: on a backend/build without ``jax.profiler`` the surface
-reports unavailable, the endpoint answers 501, and nothing else
-changes — profiling is diagnostics, never load-bearing.
+Profiling is diagnostics, never load-bearing: a capture that fails to
+start or stop is reported to the caller and logged, and the batch loop
+carries on.
 """
 
 from __future__ import annotations
@@ -34,16 +34,6 @@ logger = logging.getLogger(__name__)
 
 DEFAULT_SECONDS = 5.0
 MAX_SECONDS = 120.0
-
-
-def profiler_available() -> bool:
-    """True when this process can start a jax profiler trace."""
-    try:
-        import jax.profiler  # noqa: F401
-
-        return hasattr(jax.profiler, "start_trace")
-    except Exception:  # noqa: BLE001 — any import failure = unavailable
-        return False
 
 
 class ProfilerSurface:
@@ -61,10 +51,6 @@ class ProfilerSurface:
         self._finished: List[Dict] = []
         self._lock = threading.Lock()
 
-    @property
-    def available(self) -> bool:
-        return profiler_available()
-
     def active(self) -> Optional[dict]:
         with self._lock:
             return dict(self._active) if self._active else None
@@ -72,11 +58,9 @@ class ProfilerSurface:
     def start(self, seconds: float = DEFAULT_SECONDS) -> dict:
         """Arm a capture for ``seconds``; returns
         ``{path, seconds, active}`` or ``{error}`` (already capturing /
-        profiler unavailable). The path is returned immediately so the
-        caller can watch it fill."""
+        start failed). The path is returned immediately so the caller
+        can watch it fill."""
         seconds = min(max(float(seconds), 0.1), MAX_SECONDS)
-        if not self.available:
-            return {"error": "jax.profiler unavailable on this backend"}
         with self._lock:
             if self._active is not None:
                 return {

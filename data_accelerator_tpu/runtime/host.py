@@ -21,6 +21,8 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
+import jax
+
 from ..constants import MetricName
 from ..core.config import SettingDictionary, SettingNamespace
 from ..core.confmanager import ConfigManager
@@ -119,6 +121,14 @@ class StreamingHost:
                     spec.conf, spec.schema, source=name
                 )
         self.source = self.sources[self.processor.primary]
+        if any(hasattr(s, "poll_raw") for s in self.sources.values()):
+            # raw-bytes sources decode natively and nothing else: build
+            # and load the library NOW so a missing toolchain fails the
+            # start with the compiler's stderr (NativeBuildError), not
+            # the first batch
+            from ..native import load_library
+
+            load_library()
         self.interval_s = self.processor.interval_s
         self.max_rate = int(input_conf.get_or_else("eventhub.maxrate", "1000"))
         # backpressure: when a batch overruns the interval, shrink the
@@ -737,7 +747,20 @@ class StreamingHost:
             # Protocol_Events_Count for this batch's recorded prefix
             # (the post-ack checkpoint trio drains on the next batch)
             metrics.update(pm.drain_metric_deltas())
-        self.telemetry.batch_end(batch_time_ms, {"latencyMs": metrics["Latency-Batch"]})
+        if self.batches_processed == 0:
+            # what this job actually runs on, flight-recorded with its
+            # first batch: a reader of the recorder (chip_smoke.py, an
+            # operator) learns platform, placement and decode engine
+            # from the process that holds the chip, not from its own
+            self.telemetry.track_event(
+                "host/devices", self._device_report()
+            )
+        # the batch's whole metric set rides the end event, so the
+        # flight recorder alone reconstructs per-batch counts
+        self.telemetry.batch_end(
+            batch_time_ms,
+            {"latencyMs": metrics["Latency-Batch"], **metrics},
+        )
         self.metric_logger.send_batch_metrics(metrics, batch_time_ms)
         # alert evaluation AFTER the store flush so window aggregates
         # include this batch; the firing set rides the health payload
@@ -849,6 +872,28 @@ class StreamingHost:
         self.health.record_watermark(batch_time_ms)
         trace.end()
         return metrics
+
+    def _device_report(self) -> Dict[str, object]:
+        """Platform, device kind and count as jax reports them in THIS
+        process, plus where the processor's data lives and which
+        engines (decoder, Pallas compile mode) served the batch."""
+        from ..udf.api import PallasUdf
+
+        devices = jax.devices()
+        return {
+            "platform": devices[0].platform,
+            "deviceKind": devices[0].device_kind,
+            "deviceCount": len(devices),
+            "jaxVersion": jax.__version__,
+            "batchCapacity": self.processor.batch_capacity,
+            "decoderPath": self.processor.last_decoder_path,
+            "pallasInterpret": {
+                name: udf.interpret
+                for name, udf in self.processor.udfs.items()
+                if isinstance(udf, PallasUdf)
+            },
+            **self.processor.placement(),
+        }
 
     def _traced_poll(self, trace):
         """Poll + encode under the batch's trace (the pipelined loop
@@ -1117,6 +1162,14 @@ def main(argv=None):
     ConfigManager.reset()
     ConfigManager.get_configuration_from_arguments(args)
     d = ConfigManager.load_config()
+    # first touch of the backend: a chip another process holds, or an
+    # accelerator platform that cannot start, fails here, by name
+    devices = jax.devices()
+    logger.info(
+        "devices: platform=%s device_kind=%s count=%d (jax %s)",
+        devices[0].platform, devices[0].device_kind, len(devices),
+        jax.__version__,
+    )
     host = StreamingHost(d)
     max_batches = int(named["batches"]) if "batches" in named else None
     logger.info(

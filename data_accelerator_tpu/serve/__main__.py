@@ -39,6 +39,13 @@ Args (key=value):
 The one-box analog of the reference's local container entry
 (DeploymentLocal/finalrun.sh): flow services + gateway + website +
 metrics path in one process, local file storage under ``root``.
+
+The control plane never holds a chip: ``main()`` pins its own jax to
+the CPU backend. A chip belongs to one process at a time, and the job
+hosts this process spawns (``serve/jobs.py LocalJobClient``) are the
+ones that need it; LiveQuery kernels and design-time lowering run on
+the control plane's CPU, as they do in the k8s layout
+(``deploy/k8s/control-plane.yaml`` asks for no TPU).
 """
 
 import logging
@@ -49,9 +56,26 @@ from .restapi import DataXApi, DataXApiService
 from .storage import LocalDesignTimeStorage, LocalRuntimeStorage
 
 
+def pin_to_cpu() -> None:
+    """Pin THIS process's jax to the CPU backend, before anything
+    initializes one. Through ``jax.config``, not ``JAX_PLATFORMS``:
+    ``LocalJobClient`` hands this process's environment to the hosts it
+    spawns, and a host that inherits ``cpu`` would run its flow off the
+    chip without anyone having asked for that."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main(argv=None):
     logging.basicConfig(level=logging.INFO)
     log = logging.getLogger(__name__)
+    pin_to_cpu()
+    log.info(
+        "control plane pinned to the CPU backend (jax.config "
+        "jax_platforms=cpu; JAX_PLATFORMS in the environment left as "
+        "the operator set it, for the job hosts)"
+    )
     args = dict(
         a.split("=", 1) for a in (argv or sys.argv[1:]) if "=" in a
     )
@@ -150,9 +174,6 @@ def main(argv=None):
     # (lq.* args override the datax.job.process.lq.* defaults, e.g.
     # lq.maxbatchwaitms=8 lq.tenant.maxqps=50; lq.ticker=false falls
     # back to the tickless in-process mode)
-    import os as _os
-
-    from ..compile.aotcache import compile_conf_for
     from ..lq.service import LiveQueryService
 
     lq_conf = {
@@ -160,12 +181,7 @@ def main(argv=None):
         for k, v in args.items() if k.startswith("lq.")
     }
     lq_conf.setdefault("datax.job.process.lq.ticker", "true")
-    livequery = LiveQueryService(
-        conf=lq_conf,
-        compile_conf=compile_conf_for(_os.path.join(
-            runtime_storage.resolve("livequery"), "compilecache"
-        )),
-    )
+    livequery = LiveQueryService(conf=lq_conf)
     if fleet_view is not None:
         # job-registry records carry the authoritative partition map;
         # trace lineage stitching prefers them over frame ordering

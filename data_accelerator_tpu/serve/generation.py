@@ -529,7 +529,7 @@ class RuntimeConfigGeneration:
 
     def _s630_compile(self, ctx) -> None:
         """Emit the flow's AOT **compile manifest** as a deployment
-        artifact and wire the persistent compilation cache — the
+        artifact and wire the shared compilation-cache layer — the
         reference compiled Flow JSON into a deployable job artifact
         ahead of time (SURVEY §1 L3, DataX.Config -> flat .conf ->
         spark-submit); ours additionally ships the *compiled
@@ -540,12 +540,12 @@ class RuntimeConfigGeneration:
         (``datax.job.process.compile.manifest``) so ``FlowProcessor``
         AOT-warms every entry at init instead of first dispatch.
 
-        The cache conf rides along: ``compile.cachedir`` under the
-        flow's runtime folder (restarts deserialize instead of
-        recompiling), and — when runtime storage is the shared object
-        store — ``compile.cacheurl`` (an ``objstore://`` prefix) so
-        preemption-recovered and scaled-out replicas pull compiles
-        their peers already paid for.
+        The shared cache layer rides along: when runtime storage is
+        the shared object store, ``compile.cacheurl`` (an
+        ``objstore://`` prefix) so preemption-recovered and scaled-out
+        replicas pull compiles their peers already paid for. The local
+        cache directory is not conf — every host resolves it the same
+        way (``compile/aotcache.py resolve_cache_dir``).
 
         Fail-open like S620: an analyzer error must not block
         deployment — the job simply cold-starts like every job did
@@ -583,9 +583,6 @@ class RuntimeConfigGeneration:
                     "compile manifest generation failed for %s: %s",
                     doc.get("name"), e,
                 )
-        ctx["compile_cache_dir"] = os.path.join(
-            self.runtime.resolve(ctx["flow_dir"]), "compilecache"
-        )
         ctx["compile_cache_url"] = None
         client = getattr(self.runtime, "client", None)
         if client is not None and hasattr(client, "url_for"):
@@ -732,9 +729,6 @@ class RuntimeConfigGeneration:
             if ctx.get("compile_manifest_path"):
                 extra["datax.job.process.compile.manifest"] = (
                     ctx["compile_manifest_path"])
-            if ctx.get("compile_cache_dir"):
-                extra["datax.job.process.compile.cachedir"] = (
-                    ctx["compile_cache_dir"])
             if ctx.get("compile_cache_url"):
                 extra["datax.job.process.compile.cacheurl"] = (
                     ctx["compile_cache_url"])

@@ -66,12 +66,6 @@ class Kernel:
     # jax.debug_nans and tracer-leak checking; a dict selects
     # individual process.debug.* flags ({"nans": "true"})
     debug: object = None
-    # persistent-compile-cache conf keys (datax.job.process.compile.*)
-    # merged into every query processor's conf: the kernel pool shares
-    # one cache dir, so a re-created kernel (or a restarted control
-    # plane) deserializes query compiles instead of re-tracing — the
-    # warm-kernel-pool half of the AOT compile path
-    compile_conf: Dict[str, str] = field(default_factory=dict)
     created_at: float = field(default_factory=time.time)
     last_used: float = field(default_factory=time.time)
     _processors: Dict[str, object] = field(default_factory=dict)
@@ -96,7 +90,6 @@ class Kernel:
                 max(1, int(max_window_s))
             )
         conf.update(self.refdata_conf)
-        conf.update(self.compile_conf)
         if self.debug:
             # process.debug conf block (runtime/processor.py): the
             # kernel's one-batch runs are exactly the "test job" the
@@ -265,16 +258,12 @@ class KernelService:
         runtime_storage=None,
         ttl_s: float = DEFAULT_KERNEL_TTL_S,
         max_kernels: int = DEFAULT_MAX_KERNELS,
-        compile_conf: Optional[Dict[str, str]] = None,
         session_manager=None,
     ):
         from ..lq.session import LEGACY_TENANT, SessionManager
 
         self.runtime = runtime_storage
         self.max_kernels = max_kernels
-        # shared persistent-compile-cache conf applied to every kernel
-        # (see Kernel.compile_conf)
-        self.compile_conf = dict(compile_conf or {})
         self._tenant = LEGACY_TENANT
         self.sessions = session_manager or SessionManager(ttl_s=ttl_s)
         self.ttl_s = self.sessions.ttl_s
@@ -309,7 +298,6 @@ class KernelService:
             udfs=udfs,
             refdata_conf=refdata_conf or {},
             debug=debug,
-            compile_conf=dict(self.compile_conf),
         )
         # legacy policy: evict the oldest-idle kernel when this
         # surface's cap is reached (the designer's recycle-oldest

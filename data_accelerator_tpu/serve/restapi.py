@@ -27,7 +27,6 @@ from __future__ import annotations
 import contextlib
 import json
 import logging
-import os
 import re
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -76,16 +75,6 @@ class DataXApi:
         # the batch spans it caused. None = tracing off (default).
         self.tracer = tracer
         self.flow_ops = flow_ops
-        # kernel pool shares one persistent compile cache under the
-        # runtime root: repeated kernel creates (and restarts of the
-        # whole control plane) deserialize query compiles instead of
-        # re-tracing them — the warm-LiveQuery-pool half of the AOT
-        # compile path (runtime/processor.py process.compile.*)
-        from ..compile.aotcache import compile_conf_for
-
-        compile_conf = compile_conf_for(os.path.join(
-            flow_ops.runtime.resolve("livequery"), "compilecache"
-        ))
         # ONE session registry behind both interactive surfaces: the
         # legacy designer kernels (kernel/* routes, TTL-reaped now) and
         # the multi-tenant serving plane (lq/* routes, quota'd). The
@@ -97,16 +86,13 @@ class DataXApi:
             self.kernels = kernels
             self.livequery = livequery or LiveQueryService(
                 session_manager=kernels.sessions,
-                compile_conf=compile_conf,
             )
         else:
             self.livequery = livequery or LiveQueryService(
                 session_manager=SessionManager(),
-                compile_conf=compile_conf,
             )
             self.kernels = KernelService(
                 runtime_storage=flow_ops.runtime,
-                compile_conf=compile_conf,
                 session_manager=self.livequery.sessions,
             )
         # fleet telemetry rollup (obs/fleetview.py): /fleet/* routes

@@ -11,7 +11,8 @@ reference: the extension API surface —
 - custom aggregates (UserDefinedAggregateFunction) -> ``JaxUdaf`` with a
   segment-reduce over sorted groups.
 - the Scala-tier escape hatch for custom kernels -> ``PallasUdf``
-  (TPU Pallas kernel with interpreter fallback off-TPU).
+  (TPU Pallas kernel, compiled by Mosaic; the interpreter only when a
+  test asks for it).
 - AzureFunctionHandler's per-row external calls -> the
   ``externalfn`` sink kind (runtime/sinks.py), keeping network I/O out
   of the compiled graph by design.

@@ -112,14 +112,28 @@ class JaxUdaf:
         )
 
 
+# rows per smallest Pallas block: Mosaic tiles the last two dims of a
+# block as (8, 128) for 32-bit, (16, 128) for 16-bit and (32, 128) for
+# 8-bit (bool) operands, so 32 x 128 rows is whole tiles for every
+# column type the engine ships
+_PALLAS_LANES = 128
+_PALLAS_TILE_ROWS = 32 * _PALLAS_LANES
+
+
 class PallasUdf(JaxUdf):
     """JaxUdf whose body is a Pallas TPU kernel.
 
-    ``kernel(*refs)``: standard pallas kernel over 1-D row blocks; built
-    with interpret=True automatically off-TPU so the same flow runs on
-    the CPU one-box. The escape hatch the reference provides via custom
-    Scala UDFs compiled into the job JAR (datax-udf-samples/) — here the
-    user ships a Pallas kernel instead and keeps MXU/VPU control.
+    ``kernel(*in_refs, out_ref)``: an elementwise pallas kernel. Each
+    row-vector argument reaches it as a lane-dense 2-D block
+    ``[block_rows / 128, 128]`` (row ``i`` of the batch is element
+    ``[i // 128, i % 128]``): the batch is zero-padded to whole blocks
+    and reshaped, because Mosaic cannot tile a 1-D block or a ragged
+    last one. Compiled by Mosaic by default; ``interpret=True`` runs
+    the Pallas interpreter instead and is for tests on hosts without a
+    TPU — it is never chosen for you. The escape hatch the reference
+    provides via custom Scala UDFs compiled into the job JAR
+    (datax-udf-samples/) — here the user ships a Pallas kernel instead
+    and keeps MXU/VPU control.
     """
 
     def __init__(
@@ -128,12 +142,14 @@ class PallasUdf(JaxUdf):
         kernel: Callable,
         out_type: str = "double",
         out_dtype=jnp.float32,
-        block_rows: int = 1024,
+        block_rows: int = 32768,
         on_interval: Optional[Callable[[int], bool]] = None,
+        interpret: bool = False,
     ):
         self.kernel = kernel
         self.out_dtype = out_dtype
         self.block_rows = block_rows
+        self.interpret = interpret
 
         def fn(*arrays):
             return self._pallas_call(*arrays)
@@ -144,20 +160,28 @@ class PallasUdf(JaxUdf):
         import jax
         from jax.experimental import pallas as pl
 
+        def round_up(x: int, m: int) -> int:
+            return -(-x // m) * m
+
         n = arrays[0].shape[0]
-        block = min(self.block_rows, n)
-        grid = (n + block - 1) // block
-        interpret = jax.default_backend() != "tpu"
-        return pl.pallas_call(
+        block = min(
+            round_up(self.block_rows, _PALLAS_TILE_ROWS),
+            round_up(n, _PALLAS_TILE_ROWS),
+        )
+        padded = round_up(n, block)
+        shape = (padded // _PALLAS_LANES, _PALLAS_LANES)
+        spec = pl.BlockSpec(
+            (block // _PALLAS_LANES, _PALLAS_LANES), lambda i: (i, 0)
+        )
+        out = pl.pallas_call(
             self.kernel,
-            out_shape=jax.ShapeDtypeStruct((n,), self.out_dtype),
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block,), lambda i: (i,)) for _ in arrays
-            ],
-            out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-            interpret=interpret,
-        )(*arrays)
+            out_shape=jax.ShapeDtypeStruct(shape, self.out_dtype),
+            grid=(padded // block,),
+            in_specs=[spec] * len(arrays),
+            out_specs=spec,
+            interpret=self.interpret,
+        )(*[jnp.pad(a, (0, padded - n)).reshape(shape) for a in arrays])
+        return out.reshape(padded)[:n]
 
 
 class UdfRegistry:

@@ -95,10 +95,10 @@ def _anomaly_kernel(x_ref, mu_ref, o_ref):
     o_ref[...] = 1.0 / (1.0 + jnp.exp(-d))
 
 
-def anomalyscore() -> PallasUdf:
+def anomalyscore(interpret: bool = False) -> PallasUdf:
     return PallasUdf(
         "anomalyscore", _anomaly_kernel, out_type="double",
-        out_dtype=jnp.float32,
+        out_dtype=jnp.float32, interpret=interpret,
     )
 
 

@@ -173,7 +173,7 @@ def test_config4_multi_rule_with_pallas_udf(tmp_path):
     )
     proc = FlowProcessor(
         _conf(tmp_path, transform),
-        udfs={"anomalyscore": anomalyscore()},
+        udfs={"anomalyscore": anomalyscore(interpret=True)},
         output_datasets=["HotAlerts", "AnomalyAlerts"],
     )
     base = 1_700_000_000_000

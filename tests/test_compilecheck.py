@@ -288,7 +288,8 @@ def test_generation_embeds_manifest_and_cache_conf(generated_conf):
     manifest = json.loads(open(mpath).read())
     assert manifest["flow"] == "CompileWarm"
     assert [e["entry"] for e in manifest["entries"]].count("step") == 1
-    assert "datax.job.process.compile.cachedir=" in text
+    # the cache directory is not conf: every host resolves it itself
+    assert "compile.cachedir" not in text
 
 
 def test_warm_init_performs_no_first_dispatch_compile(generated_conf):
@@ -317,21 +318,17 @@ def test_warm_init_performs_no_first_dispatch_compile(generated_conf):
     assert cold._step_cache_size() == 1  # first dispatch compiled
 
     warm = FlowProcessor(SettingDictionary(dict(conf.dict)))
-    try:
-        assert warm._aot_warmed and warm.compile_manifest is not None
-        mark = warm._warm_step_mark
-        assert mark and mark >= 1  # init compiled the step
-        _d, m = warm.process_batch(
-            warm.encode_rows(rows, 1_700_000_000_000),
-            batch_time_ms=1_700_000_000_000,
-        )
-        assert warm._step_cache_size() == mark  # zero dispatch compiles
-        assert "Compile_WarmMiss_Count" not in m
-        assert "Compile_ManifestDrift_Count" not in m
-        assert m["Compile_ColdStart_Ms"] > 0
-    finally:
-        if warm._compile_cache is not None:
-            warm._compile_cache.disable()
+    assert warm._aot_warmed and warm.compile_manifest is not None
+    mark = warm._warm_step_mark
+    assert mark and mark >= 1  # init compiled the step
+    _d, m = warm.process_batch(
+        warm.encode_rows(rows, 1_700_000_000_000),
+        batch_time_ms=1_700_000_000_000,
+    )
+    assert warm._step_cache_size() == mark  # zero dispatch compiles
+    assert "Compile_WarmMiss_Count" not in m
+    assert "Compile_ManifestDrift_Count" not in m
+    assert m["Compile_ColdStart_Ms"] > 0
 
 
 def test_warm_miss_fires_dx604_counter(generated_conf):
@@ -341,68 +338,91 @@ def test_warm_miss_fires_dx604_counter(generated_conf):
     Compile_WarmMiss_Count (DX604's runtime face)."""
     conf, _text = generated_conf
     warm = FlowProcessor(SettingDictionary(dict(conf.dict)))
-    try:
-        spec = warm.specs[warm.primary]
-        np_cols = {
-            c: np.zeros(
-                spec.capacity,
-                {"double": np.float32, "boolean": np.bool_}.get(t, np.int32),
-            )
-            for c, t in spec.raw_schema.types.items()
-        }
-        packed = pack_raw(np_cols, np.zeros(spec.capacity, np.bool_))
-        _d, m = warm.process_batch(packed, batch_time_ms=1_700_000_000_000)
-        assert m.get("Compile_WarmMiss_Count", 0) >= 1
-    finally:
-        if warm._compile_cache is not None:
-            warm._compile_cache.disable()
+    spec = warm.specs[warm.primary]
+    np_cols = {
+        c: np.zeros(
+            spec.capacity,
+            {"double": np.float32, "boolean": np.bool_}.get(t, np.int32),
+        )
+        for c, t in spec.raw_schema.types.items()
+    }
+    packed = pack_raw(np_cols, np.zeros(spec.capacity, np.bool_))
+    _d, m = warm.process_batch(packed, batch_time_ms=1_700_000_000_000)
+    assert m.get("Compile_WarmMiss_Count", 0) >= 1
 
 
-def test_persistent_cache_hits_across_restarts(generated_conf):
-    """Second init against the same cachedir deserializes instead of
-    compiling: misses on the first start become hits on the restart."""
+@pytest.fixture
+def fresh_cache_dir(tmp_path, monkeypatch):
+    """Point this process's compile cache at an empty directory for one
+    test (the suite's own cache is warm from earlier runs, so nothing
+    would miss), and put jax back afterwards. Moving the directory is
+    what the engine itself never does; a test of cold-vs-warm has to."""
+    import jax
+    from jax._src import compilation_cache
+
+    from data_accelerator_tpu.compile.aotcache import CACHE_DIR_ENV
+
+    before = jax.config.jax_compilation_cache_dir
+
+    def point_at(path):
+        monkeypatch.setenv(CACHE_DIR_ENV, path)
+        jax.config.update("jax_compilation_cache_dir", path)
+        compilation_cache.reset_cache()
+        return path
+
+    yield lambda name: point_at(str(tmp_path / name))
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
+
+
+def test_persistent_cache_hits_across_restarts(
+    generated_conf, fresh_cache_dir,
+):
+    """A second init against the same cache directory deserializes
+    instead of compiling: jax's own cache events (the
+    Compile_Cache_{Hit,Miss}_Count metrics) show misses on the first
+    start, hits and no miss on the restart."""
     conf, _text = generated_conf
+    fresh_cache_dir("cc")
     rows = [{
         "deviceDetails": {"deviceId": 1, "deviceType": "Heating",
                           "homeId": 150, "status": 1,
                           "temperature": 50.0},
         "eventTimeStamp": 1_700_000_000_000,
     }]
-    procs = []
-    try:
-        p1 = FlowProcessor(SettingDictionary(dict(conf.dict)))
-        procs.append(p1)
-        _d, m1 = p1.process_batch(
-            p1.encode_rows(rows, 1_700_000_000_000),
-            batch_time_ms=1_700_000_000_000,
-        )
-        assert m1["Compile_Cache_Miss_Count"] > 0
-        p2 = FlowProcessor(SettingDictionary(dict(conf.dict)))
-        procs.append(p2)
-        _d, m2 = p2.process_batch(
-            p2.encode_rows(rows, 1_700_000_000_000),
-            batch_time_ms=1_700_000_000_000,
-        )
-        assert m2["Compile_Cache_Hit_Count"] >= m1["Compile_Cache_Miss_Count"]
-        assert m2["Compile_Cache_Miss_Count"] == 0
-        assert m2["Compile_ColdStart_Ms"] < m1["Compile_ColdStart_Ms"]
-    finally:
-        for p in reversed(procs):
-            if p._compile_cache is not None:
-                p._compile_cache.disable()
+    p1 = FlowProcessor(SettingDictionary(dict(conf.dict)))
+    _d, m1 = p1.process_batch(
+        p1.encode_rows(rows, 1_700_000_000_000),
+        batch_time_ms=1_700_000_000_000,
+    )
+    assert m1["Compile_Cache_Miss_Count"] > 0
+    p2 = FlowProcessor(SettingDictionary(dict(conf.dict)))
+    _d, m2 = p2.process_batch(
+        p2.encode_rows(rows, 1_700_000_000_000),
+        batch_time_ms=1_700_000_000_000,
+    )
+    assert m2["Compile_Cache_Hit_Count"] >= m1["Compile_Cache_Miss_Count"]
+    assert m2["Compile_Cache_Miss_Count"] == 0
+    assert m2["Compile_ColdStart_Ms"] < m1["Compile_ColdStart_Ms"]
 
 
-def test_compile_cache_routes_through_objstore(tmp_path):
+def test_compile_cache_routes_through_objstore(tmp_path, fresh_cache_dir):
     """cacheurl = objstore:// prefix: the first processor pushes its
-    compiles to the shared store; a replica with a DIFFERENT local dir
-    pulls them back (the preemption-recovery / scale-out path)."""
+    compiles to the shared store; a replica whose local directory is
+    EMPTY pulls them back (the preemption-recovery / scale-out path)
+    and compiles nothing. The replica resolves the SAME directory path
+    (as every host of one deployment does): jax hashes the directory
+    into its cache key, so entries carried to another path never hit."""
+    import shutil
+
+    from jax._src import compilation_cache
+
     from data_accelerator_tpu.serve.objectstore import (
         ObjectStoreClient,
         ObjectStoreServer,
     )
 
     store = ObjectStoreServer(port=0, root=str(tmp_path / "store")).start()
-    procs = []
     try:
         client = ObjectStoreClient(store.endpoint)
         url = client.url_for("flows/CacheFlow/compilecache")
@@ -412,28 +432,57 @@ def test_compile_cache_routes_through_objstore(tmp_path):
             "datax.job.process.compile.manifest": json.dumps(manifest),
             "datax.job.process.compile.cacheurl": url,
         }
-        extra_a = dict(extra)
-        extra_a["datax.job.process.compile.cachedir"] = str(tmp_path / "a")
-        p1 = FlowProcessor(conf_for_gui(flow, extra_a))
-        procs.append(p1)
+        cache_dir = fresh_cache_dir("cc")
+        p1 = FlowProcessor(conf_for_gui(flow, extra))
         assert p1._aot_warmed
         keys = client.list("flows/CacheFlow/compilecache")
         assert keys, "warm pushed no cache entries to the store"
-        extra_b = dict(extra)
-        extra_b["datax.job.process.compile.cachedir"] = str(tmp_path / "b")
-        p2 = FlowProcessor(conf_for_gui(flow, extra_b))
-        procs.append(p2)
+        shutil.rmtree(cache_dir)  # the replica's disk starts empty
+        compilation_cache.reset_cache()
+        p2 = FlowProcessor(conf_for_gui(flow, extra))
         pulled = [
-            f for f in os.listdir(str(tmp_path / "b"))
-            if not f.endswith("-atime")
+            f for f in os.listdir(cache_dir) if not f.endswith("-atime")
         ]
         assert len(pulled) >= len(keys)
-        assert p2.compile_stats["Cache_Hit_Count"] >= len(keys)
+        hits, misses = p2._compile_cache.take_counts()
+        assert hits > 0 and misses == 0
     finally:
-        for p in reversed(procs):
-            if p._compile_cache is not None:
-                p._compile_cache.disable()
         store.stop()
+
+
+def test_cache_dir_comes_from_the_environment_or_the_checkout(monkeypatch):
+    """One resolver: JAX_COMPILATION_CACHE_DIR when set — and then no
+    code of ours touches jax's cache-directory config — else
+    <checkout>/.jax_cache. No path to it goes through tempfile."""
+    import inspect
+
+    import jax
+
+    from data_accelerator_tpu.compile import aotcache
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv(aotcache.CACHE_DIR_ENV)
+    assert aotcache.resolve_cache_dir() == os.path.join(checkout, ".jax_cache")
+    assert aotcache.PersistentCompileCache().dir == aotcache.resolve_cache_dir()
+
+    armed = jax.config.jax_compilation_cache_dir  # conftest's env value
+    monkeypatch.setenv(aotcache.CACHE_DIR_ENV, armed)
+    assert aotcache.resolve_cache_dir() == armed
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v)),
+    )
+    aotcache.PersistentCompileCache().enable()
+    assert "jax_compilation_cache_dir" not in updates
+    assert jax.config.jax_compilation_cache_dir == armed
+
+    assert "tempfile" not in inspect.getsource(aotcache)
+    for name in ("bench.py", "tests/conftest.py"):
+        with open(os.path.join(checkout, name), encoding="utf-8") as f:
+            src = f.read()
+        assert "compile.cachedir" not in src and "dxtpu-jax-cache" not in src
 
 
 # ---------------------------------------------------------------------------

@@ -140,6 +140,43 @@ def test_sharded_input_placement(tmp_path):
     assert len(ts.sharding.device_set) == 8
 
 
+def test_numchips_builds_the_mesh_and_places_state_and_ingest_on_it(tmp_path):
+    """process.numchips=N makes an N-device mesh: the rings start out
+    sharded over it (not whole on device 0 until the first step), the
+    encoders hand each chip its row shard, and placement() reports
+    both."""
+    conf = dict(make_conf(tmp_path).dict)
+    conf["datax.job.process.numchips"] = "4"
+    proc = FlowProcessor(SettingDictionary(conf), batch_capacity=256,
+                         output_datasets=["PerDevice"])
+    assert proc.mesh is not None and proc.mesh.size == 4
+    ring = proc.window_buffers["DataXProcessedInput"]
+    assert len(ring.valid.sharding.device_set) == 4
+    cols, valid = crafted_raw(proc)
+    raw = proc.encode_columns(cols, 96)
+    assert all(
+        len(a.sharding.device_set) == 4
+        for a in (*raw.cols.values(), raw.valid)
+    )
+    proc.process_batch(raw, batch_time_ms=1_700_000_000_000)
+    placed = proc.placement()
+    assert placed["stepDevices"] == 4
+    assert placed["ringDevices"] == {"DataXProcessedInput": 4}
+    assert placed["rawDevices"] == {"default": 4}
+    assert len(placed["deviceBytesInUse"]) == 4
+
+
+def test_numchips_above_the_visible_devices_raises(tmp_path):
+    """A conf that asks for more chips than this process can see is an
+    error, never a smaller mesh with a log line."""
+    from data_accelerator_tpu.core.config import EngineException
+
+    conf = dict(make_conf(tmp_path).dict)
+    conf["datax.job.process.numchips"] = str(len(jax.devices()) + 1)
+    with pytest.raises(EngineException, match="numchips=9.*only 8"):
+        FlowProcessor(SettingDictionary(conf), batch_capacity=256)
+
+
 def test_host_ingest_plan_single_process_owns_everything(tmp_path):
     """On one process the plan covers all partitions/rows; the global
     batch assembled from 'local' data is correctly row-sharded and runs
@@ -186,3 +223,53 @@ def test_host_ingest_plan_rejects_wrong_shard_size():
     plan = HostIngestPlan(make_mesh(8), 64, 4, 1000)
     with pytest.raises(ValueError):
         plan.make_global({"v": np.zeros(32, np.int32)}, np.ones(32, bool))
+
+
+# compiled HLO as the TPU backend prints it (lines from the 4-chip v5e
+# run of __graft_entry__.dryrun_multichip, PR 21): tiled layouts, a
+# combined tuple-shaped all-reduce with /*index=N*/ markers, and uses of
+# a collective's NAME as an operand, which are not collectives
+_TPU_HLO = """
+  %all-reduce.9 = (s32[384]{0:T(512)}, s32[384]{0:T(512)}, s32[384]{0:T(512)}, s32[384]{0:T(512)}, s32[384]{0:T(512)}, /*index=5*/s32[384]{0:T(512)}, s32[384]{0:T(512)}) all-reduce(%dynamic-update-slice, %dynamic-update-slice.1, /*index=5*/%dynamic-update-slice.5), channel_id=1, replica_groups=[1,4]<=[4], to_apply=%add
+  %get-tuple-element = s32[384]{0:T(512)} get-tuple-element(%all-reduce.9), index=0
+  %all-reduce.6 = f32[384]{0:T(512)} all-reduce(%dynamic-update-slice.6), channel_id=7, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.6
+  %all-reduce.8 = u32[1,1,128]{2,1,0:T(1,128)} all-reduce(%bitcast.2), channel_id=9, replica_groups=[1,4]<=[4], to_apply=%add.8.clone
+  %bitcast.3 = pred[384]{0:T(512)(128)(4,1)} bitcast(%all-reduce.8)
+  %compare_select_fusion.5 = s32[704]{0} fusion(%copy.80, %all-reduce.17, %copy.81), kind=kLoop, calls=%fused_computation.9
+  %all-gather = pred[6,64]{0,1} all-gather(%select_dynamic-update-slice_fusion.1), channel_id=3, replica_groups=[1,4]<=[4], dimensions={1}
+  %collective-permute.2 = s32[48]{0:T(128)} collective-permute(%wrapped_slice.7), channel_id=30, source_target_pairs={{0,1},{1,2},{2,3}}
+  ROOT %tuple.2 = (s32[384]{0:T(512)}, pred[384]{0:T(512)(128)(4,1)}) tuple(%get-tuple-element, %bitcast.3)
+"""
+
+
+def test_collective_census_reads_tpu_hlo():
+    from data_accelerator_tpu.dist.mesh import collective_summary
+
+    assert collective_summary(_TPU_HLO).to_dict() == {
+        "all-reduce": {"count": 3,
+                       "resultBytes": 7 * 384 * 4 + 384 * 4 + 128 * 4},
+        "all-gather": {"count": 1, "resultBytes": 6 * 64},
+        "collective-permute": {"count": 1, "resultBytes": 48 * 4},
+    }
+
+
+def test_collective_census_counts_an_async_pair_once_by_its_result():
+    """-start returns (operand, result[, context scalars]); only the
+    -done's shape is the result."""
+    from data_accelerator_tpu.dist.mesh import collective_summary
+
+    hlo = (
+        "  %all-gather-start.1 = (f32[16384]{0:T(1024)}, "
+        "f32[65536]{0:T(1024)}) all-gather-start(%param.3), channel_id=5, "
+        "dimensions={0}\n"
+        "  %all-gather-done.1 = f32[65536]{0:T(1024)} "
+        "all-gather-done(%all-gather-start.1)\n"
+        "  %collective-permute-start = (s32[16]{0}, s32[16]{0}, u32[], "
+        "u32[]) collective-permute-start(%x), source_target_pairs={{0,1}}\n"
+        "  %collective-permute-done = s32[16]{0} "
+        "collective-permute-done(%collective-permute-start)\n"
+    )
+    assert collective_summary(hlo).to_dict() == {
+        "all-gather": {"count": 1, "resultBytes": 65536 * 4},
+        "collective-permute": {"count": 1, "resultBytes": 64},
+    }

@@ -50,17 +50,59 @@ SCHEMA_JSON = json.dumps({
 
 def test_native_library_builds_in_ci():
     """CI guard (satellite): the native decoder must BUILD and load in
-    the test environment — a silent g++ failure would otherwise demote
-    every ingest path to the Python fallback while the suite still
-    passes. Set DATAX_ALLOW_NO_NATIVE=1 only on machines that
-    genuinely have no toolchain."""
+    the test environment — every native-gated test below would
+    otherwise skip while the suite still passes. Set
+    DATAX_ALLOW_NO_NATIVE=1 only on machines that genuinely have no
+    toolchain."""
     if os.environ.get("DATAX_ALLOW_NO_NATIVE") == "1":
         pytest.skip("explicitly allowed to run without the native decoder")
     assert native_available(), (
-        "native decoder failed to build/load — the whole ingest tree "
-        "would silently run on the Python fallback (check g++ and "
+        "native decoder failed to build/load (check g++ and "
         "native/decoder.cpp)"
     )
+
+
+def test_library_is_named_by_a_hash_of_its_source_and_flags(monkeypatch):
+    """The library loaded is the one built from THIS decoder.cpp with
+    THESE flags: its name is their hash, under the ignored build dir —
+    so changing either changes the name, and a stale or foreign .so is
+    never picked up in its place."""
+    from data_accelerator_tpu.native import decoder as dec
+
+    path = dec._build_library()
+    assert os.path.dirname(path) == dec._BUILD_DIR
+    assert dec._BUILD_DIR.endswith(os.path.join("native", ".build"))
+    assert os.path.exists(path)
+    monkeypatch.setattr(dec, "_CXX", dec._CXX + ["-DDX_OTHER_BUILD"])
+    assert os.path.basename(dec._build_library()) != os.path.basename(path)
+
+
+def test_unbuildable_decoder_fails_host_start_with_compiler_message(
+    tmp_path, monkeypatch,
+):
+    """A raw-bytes host whose native decoder cannot be built does not
+    start: StreamingHost raises NativeBuildError carrying the compiler's
+    stderr — no quiet switch to the per-row Python encoder — and the
+    processor's own encode raises the same way."""
+    from data_accelerator_tpu.native import NativeBuildError
+    from data_accelerator_tpu.native import decoder as dec
+    from data_accelerator_tpu.runtime.host import StreamingHost
+
+    monkeypatch.setattr(dec, "_lib", None)
+    monkeypatch.setattr(dec, "_lib_error", None)
+    monkeypatch.setattr(dec, "_CXX", dec._CXX + ["--no-such-flag-dx"])
+    conf = dict(_proc(tmp_path).dict.dict)
+    conf.update({
+        "datax.job.input.default.inputtype": "socket",
+        "datax.job.process.observability.calibration": "false",
+    })
+    with pytest.raises(NativeBuildError, match="no-such-flag-dx") as err:
+        StreamingHost(SettingDictionary(conf))
+    assert "build failed" in str(err.value)
+    proc = _proc(tmp_path)
+    with pytest.raises(NativeBuildError, match="no-such-flag-dx"):
+        proc.encode_json_bytes(b"{}\n", 1_700_000_000_000)
+    assert proc.last_decoder_path is None
 
 
 def _proc(tmp_path, capacity=32, extra=None):
@@ -126,12 +168,12 @@ pytest_native = pytest.mark.skipif(
 
 
 @pytest_native
-def test_kafka_fast_path_golden_vs_python_fallback(tmp_path, monkeypatch):
+def test_kafka_fast_path_golden_vs_python_reference(tmp_path):
     """Acceptance: KafkaSource.poll_raw blobs route through
     encode_json_bytes(fmt="kafka-v2") with ZERO per-row Python objects
-    (native walker), and the decoded batch equals the Python-fallback
+    (native walker), and the decoded batch equals the Python reference
     row encoder's output row for row — incl. malformed record values,
-    which both paths drop and count."""
+    which both drop and count."""
     vals = _values(12)
     vals.insert(3, b"{not json")      # malformed value
     vals.insert(7, b"")               # empty value
@@ -148,20 +190,18 @@ def test_kafka_fast_path_golden_vs_python_fallback(tmp_path, monkeypatch):
     got_native = _rows_of(native, raw_native)
     native_malformed = native.ingest_stats.get("malformed_rows", 0)
 
-    fallback = _proc(tmp_path)
-    import data_accelerator_tpu.native as native_mod
-
-    monkeypatch.setattr(native_mod, "native_available", lambda: False)
-    raw_py = fallback.encode_json_bytes(
-        blob, 1_700_000_000_000, fmt="kafka-v2"
+    # the per-row Python encoder is the parity reference, called
+    # directly — the served path never reaches it
+    reference = _proc(tmp_path)
+    raw_py = reference._encode_json_python(
+        blob, 1_700_000_000_000, reference._spec(None), "kafka-v2"
     )
-    assert fallback.last_decoder_path == "python-fallback"
-    got_py = _rows_of(fallback, raw_py)
+    got_py = _rows_of(reference, raw_py)
 
     assert got_native == got_py
     assert len(got_native) == 12
     assert native_malformed == 2
-    assert fallback.ingest_stats.get("malformed_rows", 0) == 2
+    assert reference.ingest_stats.get("malformed_rows", 0) == 2
 
 
 @pytest_native

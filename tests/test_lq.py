@@ -164,7 +164,7 @@ class TestCoalescingInvariant:
         # predicts exactly ONE kernel entry for this load
         sessions = [lq.sessions.get(sid) for sid in sids]
         sigs = {
-            signature_for(s, QUERY, lq.cache.compile_conf).key
+            signature_for(s, QUERY).key
             for s in sessions
         }
         assert len(sigs) == 1

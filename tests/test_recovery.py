@@ -620,7 +620,7 @@ def test_profiler_hook_writes_trace(tmp_path):
     host = StreamingHost(_conf(tmp_path, {
         "datax.job.process.observability.profilerdir": str(prof_dir),
     }))
-    assert host.profiler is not None and host.profiler.available
+    assert host.profiler is not None
     res = host.profiler.start(seconds=60)  # stopped explicitly below
     assert res.get("path"), res
     host.run_batch()

@@ -151,3 +151,56 @@ class TestLocalJobClient:
         log = open(os.path.join(str(tmp_path / "logs"), f"{name}.log")).read()
         assert job["state"] == JobState.Success, log[-2000:]
         assert "Input_DataXProcessedInput_Events_Count=100" in log
+
+
+def test_control_plane_pins_itself_not_its_job_hosts(tmp_path):
+    """The control plane pins ITS jax to the CPU through jax.config and
+    leaves JAX_PLATFORMS as the operator set it, so the environment
+    LocalJobClient hands a job host carries no cpu pin the operator did
+    not ask for. Run in a fresh interpreter whose environment names an
+    accelerator first, as a chip host's does."""
+    import subprocess
+
+    script = (
+        "import json, os, subprocess\n"
+        "from data_accelerator_tpu.serve.__main__ import pin_to_cpu\n"
+        "from data_accelerator_tpu.serve.jobs import LocalJobClient\n"
+        "pin_to_cpu()\n"
+        "import jax\n"
+        "seen = {}\n"
+        "class Child:\n"
+        "    pid = 1\n"
+        "    def __init__(self, cmd, env=None, **kw): seen['env'] = env\n"
+        "subprocess.Popen = Child\n"
+        "LocalJobClient().submit({'name': 'j', 'confPath': 'x.conf'})\n"
+        "print(json.dumps({\n"
+        "    'own': jax.devices()[0].platform,\n"
+        "    'config': jax.config.jax_platforms,\n"
+        "    'environ': os.environ.get('JAX_PLATFORMS'),\n"
+        "    'child': seen['env'].get('JAX_PLATFORMS'),\n"
+        "}))\n"
+    )
+    env = {**os.environ, "JAX_PLATFORMS": "tpu,cpu"}
+    done = subprocess.run(
+        [sys.executable, "-c", script], env=env, capture_output=True,
+        text=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    got = json.loads(done.stdout.strip().splitlines()[-1])
+    assert got == {
+        "own": "cpu", "config": "cpu",
+        "environ": "tpu,cpu", "child": "tpu,cpu",
+    }
+
+
+def test_serve_main_pins_before_anything_else():
+    """main() pins the backend first and says so; nothing in the serve
+    package exports JAX_PLATFORMS."""
+    import inspect
+
+    from data_accelerator_tpu.serve import __main__ as serve_main
+
+    body = inspect.getsource(serve_main.main)
+    assert body.index("pin_to_cpu()") < body.index("FlowOperation(")
+    assert 'environ["JAX_PLATFORMS"]' not in inspect.getsource(serve_main)

@@ -17,7 +17,6 @@ from concurrent.futures import ThreadPoolExecutor
 import pytest
 
 from data_accelerator_tpu.core.config import EngineException, SettingDictionary
-from data_accelerator_tpu.runtime import processor as processor_mod
 from data_accelerator_tpu.runtime.processor import (
     OUTPUT_SLOT_BUFFERS,
     OVERFLOW_BOOST_BATCHES,
@@ -32,13 +31,6 @@ SCHEMA = json.dumps({"type": "struct", "fields": [
 TRANSFORM = (
     "--DataXQuery--\n"
     "Out = SELECT k, v FROM DataXProcessedInput\n"
-)
-
-TWO_OUT_TRANSFORM = (
-    "--DataXQuery--\n"
-    "Out = SELECT k, v FROM DataXProcessedInput\n"
-    "--DataXQuery--\n"
-    "Out2 = SELECT k FROM DataXProcessedInput\n"
 )
 
 
@@ -102,58 +94,6 @@ def test_overflow_refetch_matches_full_capacity_fetch(tmp_path):
     d2, m2 = h2.collect()
     assert d2["Out"] == golden["Out"]
     assert "Transfer_Overflow_Count" not in m2
-
-
-def test_async_copy_capability_probed_per_type_and_counted(
-    tmp_path, monkeypatch
-):
-    """An unsupported backend array type (no copy_to_host_async) falls
-    back to the synchronous fetch — the capability is cached per ARRAY
-    TYPE and counted in Transfer_AsyncCopyFallback_Count, results
-    identical."""
-    import jax.numpy as jnp
-
-    arr_type = type(jnp.zeros((1,), jnp.int32))
-    monkeypatch.setattr(
-        processor_mod, "_ASYNC_COPY_SUPPORT", {arr_type: False}
-    )
-    proc = _proc(tmp_path)
-    h = proc.dispatch_batch(proc.encode_rows(_rows(5), 0), 1000)
-    assert not h._prefetched
-    datasets, metrics = h.collect()
-    assert len(datasets["Out"]) == 5
-    assert metrics["Transfer_AsyncCopyFallback_Count"] == 1.0
-    # the probe result stayed cached for the type (no flip-flop)
-    assert processor_mod._ASYNC_COPY_SUPPORT[arr_type] is False
-
-
-def test_async_copy_fallback_counted_per_table(tmp_path, monkeypatch):
-    """When the counts vector streams but table arrays can't, each
-    affected TABLE counts one fallback (the old probe flagged once per
-    batch and assumed the counts probe covered table arrays too)."""
-    # counts_vec is a tiny vector; output table columns are >= 256 rows
-    monkeypatch.setattr(
-        processor_mod, "_async_copy_supported", lambda a: a.size <= 16
-    )
-    # a two-output transform so per-table counting shows
-    tmp_path.mkdir(parents=True, exist_ok=True)
-    t = tmp_path / "two.transform"
-    t.write_text(TWO_OUT_TRANSFORM)
-    d = {
-        "datax.job.name": "SizedFlow2",
-        "datax.job.input.default.blobschemafile": SCHEMA,
-        "datax.job.process.transform": str(t),
-        "datax.job.process.batchcapacity": "4096",
-    }
-    proc = FlowProcessor(
-        SettingDictionary(d), output_datasets=["Out", "Out2"]
-    )
-    h = proc.dispatch_batch(proc.encode_rows(_rows(5), 0), 1000)
-    assert not h._prefetched  # no table landed ahead of time
-    datasets, metrics = h.collect()
-    assert len(datasets["Out"]) == 5
-    assert len(datasets["Out2"]) == 5
-    assert metrics["Transfer_AsyncCopyFallback_Count"] == 2.0  # per table
 
 
 def test_pipeline_depth_conf_validation(tmp_path):

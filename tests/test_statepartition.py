@@ -226,21 +226,24 @@ def test_client_does_not_retry_definitive_answers():
 
 def test_compile_cache_fails_open_on_dead_store(monkeypatch, tmp_path):
     """Satellite posture #1: a dead shared store degrades the compile
-    cache to local-only — pull returns 0, push still counts local
-    misses, nothing raises (a cold compile beats a dead host)."""
+    cache to local-only — pull returns 0, a push that fails is
+    swallowed, nothing raises (a cold compile beats a dead host)."""
     import data_accelerator_tpu.serve.objectstore as om
 
     monkeypatch.setattr(om.time, "sleep", lambda s: None)
-    from data_accelerator_tpu.compile.aotcache import PersistentCompileCache
+    from data_accelerator_tpu.compile.aotcache import (
+        CACHE_DIR_ENV,
+        PersistentCompileCache,
+    )
 
-    cache = PersistentCompileCache(cache_dir=str(tmp_path / "cc"),
-                                   cache_url="objstore://dead.test:1/b/p")
+    monkeypatch.setenv(CACHE_DIR_ENV, str(tmp_path / "cc"))
+    cache = PersistentCompileCache("objstore://dead.test:1/b/p")
     flaky = _FlakyStore(always_fail=True)
     cache._client = _objstore(flaky)
     assert cache.pull() == 0  # swallowed
     (tmp_path / "cc").mkdir(exist_ok=True)
     (tmp_path / "cc" / "entry-cache").write_bytes(b"x")
-    assert cache.push() == 1  # counted locally, push failure swallowed
+    assert cache.push() == 1  # the new entry, its upload failure swallowed
 
 
 def test_state_store_fails_closed_on_dead_store(monkeypatch):

@@ -470,7 +470,6 @@ def test_post_profile_on_live_host_writes_capture_into_batch_trace(
             f"http://127.0.0.1:{port}/profile", timeout=10
         ) as r:
             state = json.loads(r.read())
-        assert state["available"] is True
         assert state["captures"] == 1
     finally:
         host.stop()
@@ -487,50 +486,24 @@ def test_post_profile_on_live_host_writes_capture_into_batch_trace(
     assert pts and pts[-1]["val"] == 1.0
 
 
-def test_profile_endpoint_noop_when_profiler_unavailable(
-    tmp_path, monkeypatch,
-):
-    """No-op posture: without jax.profiler the endpoint answers 501 and
-    the surface reports unavailable — never an exception."""
-    from data_accelerator_tpu.obs import profiler as prof_mod
+def test_profile_endpoint_501_when_surface_disabled():
+    """A host with the profiler surface conf'd OFF answers 501 — never
+    an exception."""
     from data_accelerator_tpu.obs.exposition import (
         HealthState,
         ObservabilityServer,
     )
 
-    monkeypatch.setattr(prof_mod, "profiler_available", lambda: False)
-    surface = prof_mod.ProfilerSurface(str(tmp_path / "p"), flow="f")
-    assert surface.available is False
-    assert "error" in surface.start(1.0)
-    srv = ObservabilityServer(
-        HealthState(flow="f"), port=0, profiler=surface
-    )
+    srv = ObservabilityServer(HealthState(flow="f"), port=0, profiler=None)
     srv.start()
     try:
         req = urllib.request.Request(
-            f"http://127.0.0.1:{srv.port}/profile?seconds=1",
-            data=b"", method="POST",
+            f"http://127.0.0.1:{srv.port}/profile", data=b"", method="POST",
         )
         with pytest.raises(urllib.error.HTTPError) as err:
             urllib.request.urlopen(req, timeout=10)
         assert err.value.code == 501
-        body = json.loads(err.value.read())
-        assert "unavailable" in body["error"]
-        # a host with the surface conf'd OFF answers 501 too
-        srv2 = ObservabilityServer(
-            HealthState(flow="f"), port=0, profiler=None
-        )
-        srv2.start()
-        try:
-            req2 = urllib.request.Request(
-                f"http://127.0.0.1:{srv2.port}/profile",
-                data=b"", method="POST",
-            )
-            with pytest.raises(urllib.error.HTTPError) as err2:
-                urllib.request.urlopen(req2, timeout=10)
-            assert err2.value.code == 501
-        finally:
-            srv2.stop()
+        assert "disabled" in json.loads(err.value.read())["error"]
     finally:
         srv.stop()
 

@@ -205,7 +205,7 @@ class TestPallasUdf:
             "--DataXQuery--\n"
             "T = SELECT deviceId, anomalyscore(temperature, deviceId) AS a "
             "FROM DataXProcessedInput",
-            {"anomalyscore": anomalyscore()},
+            {"anomalyscore": anomalyscore(interpret=True)},
             outputs=["T"],
         )
         datasets, _ = feed(proc, [1, 2], [1.0, 100.0], [1, 2])
@@ -213,6 +213,30 @@ class TestPallasUdf:
         # sigmoid(0)=0.5 at x==mu; saturates toward 1 as |x-mu| grows
         assert all(0.5 <= r["a"] <= 1.0 for r in rows)
         assert rows[1]["a"] > rows[0]["a"]
+
+    def test_default_is_the_compiled_kernel_never_the_interpreter(self):
+        """interpret is an explicit argument: on a host without a TPU
+        the default (Mosaic) build fails instead of quietly switching
+        to the interpreter."""
+        udf = anomalyscore()
+        assert udf.interpret is False
+        with pytest.raises(ValueError, match="interpret mode"):
+            udf.fn(jnp.zeros(8), jnp.zeros(8, jnp.int32))
+
+    @pytest.mark.parametrize("n", [3, 4096, 5000, 40000])
+    def test_ragged_and_multi_block_sizes_match_the_formula(self, n):
+        """Rows are padded to whole (32, 128)-tiled blocks and sliced
+        back: any batch size, one block or many, gives the formula's
+        value for every row."""
+        rng = np.random.RandomState(n)
+        x = rng.uniform(0, 100, n).astype(np.float32)
+        mu = rng.randint(1, 9, n).astype(np.int32)
+        udf = anomalyscore(interpret=True)
+        udf.block_rows = 8192
+        got = np.asarray(udf.fn(jnp.asarray(x), jnp.asarray(mu)))
+        want = 1.0 / (1.0 + np.exp(-(np.abs(x - mu) / (1.0 + np.abs(mu)))))
+        assert got.shape == (n,)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
 class TestConfLoading:
@@ -234,16 +258,16 @@ class TestConfLoading:
             "datax.job.input.default.blobschemafile": SCHEMA,
             "datax.job.process.transform": (
                 "--DataXQuery--\n"
-                "T = SELECT anomalyscore(temperature, deviceId) AS a "
+                "T = SELECT scaleby(temperature) AS a "
                 "FROM DataXProcessedInput"
             ),
             "datax.job.process.projection": "Raw.*",
-            "datax.job.process.jar.udf.anomalyscore.class":
-                "data_accelerator_tpu.udf.samples:anomalyscore",
+            "datax.job.process.jar.udf.scaleby.class":
+                "data_accelerator_tpu.udf.samples:scaleby",
         })
         proc = FlowProcessor(conf, batch_capacity=64, output_datasets=["T"])
         datasets, _ = feed(proc, [1], [50.0], [1])
-        assert 0.5 <= datasets["T"][0]["a"] <= 1.0
+        assert datasets["T"][0]["a"] == 100.0
 
     def test_class_path_instantiated(self):
         """A class (not factory) conf target must be instantiated."""
