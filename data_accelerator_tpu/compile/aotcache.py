@@ -28,7 +28,12 @@ Layering:
 
 ``Compile_Cache_{Hit,Miss}_Count`` report jax's own cache events
 (``/jax/compilation_cache/cache_hits`` / ``cache_misses``), counted
-process-wide by one ``jax.monitoring`` listener.
+process-wide by one ``jax.monitoring`` listener. The same listener keeps
+*which* program each was: jax times every backend compile (a load from
+the cache included) as ``/jax/core/compile/backend_compile_duration``
+with the function's name, and the hit or miss event fires inside that
+interval on the same thread; ``take_programs`` hands them to the batch
+that paid for them, which records one ``compile`` span each.
 """
 
 from __future__ import annotations
@@ -36,7 +41,8 @@ from __future__ import annotations
 import logging
 import os
 import threading
-from typing import List, Optional, Set, Tuple
+from collections import deque
+from typing import Dict, List, Optional, Set, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -61,19 +67,54 @@ class _CacheEvents:
     carry no attribution, so one listener counts for everyone and each
     ``PersistentCompileCache`` reports deltas against its own marks."""
 
+    # programs remembered between two takes; a start-up's AOT warm
+    # compiles a few dozen
+    KEEP_PROGRAMS = 256
+
     def __init__(self):
         self._lock = threading.Lock()
         self._registered = False
         self.hits = 0
         self.misses = 0
+        self.programs_total = 0
+        self._programs: deque = deque(maxlen=self.KEEP_PROGRAMS)
+        # "hit" | "miss" of the compile this thread is inside
+        self._compiling = threading.local()
 
     def _on_event(self, event: str, **_kwargs) -> None:
         if event == "/jax/compilation_cache/cache_hits":
+            self._compiling.cache = "hit"
             with self._lock:
                 self.hits += 1
         elif event == "/jax/compilation_cache/cache_misses":
+            self._compiling.cache = "miss"
             with self._lock:
                 self.misses += 1
+
+    def _on_time_span(
+        self, event: str, start_time: float, end_time: float, **kwargs
+    ) -> None:
+        if event != "/jax/core/compile/backend_compile_duration":
+            return
+        program = {
+            "fn": str(kwargs.get("fun_name", "")),
+            "startTs": start_time,
+            "ms": (end_time - start_time) * 1000.0,
+            # None: jax did not consult the persistent cache for it
+            "cache": getattr(self._compiling, "cache", None),
+        }
+        self._compiling.cache = None
+        with self._lock:
+            self.programs_total += 1
+            self._programs.append(program)
+
+    def programs_since(self, seen: int) -> Tuple[List[Dict], int]:
+        """(programs compiled or loaded after the first ``seen``, the
+        new total)."""
+        with self._lock:
+            fresh = min(self.programs_total - seen, len(self._programs))
+            return list(self._programs)[len(self._programs) - fresh:], \
+                self.programs_total
 
     def ensure_registered(self) -> None:
         with self._lock:
@@ -83,6 +124,7 @@ class _CacheEvents:
         import jax.monitoring
 
         jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_time_span_listener(self._on_time_span)
 
     def totals(self) -> Tuple[int, int]:
         with self._lock:
@@ -125,6 +167,7 @@ class PersistentCompileCache:
         self.dir = resolve_cache_dir()
         self._baseline: Set[str] = set()
         self._seen = (0, 0)
+        self._seen_programs = 0
 
     # -- local entries ---------------------------------------------------
     def _entries(self) -> List[str]:
@@ -154,10 +197,23 @@ class PersistentCompileCache:
             # effect.
             jax.config.update("jax_compilation_cache_dir", self.dir)
             compilation_cache.reset_cache()
+        # the executable carries the op metadata a device trace is read
+        # by (the step's ``dx.<stage>`` scopes, source lines). jax leaves
+        # metadata out of the key by default; a start would then load an
+        # executable compiled before a scope was added or renamed, and
+        # the trace would show the old names
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+        # ...and an operation's location is its own source line under its
+        # scope, not the call stack above it: the key must not move with
+        # the line a host was constructed on. (The frame limit, not
+        # jax_include_full_tracebacks_in_locations=False: with that the
+        # TPU compiler drops the scope from op_name.)
+        jax.config.update("jax_traceback_in_locations_limit", 1)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         _EVENTS.ensure_registered()
         self._seen = _EVENTS.totals()
+        self._seen_programs = _EVENTS.programs_total
         self._baseline = set(self._entries())
 
     def take_counts(self) -> Tuple[int, int]:
@@ -168,6 +224,15 @@ class PersistentCompileCache:
         seen_h, seen_m = self._seen
         self._seen = (hits, misses)
         return hits - seen_h, misses - seen_m
+
+    def take_programs(self) -> List[Dict]:
+        """``{fn, startTs, ms, cache}`` of every program jax compiled
+        (``cache`` "miss") or loaded from the persistent cache ("hit")
+        in this process since ``enable()`` or the previous take."""
+        programs, self._seen_programs = _EVENTS.programs_since(
+            self._seen_programs
+        )
+        return programs
 
     # -- shared layer ----------------------------------------------------
     def _key(self, fn: str) -> str:

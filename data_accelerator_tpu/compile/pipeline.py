@@ -19,6 +19,8 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import jax
+
 from ..core.config import EngineException
 from ..core.schema import StringDictionary
 from .planner import (
@@ -65,7 +67,10 @@ class Pipeline:
                 )
             env["__aux"] = {}
         for view in self.views:
-            env[view.name] = view.fn(env, base_s, now_rel_ms)
+            # the view's name on every operation it lowers to: a device
+            # trace splits the step's time by view (metadata only)
+            with jax.named_scope(f"dx.view.{view.name}"):
+                env[view.name] = view.fn(env, base_s, now_rel_ms)
         return env
 
     def schema_of(self, name: str) -> ViewSchema:

@@ -141,6 +141,9 @@ class MetricName:
         r"Decode_Shards",
         r"Decode_RowsPerSec",
         r"Decode_BufferReuse_Count",
+        # source lag inside the program (runtime/host.py _traced_poll):
+        # rows the sources still held after the batch's poll
+        r"Source_Backlog_Rows",
         r"Output_[A-Za-z0-9_.]+_Events_Count",
         r"Output_[A-Za-z0-9_.]+_(GroupsDropped|JoinRowsDropped)",
         r"Sink_[a-z]+",

@@ -18,10 +18,12 @@ rule table with firing state; ``alerts --validate rules.json``
 schema-checks a rule file (obs/alerts.py RULE_SCHEMA) and exits
 non-zero on errors.
 
-``python -m data_accelerator_tpu.obs profile <url> [--seconds N]``
-POSTs ``/profile?seconds=N`` on a live host's observability port —
-the on-demand jax profiler surface (obs/profiler.py) — and prints the
-capture path the host returned.
+``python -m data_accelerator_tpu.obs profile <url> [--seconds N]
+[--python]`` POSTs ``/profile?seconds=N`` on a live host's
+observability port — the on-demand jax profiler surface
+(obs/profiler.py) — and prints the capture path the host returned.
+``--python`` (``&python=1``) adds Python frames to the capture, at the
+traced host's cost.
 
 ``python -m data_accelerator_tpu.obs spans [--aggregate] [--file F]``
 reads the flight recorder's span records; with ``--aggregate`` it
@@ -369,7 +371,9 @@ def cmd_profile(args) -> int:
     url = (
         args.url.rstrip("/")
         + "/profile?"
-        + urllib.parse.urlencode({"seconds": args.seconds})
+        + urllib.parse.urlencode(
+            {"seconds": args.seconds, **({"python": 1} if args.python else {})}
+        )
     )
     try:
         req = urllib.request.Request(url, data=b"", method="POST")
@@ -538,6 +542,11 @@ def main(argv=None) -> int:
     pp.add_argument(
         "--seconds", type=float, default=5.0,
         help="capture window in seconds (default 5)",
+    )
+    pp.add_argument(
+        "--python", action="store_true",
+        help="also trace Python frames (slows the traced host; off by "
+             "default)",
     )
     pp.add_argument("--json", action="store_true", help="raw JSON payload")
     fp = sub.add_parser(

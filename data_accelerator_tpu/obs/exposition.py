@@ -474,6 +474,7 @@ class ObservabilityServer:
                     )
                     return
                 seconds = None
+                python = False
                 for part in query.split("&"):
                     k, _, v = part.partition("=")
                     if k == "seconds":
@@ -481,10 +482,13 @@ class ObservabilityServer:
                             seconds = float(v)
                         except ValueError:
                             pass
+                    elif k == "python":
+                        python = v == "1"
                 from .profiler import DEFAULT_SECONDS
 
                 result = obs.profiler.start(
-                    seconds if seconds is not None else DEFAULT_SECONDS
+                    seconds if seconds is not None else DEFAULT_SECONDS,
+                    python=python,
                 )
                 status = 200 if "error" not in result else 409
                 self._send(

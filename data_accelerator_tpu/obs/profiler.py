@@ -9,6 +9,12 @@ and the capture lands beside the flight recorder —
 - ``POST <host>/profile?seconds=N`` (obs/exposition.py) starts a
   capture and returns its path immediately; a timer thread stops the
   trace when the window closes.
+- a capture holds the device planes and the host's ``dx/<span>``
+  annotations (obs/tracing.py), NOT Python frames: jax's Python tracer
+  is all but 0.1 % of a capture's bytes, slows the traced host's Python
+  and stalls the loop for seconds while the file is written.
+  ``&python=1`` (CLI ``--python``) turns it back on for the operator
+  who wants the frames and pays for them.
 - every finished capture is drained by the streaming host at the next
   batch finish and recorded as a ``profiler/capture`` span inside that
   batch's trace (so ``obs trace <batch>`` shows exactly which capture
@@ -55,11 +61,12 @@ class ProfilerSurface:
         with self._lock:
             return dict(self._active) if self._active else None
 
-    def start(self, seconds: float = DEFAULT_SECONDS) -> dict:
+    def start(self, seconds: float = DEFAULT_SECONDS,
+              python: bool = False) -> dict:
         """Arm a capture for ``seconds``; returns
         ``{path, seconds, active}`` or ``{error}`` (already capturing /
         start failed). The path is returned immediately so the caller
-        can watch it fill."""
+        can watch it fill. ``python``: also trace Python frames."""
         seconds = min(max(float(seconds), 0.1), MAX_SECONDS)
         with self._lock:
             if self._active is not None:
@@ -74,14 +81,18 @@ class ProfilerSurface:
             os.makedirs(path, exist_ok=True)
             import jax
 
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python else 0
+            options.host_tracer_level = 2
             try:
-                jax.profiler.start_trace(path)
+                jax.profiler.start_trace(path, profiler_options=options)
             except Exception as e:  # noqa: BLE001 — diagnostics only
                 logger.warning("profiler start failed: %s", e)
                 return {"error": f"profiler start failed: {e}"}
             self._active = {
                 "path": path,
                 "seconds": seconds,
+                "python": bool(python),
                 "startedTs": time.time(),
             }
             self._timer = threading.Timer(seconds, self._stop_timed)
@@ -90,7 +101,8 @@ class ProfilerSurface:
             logger.info(
                 "profiler capture armed for %.1fs -> %s", seconds, path
             )
-            return {"path": path, "seconds": seconds, "active": True}
+            return {"path": path, "seconds": seconds,
+                    "python": bool(python), "active": True}
 
     def _stop_timed(self) -> None:
         try:

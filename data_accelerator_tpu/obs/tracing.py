@@ -28,6 +28,14 @@ Design notes:
 - Every finished span also feeds the per-stage latency histograms
   (obs/histogram.py) when the tracer holds a registry — spans and
   histograms cannot disagree because they share the one measurement.
+- One clock: every span opened through ``_child`` is also a
+  ``jax.profiler.TraceAnnotation`` named ``dx/<span name>`` for its
+  duration, so an on-demand capture (obs/profiler.py) holds the host's
+  stages on the same clock as the device's ``XLA Ops`` line. A TraceMe
+  is a flag test while no capture runs. Spans whose two ends are seen
+  at different call sites (``record``/``record_since``: ``device-step``,
+  ``profiler/capture``, ``compile``) get none — the device's own line
+  is their truth.
 """
 
 from __future__ import annotations
@@ -36,6 +44,7 @@ import contextlib
 import itertools
 import os
 import struct
+import sys
 import threading
 import time
 from typing import Dict, Iterator, Optional
@@ -79,6 +88,18 @@ def parse_parent(text: Optional[str]):
     if not trace_id or not span_id:
         return None
     return trace_id, span_id
+
+
+def annotation(name: str, **stats):
+    """``dx/<name>`` on the profiler's host plane for the length of a
+    ``with`` block; a no-op context where jax is not loaded (the
+    control plane pins itself to CPU and must not pay the import: no
+    capture can run in a process that never imported jax)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    cls = getattr(profiler, "TraceAnnotation", None)
+    if cls is None:
+        return contextlib.nullcontext()
+    return cls("dx/" + name, **stats)
 
 
 def current_trace() -> Optional["TraceContext"]:
@@ -161,6 +182,10 @@ class TraceContext:
         # different call sites (e.g. device-step: dispatch return ->
         # completion sync)
         self.marks: Dict[str, tuple] = {}
+        # numbers one stage measured that belong on the batch's end
+        # event (the poll's Source_Backlog_Rows): the trace is what
+        # travels with a batch from its poll to its tail
+        self.counters: Dict[str, float] = {}
 
     # -- root ------------------------------------------------------------
     def add(self, **props) -> None:
@@ -241,7 +266,10 @@ class TraceContext:
             stack.append((self, span_id))
             pushed = True
         try:
-            yield
+            with annotation(
+                name, batch=self._props.get("batchTime", self.trace_id)
+            ):
+                yield
         finally:
             if pushed:
                 stack.pop()

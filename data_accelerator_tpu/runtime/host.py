@@ -447,9 +447,10 @@ class StreamingHost:
             received = max_events
             malformed0 = self.processor.malformed_rows_total
             if isinstance(src, LocalSource):
-                cols, now_ms, c = src.poll_columns(
-                    max_events, self.processor.dictionary
-                )
+                with tracing.span("source-poll"):
+                    cols, now_ms, c = src.poll_columns(
+                        max_events, self.processor.dictionary
+                    )
                 raw[name] = self.processor.encode_columns(
                     cols, max_events, source=name
                 )
@@ -467,7 +468,8 @@ class StreamingHost:
                 # matrix stays numpy (to_device=False) so the
                 # decode-ahead worker never touches jax off-thread —
                 # the jitted step's call transfers it
-                blob, _n, c = src.poll_raw(max_events)
+                with tracing.span("source-poll"):
+                    blob, _n, c = src.poll_raw(max_events)
                 received = _n
                 raw[name] = self.processor.encode_json_bytes(
                     blob, (batch_time_ms // 1000) * 1000, source=name,
@@ -475,7 +477,8 @@ class StreamingHost:
                     fmt=getattr(src, "raw_format", "jsonl"),
                 )
             else:
-                rows, c = src.poll(max_events)
+                with tracing.span("source-poll"):
+                    rows, c = src.poll(max_events)
                 received = len(rows)
                 raw[name] = self.processor.encode_rows(
                     rows, (batch_time_ms // 1000) * 1000, source=name
@@ -672,148 +675,150 @@ class StreamingHost:
             logger.exception("batch processing failed; rethrowing for retry")
             raise
 
-        metrics["Latency-Batch"] = (time.time() - t0) * 1000.0
-        metrics["IngestRateScale"] = self._rate_scale
-        metrics["Pipeline_Depth"] = float(inflight_depth)
-        metrics["Pipeline_Stall_Ms"] = stall_ms
-        if backlog is not None:
-            # background landing accounting: landings still queued when
-            # this one was submitted (sustained > pipeline depth is the
-            # default backlog alert), and the ms this batch's streamed
-            # tables took to resolve on the landing thread
-            metrics["Transfer_Background_Pending"] = float(backlog)
-            metrics["Transfer_Background_LandMs"] = land_ms
-        self.health.record_stall(stall_ms)
-        # the calibrated machine profile rides every batch as Calib_*
-        # gauges (constant per process — dashboards see the machine
-        # model their roofline ratios are judged against)
-        if self._calib_metrics:
-            metrics.update(self._calib_metrics)
-        # live HBM watermark (DX522's observation): the device
-        # allocator's in-use/peak bytes, absent on backends that don't
-        # report memory stats
-        if self.hbm_sample:
-            hbm = self.processor.device_memory_stats()
-            if hbm is not None:
-                metrics["Hbm_BytesInUse"] = float(
-                    hbm.get("bytes_in_use") or 0.0
-                )
-                metrics["Hbm_PeakBytes"] = float(
-                    hbm.get("peak_bytes_in_use") or 0.0
-                )
-        # per-stage latency percentiles from the live histograms — the
-        # DATAX-<flow>:Latency-<Stage>-pNN series the dashboard's stat
-        # tiles and stage timechart read (obs/histogram.py keeps these
-        # exact over a bounded recent-sample window). Merged BEFORE the
-        # conformance pass: the DX520 stage-time check judges the same
-        # p50 series the dashboards render.
-        for stage in MetricName.STAGES:
-            stem = MetricName.stage_metric(stage)
-            for q in (50, 95, 99):
-                v = HISTOGRAMS.percentile(self.health.flow, stage, q)
-                if v is not None:
-                    metrics[f"{stem}-p{q}"] = v
-        # model-vs-observed conformance: ratio gauges join this batch's
-        # metrics; drift transitions become typed flight-recorder events
-        # and store rows (obs/conformance.py)
-        if self.conformance is not None:
-            gauges, drift_events = self.conformance.observe(
-                metrics, batch_time_ms
-            )
-            metrics.update(gauges)
-            for ev in drift_events:
-                props = ev.to_props()
-                self.telemetry.track_event("conformance/drift", props)
-                self.metric_logger.send_metric_events(
-                    "Conformance_Drift", [props], batch_time_ms
-                )
-                logger.warning(
-                    "conformance drift %s: %s", ev.code, ev.message
-                )
-        # finished profiler captures stitch into THIS batch's trace as
-        # span events (the capture path is then one `obs trace` away
-        # from the batches it overlapped) and bump the capture counter
-        if self.profiler is not None:
-            for cap in self.profiler.drain_finished():
-                trace.record(
-                    "profiler/capture", cap["startedTs"],
-                    cap.get("durationMs") or 0.0, path=cap["path"],
-                )
-            if self.profiler.captures_count:
-                metrics["Profiler_Captures_Count"] = float(
-                    self.profiler.captures_count
-                )
-        if pm is not None:
-            # Protocol_Events_Count for this batch's recorded prefix
-            # (the post-ack checkpoint trio drains on the next batch)
-            metrics.update(pm.drain_metric_deltas())
-        if self.batches_processed == 0:
-            # what this job actually runs on, flight-recorded with its
-            # first batch: a reader of the recorder (chip_smoke.py, an
-            # operator) learns platform, placement and decode engine
-            # from the process that holds the chip, not from its own
-            self.telemetry.track_event(
-                "host/devices", self._device_report()
-            )
-        # the batch's whole metric set rides the end event, so the
-        # flight recorder alone reconstructs per-batch counts
-        self.telemetry.batch_end(
-            batch_time_ms,
-            {"latencyMs": metrics["Latency-Batch"], **metrics},
-        )
-        self.metric_logger.send_batch_metrics(metrics, batch_time_ms)
-        # alert evaluation AFTER the store flush so window aggregates
-        # include this batch; the firing set rides the health payload
-        # (readyz) and the Alerts_Firing series
-        firing: List[dict] = []
-        if self.alerts is not None:
-            firing = self.alerts.evaluate()
-            self.health.record_alerts(firing)
-            self.metric_logger.send_metric(
-                "Alerts_Firing", float(len(firing)), batch_time_ms
-            )
-        # fleet telemetry frame accumulation (obs/publisher.py): the
-        # acked batch's metric deltas + consumed offset ranges fold
-        # into the open window; record_batch is fail-open and
-        # thread-safe (this tail may run on the landing thread)
-        if self.fleet_publisher is not None:
-            self.fleet_publisher.record_batch(
-                metrics, consumed, batch_time_ms,
-                health=self.health.health(), alerts=firing,
-            )
-        logger.info(
-            "batch %d: %s",
-            self.batches_processed + 1,
-            " ".join(f"{k}={v:.1f}" for k, v in sorted(metrics.items())),
-        )
-        # DX53x state events (load fallback / both-sides-bad) land in
-        # the flight recorder like conformance drift — typed, greppable
-        self._drain_state_events()
-        # runtime DX805: buffer-sanitizer poison hits join the recorder
-        # the same way (and the Sanitizer_PoisonHit metric event stream)
-        san = self.processor.buffer_sanitizer
-        if san is not None:
-            for ev in san.drain_events():
-                try:
-                    self.telemetry.track_event("sanitizer/poison", ev)
-                    self.metric_logger.send_metric_events(
-                        "Sanitizer_PoisonHit", [ev], batch_time_ms
+        # everything the batch reports about itself, on its critical
+        # path: metrics, percentiles, conformance, the recorder's end
+        # event, the store flush, alerts, the fleet publisher, the log line
+        with trace.activate(), tracing.span("emit"):
+            metrics["Latency-Batch"] = (time.time() - t0) * 1000.0
+            metrics["IngestRateScale"] = self._rate_scale
+            metrics["Pipeline_Depth"] = float(inflight_depth)
+            metrics["Pipeline_Stall_Ms"] = stall_ms
+            metrics.update(trace.counters)
+            if backlog is not None:
+                # background landing accounting: landings still queued when
+                # this one was submitted (sustained > pipeline depth is the
+                # default backlog alert), and the ms this batch's streamed
+                # tables took to resolve on the landing thread
+                metrics["Transfer_Background_Pending"] = float(backlog)
+                metrics["Transfer_Background_LandMs"] = land_ms
+            self.health.record_stall(stall_ms)
+            # the calibrated machine profile rides every batch as Calib_*
+            # gauges (constant per process — dashboards see the machine
+            # model their roofline ratios are judged against)
+            if self._calib_metrics:
+                metrics.update(self._calib_metrics)
+            # live HBM watermark (DX522's observation): the device
+            # allocator's in-use/peak bytes, absent on backends that don't
+            # report memory stats
+            if self.hbm_sample:
+                hbm = self.processor.device_memory_stats()
+                if hbm is not None:
+                    metrics["Hbm_BytesInUse"] = float(
+                        hbm.get("bytes_in_use") or 0.0
                     )
-                except Exception:  # noqa: BLE001 — telemetry never kills a batch
-                    logger.exception("sanitizer event emit failed")
-                logger.warning("buffer sanitizer %s", ev.get("message"))
-        # runtime DX906: protocol-monitor ordering violations from
-        # previously sealed batches join the recorder the same way
-        if pm is not None:
-            for ev in pm.drain_events():
-                try:
-                    self.telemetry.track_event("protocol/violation", ev)
-                    self.metric_logger.send_metric_events(
-                        "Protocol_Violation", [ev], batch_time_ms
+                    metrics["Hbm_PeakBytes"] = float(
+                        hbm.get("peak_bytes_in_use") or 0.0
                     )
-                except Exception:  # noqa: BLE001 — telemetry never kills a batch
-                    logger.exception("protocol event emit failed")
-                logger.warning("protocol monitor %s", ev.get("message"))
+            # per-stage latency percentiles from the live histograms — the
+            # DATAX-<flow>:Latency-<Stage>-pNN series the dashboard's stat
+            # tiles and stage timechart read (obs/histogram.py keeps these
+            # exact over a bounded recent-sample window). Merged BEFORE the
+            # conformance pass: the DX520 stage-time check judges the same
+            # p50 series the dashboards render.
+            for stage in MetricName.STAGES:
+                stem = MetricName.stage_metric(stage)
+                for q in (50, 95, 99):
+                    v = HISTOGRAMS.percentile(self.health.flow, stage, q)
+                    if v is not None:
+                        metrics[f"{stem}-p{q}"] = v
+            # model-vs-observed conformance: ratio gauges join this batch's
+            # metrics; drift transitions become typed flight-recorder events
+            # and store rows (obs/conformance.py)
+            if self.conformance is not None:
+                gauges, drift_events = self.conformance.observe(
+                    metrics, batch_time_ms
+                )
+                metrics.update(gauges)
+                for ev in drift_events:
+                    props = ev.to_props()
+                    self.telemetry.track_event("conformance/drift", props)
+                    self.metric_logger.send_metric_events(
+                        "Conformance_Drift", [props], batch_time_ms
+                    )
+                    logger.warning(
+                        "conformance drift %s: %s", ev.code, ev.message
+                    )
+            # finished profiler captures stitch into THIS batch's trace as
+            # span events (the capture path is then one `obs trace` away
+            # from the batches it overlapped) and bump the capture counter
+            if self.profiler is not None:
+                for cap in self.profiler.drain_finished():
+                    trace.record(
+                        "profiler/capture", cap["startedTs"],
+                        cap.get("durationMs") or 0.0, path=cap["path"],
+                    )
+                if self.profiler.captures_count:
+                    metrics["Profiler_Captures_Count"] = float(
+                        self.profiler.captures_count
+                    )
+            if pm is not None:
+                # Protocol_Events_Count for this batch's recorded prefix
+                # (the post-ack checkpoint trio drains on the next batch)
+                metrics.update(pm.drain_metric_deltas())
+            if self.batches_processed == 0:
+                # what this job actually runs on, flight-recorded with its
+                # first batch: a reader of the recorder (chip_smoke.py, an
+                # operator) learns platform, placement and decode engine
+                # from the process that holds the chip, not from its own
+                self.telemetry.track_event(
+                    "host/devices", self._device_report()
+                )
+            # the batch's whole metric set rides the end event, so the
+            # flight recorder alone reconstructs per-batch counts
+            self.telemetry.batch_end(batch_time_ms, metrics)
+            self.metric_logger.send_batch_metrics(metrics, batch_time_ms)
+            # alert evaluation AFTER the store flush so window aggregates
+            # include this batch; the firing set rides the health payload
+            # (readyz) and the Alerts_Firing series
+            firing: List[dict] = []
+            if self.alerts is not None:
+                firing = self.alerts.evaluate()
+                self.health.record_alerts(firing)
+                self.metric_logger.send_metric(
+                    "Alerts_Firing", float(len(firing)), batch_time_ms
+                )
+            # fleet telemetry frame accumulation (obs/publisher.py): the
+            # acked batch's metric deltas + consumed offset ranges fold
+            # into the open window; record_batch is fail-open and
+            # thread-safe (this tail may run on the landing thread)
+            if self.fleet_publisher is not None:
+                self.fleet_publisher.record_batch(
+                    metrics, consumed, batch_time_ms,
+                    health=self.health.health(), alerts=firing,
+                )
+            logger.info(
+                "batch %d: %s",
+                self.batches_processed + 1,
+                " ".join(f"{k}={v:.1f}" for k, v in sorted(metrics.items())),
+            )
+            # DX53x state events (load fallback / both-sides-bad) land in
+            # the flight recorder like conformance drift — typed, greppable
+            self._drain_state_events()
+            # runtime DX805: buffer-sanitizer poison hits join the recorder
+            # the same way (and the Sanitizer_PoisonHit metric event stream)
+            san = self.processor.buffer_sanitizer
+            if san is not None:
+                for ev in san.drain_events():
+                    try:
+                        self.telemetry.track_event("sanitizer/poison", ev)
+                        self.metric_logger.send_metric_events(
+                            "Sanitizer_PoisonHit", [ev], batch_time_ms
+                        )
+                    except Exception:  # noqa: BLE001 — telemetry never kills a batch
+                        logger.exception("sanitizer event emit failed")
+                    logger.warning("buffer sanitizer %s", ev.get("message"))
+            # runtime DX906: protocol-monitor ordering violations from
+            # previously sealed batches join the recorder the same way
+            if pm is not None:
+                for ev in pm.drain_events():
+                    try:
+                        self.telemetry.track_event("protocol/violation", ev)
+                        self.metric_logger.send_metric_events(
+                            "Protocol_Violation", [ev], batch_time_ms
+                        )
+                    except Exception:  # noqa: BLE001 — telemetry never kills a batch
+                        logger.exception("protocol event emit failed")
+                    logger.warning("protocol monitor %s", ev.get("message"))
         # dx-proto: post-commit at-least-once replay cursor: the window
         # snapshot + offset commit run AFTER the ack on purpose — a
         # crash between ack and checkpoint replays from the previous
@@ -900,7 +905,16 @@ class StreamingHost:
         runs this on the decode-ahead worker thread, so the span needs
         explicit activation there)."""
         with trace.activate(), tracing.span("decode"):
-            return self._poll_and_encode()
+            polled = self._poll_and_encode()
+        backlog = [
+            s.backlog_rows for s in self.sources.values()
+            if s.backlog_rows is not None
+        ]
+        if backlog:
+            # source lag, inside the program: rows the sources still
+            # held when this batch's poll returned
+            trace.counters["Source_Backlog_Rows"] = float(sum(backlog))
+        return polled
 
     def _dispatch_traced(self, trace, raw, batch_time_ms):
         """Dispatch under the batch's trace, marking the dispatch-done
@@ -967,7 +981,10 @@ class StreamingHost:
                     break
                 sleep = self.interval_s - (time.time() - start)
                 if sleep > 0:
-                    time.sleep(sleep)
+                    # pacing, measured in a capture instead of inferred
+                    # from the hole between two batches
+                    with tracing.annotation("pace"):
+                        time.sleep(sleep)
         finally:
             self._stop_profiler()
 

@@ -43,6 +43,7 @@ from ..compile.sqlparser import parse_select
 from ..compile.transform_parser import TransformParser
 from ..constants import ColumnName, DatasetName
 from ..core.config import EngineException, SettingDictionary, SettingNamespace
+from ..obs.tracing import current_trace as _current_trace
 from ..obs.tracing import span as _trace_span
 from ..core.schema import ColType, Schema, StringDictionary
 from .materialize import materialize_rows
@@ -350,44 +351,51 @@ def build_step_fn(
         delta_ms: jnp.ndarray,
         aux: Dict[str, jnp.ndarray],
     ):
+        # Every stage runs under a ``jax.named_scope`` (``dx.<stage>``):
+        # metadata only, the compiled program is the same, but a device
+        # trace then says which stage an operation belongs to.
         # 1. per-source projection into its target table (each source
         # gets its own env so `Raw` binds to ITS raw table)
         projected: Dict[str, TableData] = {}
         for sname_, target_ in source_targets:
-            rt = raw[sname_]
-            if isinstance(rt, PackedRaw):
-                rt = rt.unpack()  # split the single-transfer matrix
-            env: Dict[str, TableData] = {
-                "Raw": rt,
-                DatasetName.DataStreamRaw: rt,
-                "__aux": aux,
-            }
-            for v in proj_views[sname_]:
-                env[v.name] = v.fn(env, base_s, now_rel_ms)
-            projected[target_] = env[target_]
+            with jax.named_scope(f"dx.project.{sname_}"):
+                rt = raw[sname_]
+                if isinstance(rt, PackedRaw):
+                    rt = rt.unpack()  # split the single-transfer matrix
+                env: Dict[str, TableData] = {
+                    "Raw": rt,
+                    DatasetName.DataStreamRaw: rt,
+                    "__aux": aux,
+                }
+                for v in proj_views[sname_]:
+                    env[v.name] = v.fn(env, base_s, now_rel_ms)
+                projected[target_] = env[target_]
 
         # 2. ring updates (one ring per windowed table; each ring's
         # slot index derives from the shared batch counter)
         new_rings: Dict[str, WindowBuffers] = {}
-        for table in ring_tables:
-            buf = rings[table]
-            slot = jax.lax.rem(
-                counter, jnp.asarray(buf.valid.shape[0], jnp.int32)
-            )
-            new_rings[table] = update_buffers(
-                buf, projected[table], slot, delta_ms, ts_col
-            )
+        with jax.named_scope("dx.ring"):
+            for table in ring_tables:
+                buf = rings[table]
+                slot = jax.lax.rem(
+                    counter, jnp.asarray(buf.valid.shape[0], jnp.int32)
+                )
+                new_rings[table] = update_buffers(
+                    buf, projected[table], slot, delta_ms, ts_col
+                )
 
         tables: Dict[str, TableData] = dict(projected)
-        for wname, (table, dur_s) in windows.items():
-            tables[wname] = window_table(
-                new_rings[table], int(dur_s * 1000), now_rel_ms, ts_col
-            )
+        with jax.named_scope("dx.window"):
+            for wname, (table, dur_s) in windows.items():
+                tables[wname] = window_table(
+                    new_rings[table], int(dur_s * 1000), now_rel_ms, ts_col
+                )
         for rname in refdata_names:
             tables[rname] = refdata[rname]
         for sname in state_names:
             tables[sname] = state[sname]
 
+        # each view under ``dx.view.<name>`` (compile/pipeline.py)
         out = pipeline.run(tables, base_s, now_rel_ms, aux=aux)
 
         new_state = {n: out.get(n, state[n]) for n in state_names}
@@ -399,32 +407,36 @@ def build_step_fn(
         from ..ops.compact import compact_indices
 
         datasets = {}
-        counts = [projected[primary_target].count()]
+        with jax.named_scope("dx.counts"):
+            counts = [projected[primary_target].count()]
         for n in output_datasets:
             t = out[n]
-            idx, ov = compact_indices(t.valid, t.valid.shape[0])
-            datasets[n] = TableData(
-                {c: v[idx] if v.shape[:1] == t.valid.shape else v
-                 for c, v in t.cols.items()},
-                ov,
-            )
-            counts.append(t.count())
-        # fixed layout: per output one groups-overflow then one
-        # join-overflow slot; -1 marks "output does not track this
-        # overflow" so the host can keep emitting 0 for ones that do
-        for key in ("__overflow.groups", "__overflow.joins"):
-            for n in output_datasets:
-                counts.append(
-                    out[n].cols[key][0]
-                    if key in out[n].cols
-                    else jnp.asarray(-1, jnp.int32)
+            with jax.named_scope(f"dx.compact.{n}"):
+                idx, ov = compact_indices(t.valid, t.valid.shape[0])
+                datasets[n] = TableData(
+                    {c: v[idx] if v.shape[:1] == t.valid.shape else v
+                     for c, v in t.cols.items()},
+                    ov,
                 )
-        # per-target projected input counts (multi-source metrics)
-        for _sname, target_ in source_targets:
-            counts.append(projected[target_].count())
-        counts_vec = jnp.stack(
-            [jnp.asarray(c, jnp.int32) for c in counts]
-        )
+            with jax.named_scope("dx.counts"):
+                counts.append(t.count())
+        with jax.named_scope("dx.counts"):
+            # fixed layout: per output one groups-overflow then one
+            # join-overflow slot; -1 marks "output does not track this
+            # overflow" so the host can keep emitting 0 for ones that do
+            for key in ("__overflow.groups", "__overflow.joins"):
+                for n in output_datasets:
+                    counts.append(
+                        out[n].cols[key][0]
+                        if key in out[n].cols
+                        else jnp.asarray(-1, jnp.int32)
+                    )
+            # per-target projected input counts (multi-source metrics)
+            for _sname, target_ in source_targets:
+                counts.append(projected[target_].count())
+            counts_vec = jnp.stack(
+                [jnp.asarray(c, jnp.int32) for c in counts]
+            )
         # plain tuple of pytrees for the jit boundary
         return (datasets, new_rings, new_state, counts_vec)
 
@@ -1800,31 +1812,33 @@ class FlowProcessor:
             self._ingest_col_rows[spec.name] = col_rows
         valid_row = len(layout)
         mat = pool.acquire()
-        t0 = time.perf_counter()
         try:
-            if fmt == "kafka-v2":
-                rows, kstats = decoder.decode_kafka_packed(
-                    data, mat, col_rows, valid_row, base_ms, max_rows=cap
-                )
-                self._count_ingest(
-                    "malformed_rows", kstats["malformed"], malformed=True
-                )
-                self._count_ingest("CorruptBatch", kstats["corrupt_batches"])
-                # records that arrived without a row slot are LOST data
-                # (a producer batch larger than the flow capacity) —
-                # loud, never silent
-                self._count_ingest(
-                    "kafka_overflow_rows", kstats["overflow_dropped"]
-                )
-            else:
-                rows, consumed = decoder.decode_packed(
-                    data, mat, col_rows, valid_row, base_ms, max_rows=cap
-                )
-                self._count_jsonl_malformed(data, consumed, rows)
+            # the interval Decode_RowsPerSec times, as a span
+            with _trace_span("native-decode"):
+                t0 = time.perf_counter()
+                if fmt == "kafka-v2":
+                    rows, kstats = decoder.decode_kafka_packed(
+                        data, mat, col_rows, valid_row, base_ms, max_rows=cap
+                    )
+                    self._count_ingest(
+                        "malformed_rows", kstats["malformed"], malformed=True
+                    )
+                    self._count_ingest("CorruptBatch", kstats["corrupt_batches"])
+                    # records that arrived without a row slot are LOST data
+                    # (a producer batch larger than the flow capacity) —
+                    # loud, never silent
+                    self._count_ingest(
+                        "kafka_overflow_rows", kstats["overflow_dropped"]
+                    )
+                else:
+                    rows, consumed = decoder.decode_packed(
+                        data, mat, col_rows, valid_row, base_ms, max_rows=cap
+                    )
+                    self._count_jsonl_malformed(data, consumed, rows)
+                dt = time.perf_counter() - t0
         except Exception:
             pool.release(mat)
             raise
-        dt = time.perf_counter() - t0
         self.last_decoder_path = "native-sharded"
         self._decode_shards = decoder.last_shards
         if dt > 0 and rows:
@@ -2726,8 +2740,7 @@ class PendingBatch:
         most once per batch."""
         if self._counts is not None:
             return self._counts
-        with _trace_span("sync-counts"):
-            counts = np.asarray(self.counts_vec)
+        counts = np.asarray(self.counts_vec)
         # unpack in PACKING order (snapshotted at dispatch) — jax returns
         # dict pytrees with sorted keys, so iterating out_datasets may
         # not match the order the step packed counts in
@@ -2970,6 +2983,15 @@ class PendingBatch:
             if hits or misses:
                 metrics["Compile_Cache_Hit_Count"] = float(hits)
                 metrics["Compile_Cache_Miss_Count"] = float(misses)
+            # ...and WHICH programs: one `compile` span each under the
+            # batch that paid for them
+            trace = _current_trace()
+            for prog in proc._compile_cache.take_programs():
+                if trace is not None:
+                    trace.record(
+                        "compile", prog["startTs"], prog["ms"],
+                        fn=prog["fn"], cache=prog["cache"],
+                    )
         if proc.compile_stats:
             for k, v in proc.compile_stats.items():
                 metrics[f"Compile_{k}"] = float(v)

@@ -70,6 +70,9 @@ class StreamingSource:
     """Interface: poll() returns (rows, consumed offsets)."""
 
     name: str = "source"
+    # rows left in the source after the latest poll (the host reports
+    # it as Source_Backlog_Rows); None: the source has no notion of it
+    backlog_rows: Optional[int] = None
 
     def start(self, positions: Dict[Tuple[str, int], int]) -> None:
         """Apply checkpointed starting positions (source, partition)->seq."""
@@ -273,6 +276,7 @@ class SocketSource(StreamingSource):
             with self._lock:
                 lines = self._buf[:max_events]
                 self._buf = self._buf[max_events:]
+                self.backlog_rows = len(self._buf)
                 frm = self._seq
                 self._seq += len(lines)
         self._fifo.deliver((frm, lines))

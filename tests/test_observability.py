@@ -2,6 +2,7 @@
 tracing, JSONL flight-recorder rotation, the trace CLI, and the
 Prometheus/health exposition surface."""
 
+import contextlib
 import io
 import json
 import os
@@ -337,3 +338,293 @@ def test_checkpoint_staleness_gates_readiness():
     _time.sleep(0.05)  # > 3x the 10ms interval
     reasons = health.readiness()
     assert any("checkpoint stale" in r for r in reasons)
+
+
+# -- one clock, one tree: annotations, the uncovered stretches, stages --------
+def test_a_span_opens_a_trace_annotation_of_its_name(monkeypatch):
+    """Every ``_child`` span is a ``dx/<name>`` TraceAnnotation for its
+    duration (a capture then holds the host's stages on the device's
+    clock); ``record``/``record_since`` spans, whose ends are seen at two
+    call sites, get none."""
+    import jax
+
+    seen = []
+
+    class Note:
+        def __init__(self, name, **stats):
+            self.name, self.stats = name, stats
+
+        def __enter__(self):
+            seen.append(("enter", self.name, self.stats))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Note)
+    w = CaptureWriter()
+    tracer = Tracer(telemetry.TelemetryLogger("app", [w]), flow="f")
+    ctx = tracer.begin("streaming/batch")
+    ctx.add(batchTime=1234)
+    with ctx.activate():
+        with tracing.span("decode"):
+            with tracing.span("source-poll"):
+                pass
+    ctx.mark("m")
+    ctx.record_since("device-step", "m")
+    ctx.end()
+    assert [s[:2] for s in seen] == [
+        ("enter", "dx/decode"), ("enter", "dx/source-poll"),
+        ("exit", "dx/source-poll"), ("exit", "dx/decode")]
+    assert seen[0][2] == {"batch": 1234}
+    with tracing.annotation("pace"):
+        pass
+    assert seen[-2][:2] == ("enter", "dx/pace")
+    # the annotation ends before the span's record is written
+    names = [r["name"] for r in w.records if r["type"] == "span"]
+    assert names == ["source-poll", "decode", "device-step",
+                     "streaming/batch"]
+
+
+def test_spans_stay_plain_where_jax_is_not_loaded(monkeypatch):
+    """The control plane must not pay a jax import for its spans: a
+    process that never imported jax can hold no capture."""
+    import sys
+
+    monkeypatch.delitem(sys.modules, "jax")
+    assert isinstance(tracing.annotation("x"), contextlib.nullcontext)
+    w = CaptureWriter()
+    tracer = Tracer(telemetry.TelemetryLogger("app", [w]), flow="f")
+    ctx = tracer.begin("rest/x")
+    with ctx.span("admission"):
+        pass
+    ctx.end()
+    assert "jax" not in sys.modules
+    assert [r["name"] for r in w.records] == ["admission", "rest/x"]
+
+
+@pytest.mark.parametrize("python, level", [(False, 0), (True, 1)])
+def test_profiler_surface_leaves_the_python_tracer_off(
+        tmp_path, monkeypatch, python, level):
+    import jax
+
+    from data_accelerator_tpu.obs.profiler import ProfilerSurface
+
+    calls = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda path, profiler_options=None: calls.append(profiler_options))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    surface = ProfilerSurface(str(tmp_path), flow="f")
+    got = surface.start(seconds=60, python=python)
+    surface.stop()
+    assert got["python"] is python and "error" not in got
+    assert calls[0].python_tracer_level == level
+    assert calls[0].host_tracer_level == 2
+
+
+def test_profile_endpoint_and_cli_pass_python(monkeypatch):
+    from data_accelerator_tpu.obs import __main__ as cli
+
+    asked = []
+
+    class Surface:
+        captures_count = 0
+
+        def start(self, seconds, python=False):
+            asked.append((seconds, python))
+            return {"path": "p", "seconds": seconds, "python": python}
+
+    srv = ObservabilityServer(HealthState(flow="f"), port=0,
+                              profiler=Surface())
+    srv.start()
+    try:
+        base = f"http://127.0.0.1:{srv.port}"
+        assert cli.main(["profile", base, "--seconds", "2"]) == 0
+        assert cli.main(["profile", base, "--seconds", "3", "--python"]) == 0
+    finally:
+        srv.stop()
+    assert asked == [(2.0, False), (3.0, True)]
+
+
+@pytest.fixture(scope="module")
+def home_batches(tmp_path_factory):
+    """The benchmark's HomeAutomation deployment in this process at a
+    2,048-row width, its socket fed 5,000 events before the first poll:
+    three batches, their span records and end events."""
+    import socket
+    import time
+
+    from benchmark import run as bench, served, traffic
+    from data_accelerator_tpu.core.confmanager import ConfigManager
+    from data_accelerator_tpu.runtime.host import StreamingHost
+
+    run_dir = str(tmp_path_factory.mktemp("home"))
+    cell = bench.load_cell("homeautomation.paced")
+    port = served.free_port()
+    conf_path = served.write_conf(run_dir, cell["config"], 2048, port, None)
+    ConfigManager.reset()
+    ConfigManager.get_configuration_from_arguments([f"conf={conf_path}"])
+    host = StreamingHost(ConfigManager.load_config())
+    src = next(iter(host.sources.values()))
+    sent = 5000
+    try:
+        with socket.create_connection(("127.0.0.1", port), 5.0) as conn:
+            conn.sendall(traffic.EventStream(cell["flow"], 7).render(
+                0, sent, time.time()))
+        deadline = time.time() + 30
+        while len(src._buf) < sent and time.time() < deadline:
+            time.sleep(0.01)
+        assert len(src._buf) == sent
+        for _ in range(3):
+            host.run_batch()
+    finally:
+        host.stop()
+        ConfigManager.reset()
+    with open(os.path.join(run_dir, "telemetry.jsonl"), encoding="utf-8") as f:
+        records = [json.loads(line) for line in f]
+    traces = {}
+    for r in records:
+        if r.get("type") == "span":
+            traces.setdefault(r["trace"], []).append(r)
+    ends = [r for r in records if r.get("name") == "streaming/batch/end"]
+    return {"traces": list(traces.values()), "ends": ends, "sent": sent,
+            "host": host}
+
+
+def _by_name(spans):
+    return {s["name"]: s for s in spans}
+
+
+def test_the_four_uncovered_stretches_have_spans(home_batches):
+    for spans in home_batches["traces"]:
+        by = _by_name(spans)
+        root = by["streaming/batch"]["span"]
+        assert by["source-poll"]["parent"] == by["decode"]["span"]
+        assert by["native-decode"]["parent"] == by["decode"]["span"]
+        assert by["source-poll"]["durationMs"] \
+            + by["native-decode"]["durationMs"] <= by["decode"]["durationMs"]
+        assert by["emit"]["parent"] == root
+        assert by["sync"]["parent"] == root
+        # the counts read is the one `sync` span: no child repeats it
+        assert not [n for n in by if n.startswith("sync-")]
+        end = lambda s: s["startTs"] + s["durationMs"] / 1000.0  # noqa: E731
+        assert end(by["sinks"]) <= by["emit"]["startTs"] + 1e-3
+        if "checkpoint" in by:
+            assert end(by["emit"]) <= by["checkpoint"]["startTs"] + 1e-3
+    assert any("checkpoint" in _by_name(t) for t in home_batches["traces"])
+
+
+def test_compile_spans_name_the_programs_of_the_first_batch(home_batches):
+    """One ``compile`` span a program jax compiled or loaded, under the
+    batch that paid for it: the first batch compiles the step."""
+    first = [s for s in home_batches["traces"][0] if s["name"] == "compile"]
+    assert first, "the first batch recorded no compile span"
+    root = _by_name(home_batches["traces"][0])["streaming/batch"]["span"]
+    assert all(s["parent"] == root for s in first)
+    assert any("step" in s["properties"]["fn"] for s in first), [
+        s["properties"] for s in first]
+    assert {s["properties"]["cache"] for s in first} <= {"hit", "miss", None}
+    counted = home_batches["ends"][0]["measurements"]
+    assert len([s for s in first if s["properties"]["cache"]]) == int(
+        counted.get("Compile_Cache_Hit_Count", 0)
+        + counted.get("Compile_Cache_Miss_Count", 0))
+
+
+def test_source_backlog_rows_is_rows_sent_less_rows_polled(home_batches):
+    polled = 0
+    for e in home_batches["ends"]:
+        # (the loop halves its poll after the compiling first batch)
+        polled += e["measurements"]["Input_DataXProcessedInput_Events_Count"]
+        assert e["measurements"]["Source_Backlog_Rows"] \
+            == home_batches["sent"] - polled
+    assert home_batches["ends"][0]["measurements"]["Source_Backlog_Rows"] > 0
+    from data_accelerator_tpu.constants import MetricName
+
+    assert MetricName.is_runtime_metric("Source_Backlog_Rows")
+
+
+def test_every_operation_of_the_step_lies_under_a_stage_scope(home_batches):
+    """``build_step_fn`` names its stages on the device: every operation
+    of the lowered HomeAutomation step carries a ``dx.<stage>`` scope in
+    its location (metadata only: the text without locations, which the
+    compile manifest fingerprints, holds no scope name)."""
+    proc = home_batches["host"].processor
+    lowered = proc._step.lower(*proc._step_input_avals())
+    plain = lowered.as_text()
+    assert "dx." not in plain
+    text = lowered.as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, flags=re.M))
+
+    def resolve(ref, depth=0):
+        body = locs.get(ref, ref)
+        if depth < 8:
+            for inner in re.findall(r"#loc\d+", body):
+                body += resolve(inner, depth + 1)
+        return body
+
+    # an operation's location stands at the end of its last line (a
+    # scatter or sort closes its region there); constants are hoisted
+    # out of their scope and arguments have none
+    # (helpers jax lowers once and calls from several stages, ``_where``
+    # or ``cumsum``, keep one body: the call in @main carries the scope)
+    main = text[text.index("func.func public @main"):]
+    main = main[:main.index("func.func private")]
+    ops = [ln for ln in main.splitlines()
+           if re.search(r"loc\(#loc\d*\)$", ln)
+           and re.match(r"\s+(%\S+ = |\}\) |\"?stablehlo\.)", ln)
+           and not re.search(r"stablehlo\.(constant|return)|func\.", ln)]
+    assert len(ops) > 50
+    bare = [ln.strip()[:120] for ln in ops
+            if "dx." not in resolve(ln[ln.rfind("loc(") + 4:-1])]
+    assert not bare, bare[:5]
+    scopes = set(re.findall(r"dx\.[A-Za-z]+(?:\.[A-Za-z0-9_]+)?", text))
+    assert {"dx.project.default", "dx.ring", "dx.window", "dx.view.HeatAvg",
+            "dx.view.OpenDoors", "dx.compact.OpenDoors", "dx.compact.HeatAvg",
+            "dx.counts"} <= scopes
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip to compile for; made inside
+    the fixture, so collecting this file loads no TPU library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_tpu_compiler_keeps_the_stage_in_op_name(home_batches, v5e_chip):
+    """What a device trace shows is the compiled program's ``op_name``.
+    Compiled for the v5e under the settings the host runs with
+    (``PersistentCompileCache.enable``: metadata in the cache key, one
+    frame a location), the step's instructions still carry their
+    ``dx.<stage>`` scope. (``jax_include_full_tracebacks_in_locations``
+    off loses it: ``op_name`` then reads ``scatter-add`` alone.)"""
+    import jax
+
+    proc = home_batches["host"].processor
+    assert jax.config.jax_traceback_in_locations_limit == 1
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        proc._step_input_avals())
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(proc._step_fn).lower(*avals).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    named = re.findall(r'op_name="([^"]*)"', text)
+    assert len(named) > 100
+    scoped = [n for n in named if "/dx." in n]
+    assert len(scoped) >= 0.75 * len(named), (len(scoped), len(named))
+    custom = [n for n in re.findall(
+        r'fusion\([^\n]*kind=kCustom[^\n]*op_name="([^"]*)"', text)]
+    assert custom and all("/dx." in n for n in custom), custom[:3]
+    assert any("dx.view.HeatAvg" in n for n in custom)
