@@ -190,6 +190,12 @@ class MetricName:
         # thread, and the ms its streamed tables took to resolve there
         r"Sync_CountsBytes",
         r"Transfer_Background_(Pending|LandMs)",
+        # columnar egress (runtime/materialize.py ColumnBatch, counted in
+        # collect_tables): rows of the batch handed to the sinks as
+        # columns, and rows whose schema (nested name, ``.__valid``
+        # flag, deferred template, host-side ORDER BY) took the per-row
+        # fallback — both on every batch, zero included
+        r"Egress_(Columnar|Fallback)_Rows",
         # jit re-traces observed since the last collect (UDF refresh
         # rebuilds + shape/dictionary-growth cache misses); the
         # conformance monitor's DX503 input

@@ -5,11 +5,22 @@ the batch base, renders deferred string templates (CONCAT et al.), and
 folds flattened struct/array columns back into nested JSON values —
 producing the same row JSON the reference's sinks serialize
 (OutputManager.scala:103-126 to_json(struct(cols))).
+
+What crosses the boundary is a ``ColumnBatch``: one output's valid rows
+of one batch, kept as columns. A schema of flat scalar columns is
+rendered column by column (numpy), and encodes itself to the sinks'
+NDJSON without ever building a dict; any other schema (a dotted name, a
+``.__valid`` flag, a deferred template, a host-side ORDER BY) goes
+through ``materialize_rows`` row by row, behind the same type. Either
+way the batch is a read-only ``Sequence[dict]`` for the sinks that want
+rows.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import json
+from collections.abc import Sequence
+from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -137,3 +148,179 @@ def _bury(obj: dict, dotted: str, value) -> None:
     for p in parts[:-1]:
         cur = cur.setdefault(p, {})
     cur[parts[-1]] = value
+
+
+# -- columnar egress --------------------------------------------------------
+def _is_flat(schema: ViewSchema) -> bool:
+    """Every column a top-level scalar: no nested (dotted) name, no
+    ``.__valid`` flag, no ``__defer.`` part, no deferred template (and
+    at least one column: rows without columns are still rows)."""
+    return bool(schema.types) and not schema.deferred and not any(
+        "." in c for c in schema.types
+    )
+
+
+def _json_float_strings(col: np.ndarray) -> List[str]:
+    """``json.dumps`` spellings of a float64 column that holds a
+    non-finite value: ``float.__repr__`` digits, and ``NaN`` /
+    ``Infinity`` / ``-Infinity`` where repr says nan / inf / -inf."""
+    out = list(map(float.__repr__, col.tolist()))
+    for i in np.nonzero(~np.isfinite(col))[0].tolist():
+        v = col[i]
+        out[i] = "NaN" if v != v else ("Infinity" if v > 0 else "-Infinity")
+    return out
+
+
+class ColumnBatch(Sequence):
+    """One output's valid rows of one batch: what ``collect_tables``
+    hands to ``Sink.write``.
+
+    ``columnar`` says which way the schema sent it. Columnar: the valid
+    rows' columns are rendered once, as numpy columns (validity mask
+    once; ``timestamp`` / ``tssec`` widened to int64 before the batch
+    base is added; float32 -> float64; string ids decoded once per
+    distinct id); ``ndjson()`` formats the sinks' payload straight from
+    them, and the row dicts exist only if someone asks. Otherwise the
+    rows are built by ``materialize_rows`` at construction (then sorted
+    and cut by ``finish``, the view's host-side ORDER BY / LIMIT).
+
+    As a sequence (``len``, iteration, indexing, slicing, ``==`` with a
+    list) it is the very ``List[dict]`` ``materialize_rows`` gives,
+    built on first use and kept. Read-only: nobody mutates a batch.
+    """
+
+    def __init__(
+        self,
+        table: TableData,
+        schema: ViewSchema,
+        dictionary: StringDictionary,
+        base_ms: int = 0,
+        max_rows: Optional[int] = None,
+        finish: Optional[Callable[[List[dict]], List[dict]]] = None,
+    ):
+        self.schema = schema
+        self._rows: Optional[List[dict]] = None
+        # (name, type, rendered column); a string column is
+        # (distinct strings, index of each row's string among them)
+        self._columns: List[Tuple[str, str, object]] = []
+        self.columnar = finish is None and _is_flat(schema)
+        if self.columnar:
+            self.columnar = self._render(table, dictionary, base_ms, max_rows)
+        if not self.columnar:
+            rows = materialize_rows(
+                table, schema, dictionary, base_ms, max_rows
+            )
+            self._rows = rows if finish is None else finish(rows)
+            self._len = len(self._rows)
+
+    def _render(self, table, dictionary, base_ms, max_rows) -> bool:
+        valid = np.asarray(table.valid)
+        idx = np.nonzero(valid)[0]
+        if max_rows is not None:
+            idx = idx[:max_rows]
+        # compacted outputs arrive sliced to their count, every row valid
+        take_all = len(idx) == len(valid)
+        columns = []
+        for name, t in self.schema.types.items():
+            col = table.cols.get(name)
+            if col is None or np.shape(col) != valid.shape:
+                return False
+            col = np.asarray(col)
+            if not take_all:
+                col = col[idx]
+            if t == "string":
+                ids, where = np.unique(col, return_inverse=True)
+                col = (
+                    [dictionary.decode(i) for i in ids.tolist()],
+                    where.reshape(-1),
+                )
+            elif t == "timestamp":
+                col = col.astype(np.int64) + base_ms
+            elif t == "tssec":
+                col = col.astype(np.int64) + base_ms // 1000
+            elif t == "boolean":
+                col = col.astype(np.bool_)
+            elif t == "double":
+                with np.errstate(invalid="ignore"):  # a signalling NaN
+                    col = col.astype(np.float64)
+            elif col.dtype.kind in "iub":
+                col = col.astype(np.int64)
+            else:
+                return False
+            columns.append((name, t, col))
+        self._columns = columns
+        self._len = len(idx)
+        return True
+
+    def __len__(self) -> int:
+        return self._len
+
+    def __getitem__(self, i):
+        return self.rows()[i]
+
+    def __iter__(self):
+        return iter(self.rows())
+
+    def __eq__(self, other) -> bool:
+        if isinstance(other, (ColumnBatch, list)):
+            return self.rows() == list(other)
+        return NotImplemented
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return (
+            f"ColumnBatch({self._len} rows of {list(self.schema.types)}, "
+            f"columnar={self.columnar})"
+        )
+
+    def rows(self) -> List[dict]:
+        """The rows as JSON-ready dicts (the list ``collect()`` returns)."""
+        if self._rows is None:
+            names = [name for name, _t, _c in self._columns]
+            values = []
+            for _name, t, col in self._columns:
+                if t == "string":
+                    strings, where = col
+                    col = np.array(strings, dtype=object)[where]
+                values.append(col.tolist())
+            self._rows = [dict(zip(names, r)) for r in zip(*values)]
+        return self._rows
+
+    def ndjson(self) -> str:
+        """One JSON object a row, one row a line: byte for byte
+        ``json.dumps(row, default=str) + "\n"`` over ``rows()``."""
+        if not self._len:
+            return ""
+        if not self.columnar:
+            return ndjson(self._rows)
+        fields, values = [], []
+        for name, t, col in self._columns:
+            spec = "%d"
+            if t == "string":
+                strings, where = col
+                spec = "%s"
+                col = np.array(
+                    [json.dumps(s) for s in strings], dtype=object
+                )[where]
+            elif t == "boolean":
+                spec = "%s"
+                col = np.where(col, "true", "false")
+            elif t == "double":
+                if np.isfinite(col).all():
+                    spec = "%r"
+                else:
+                    spec = "%s"
+                    col = _json_float_strings(col)
+            fields.append(json.dumps(name).replace("%", "%%") + ": " + spec)
+            values.append(col if isinstance(col, list) else col.tolist())
+        line = "{" + ", ".join(fields) + "}\n"
+        return "".join(map(line.__mod__, zip(*values)))
+
+
+def ndjson(rows: Union[ColumnBatch, Sequence]) -> str:
+    """The NDJSON payload of a sink write: from the columns when
+    ``rows`` is a batch, row by row for a plain list of dicts."""
+    if isinstance(rows, ColumnBatch):
+        return rows.ndjson()
+    return "".join(json.dumps(r, default=str) + "\n" for r in rows)

@@ -46,7 +46,7 @@ from ..core.config import EngineException, SettingDictionary, SettingNamespace
 from ..obs.tracing import current_trace as _current_trace
 from ..obs.tracing import span as _trace_span
 from ..core.schema import ColType, Schema, StringDictionary
-from .materialize import materialize_rows
+from .materialize import ColumnBatch
 from .statetable import StateTable
 from .timewindow import (
     WindowBuffers,
@@ -2769,15 +2769,15 @@ class PendingBatch:
         return self._counts
 
     def collect(self) -> Tuple[Dict[str, List[dict]], Dict[str, float]]:
-        """Synchronous back-compat result path: counts sync + table
-        landing in one call. Byte-identical to the split
-        ``collect_counts()`` / ``collect_tables()`` background path
+        """Synchronous result path: counts sync + table landing in one
+        call, each ``collect_tables()`` batch as its plain row list
         (golden-tested in tests/test_sized_transfer.py)."""
-        return self.collect_tables()
+        datasets, metrics = self.collect_tables()
+        return {n: b.rows() for n, b in datasets.items()}, metrics
 
-    def collect_tables(self) -> Tuple[Dict[str, List[dict]], Dict[str, float]]:
-        """Resolve the background-streamed output tables, materialize
-        rows and persist state; returns (datasets, metrics).
+    def collect_tables(self) -> Tuple[Dict[str, ColumnBatch], Dict[str, float]]:
+        """Resolve the background-streamed output tables and persist
+        state; returns (one ColumnBatch an output, what sinks get; metrics).
 
         With a prior ``start_fetch()`` (the default from
         ``dispatch_batch``) every device read below hits an
@@ -2879,23 +2879,24 @@ class PendingBatch:
             for name, table in host_tables.items():
                 proc.buffer_sanitizer.scan_table(name, table)
 
-        datasets: Dict[str, List[dict]] = {}
+        datasets: Dict[str, ColumnBatch] = {}
         with _trace_span("materialize"):
             for name, table in host_tables.items():
-                rows = materialize_rows(
-                    table, self.pipeline.schema_of(name), proc.dictionary,
-                    self.base_ms,
-                )
                 view = self.pipeline.view_by_name(name)
+                finish = None
                 if view is not None and view.host_order:
                     # ORDER BY over computed-string columns: the device
                     # has no id to sort by, so the ordering (and limit)
                     # applies to the materialized rows (planner
-                    # host-order path)
-                    _host_sort(rows, view.host_order)
-                    if view.host_limit is not None:
-                        rows = rows[: view.host_limit]
-                datasets[name] = rows
+                    # host-order path), which the batch then builds
+                    def finish(rows, order=view.host_order,
+                               limit=view.host_limit):
+                        _host_sort(rows, order)
+                        return rows if limit is None else rows[:limit]
+                datasets[name] = ColumnBatch(
+                    table, self.pipeline.schema_of(name), proc.dictionary,
+                    self.base_ms, finish=finish,
+                )
 
         # persist state tables (A/B overwrite; persist() is the caller's
         # post-sink commit, see StreamingHost) — from THIS batch's state
@@ -2915,6 +2916,13 @@ class PendingBatch:
             metrics[f"Output_{n}_GroupsDropped"] = float(c)
         for n, c in dropped_joins.items():
             metrics[f"Output_{n}_JoinRowsDropped"] = float(c)
+        # how often the columnar egress engages: rows handed to the sinks
+        # as columns, and rows a schema sent through the per-row fallback
+        fallback = sum(len(b) for b in datasets.values() if not b.columnar)
+        metrics["Egress_Fallback_Rows"] = float(fallback)
+        metrics["Egress_Columnar_Rows"] = float(
+            sum(map(len, datasets.values())) - fallback
+        )
         # drain host-side ingest counters accumulated since last collect
         if proc.ingest_stats:
             for k, v in proc.ingest_stats.items():

@@ -11,8 +11,15 @@ reference: datax-host sink/ package —
   stubbed send hook; metric sink -> MetricLogger routing (the reference
   routes alert tables TO Metrics the same way).
 
-Sinks receive already-materialized host rows; device->host transfer
-happens once per batch in the processor, off the jitted path.
+Sinks receive each dataset's rows as a ``ColumnBatch``
+(runtime/materialize.py): a read-only ``Sequence[dict]`` that the
+processor landed once per batch, off the jitted path, and that keeps
+flat outputs as columns. A sink that wants rows iterates, indexes or
+slices it like the ``List[dict]`` it used to get (a plain list is still
+accepted); a sink that writes NDJSON asks ``ndjson(rows)`` and gets the
+payload straight from the columns, with no dict and no ``json.dumps`` a
+row; a sink that hands the whole batch to a serializer takes
+``list(rows)`` first.
 """
 
 from __future__ import annotations
@@ -24,13 +31,14 @@ import threading
 import time
 import urllib.request
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 from ..core.config import SettingDictionary
 from ..obs import tracing
 from ..obs.metrics import MetricLogger
 from ..constants import MetricName
 from ..utils import fs
+from .materialize import ndjson
 
 logger = logging.getLogger(__name__)
 
@@ -38,7 +46,9 @@ logger = logging.getLogger(__name__)
 class Sink:
     kind = "base"
 
-    def write(self, dataset: str, rows: List[dict], batch_time_ms: int) -> int:
+    def write(
+        self, dataset: str, rows: Sequence[dict], batch_time_ms: int
+    ) -> int:
         raise NotImplementedError
 
 
@@ -88,8 +98,7 @@ class FileSink(Sink):
         ext = ".json.gz" if self.compression == "gzip" else ".json"
         name = f"{dataset}_{batch_time_ms}_{self._counter}{ext}"
         path = os.path.join(out_dir, name)
-        payload = "\n".join(json.dumps(r, default=str) for r in rows) + "\n"
-        fs.write_text(path, payload)
+        fs.write_text(path, ndjson(rows))
         return len(rows)
 
 
@@ -107,7 +116,9 @@ class HttpPostSink(Sink):
             return 0
         req = urllib.request.Request(
             self.endpoint,
-            data=json.dumps(rows, default=str).encode(),
+            # the list, not the batch: with default=str a non-list
+            # would be written as its str(), silently
+            data=json.dumps(list(rows), default=str).encode(),
             headers={"Content-Type": "application/json", **self.headers},
         )
         try:
@@ -328,9 +339,7 @@ class StreamSink(Sink):
     def write(self, dataset, rows, batch_time_ms) -> int:
         if not rows:
             return 0
-        payload = b"".join(
-            json.dumps(r, default=str).encode() + b"\n" for r in rows
-        )
+        payload = ndjson(rows).encode()
         with self._lock:
             try:
                 if self._sock is None:
@@ -422,7 +431,7 @@ class OutputOperator:
     dataset: str
     sinks: List[Sink] = field(default_factory=list)
 
-    def write(self, rows: List[dict], batch_time_ms: int) -> Dict[str, int]:
+    def write(self, rows: Sequence[dict], batch_time_ms: int) -> Dict[str, int]:
         counts = {}
         for s in self.sinks:
             # one span per sink write under the batch trace (no-op when
@@ -565,7 +574,7 @@ class OutputDispatcher:
         )
 
     def dispatch(
-        self, datasets: Dict[str, List[dict]], batch_time_ms: int
+        self, datasets: Dict[str, Sequence[dict]], batch_time_ms: int
     ) -> Dict[str, int]:
         results: Dict[str, int] = {}
         lock = threading.Lock()
@@ -574,7 +583,7 @@ class OutputDispatcher:
         # per-sink spans parent under the host's "sinks" span
         trace_pos = tracing.capture()
 
-        def run_op(name: str, op: OutputOperator, rows: List[dict]):
+        def run_op(name: str, op: OutputOperator, rows: Sequence[dict]):
             try:
                 with tracing.activated(trace_pos):
                     counts = op.write(rows, batch_time_ms)
