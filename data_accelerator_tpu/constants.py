@@ -207,6 +207,9 @@ class MetricName:
         # sharding model, judged by the DX510/DX511 conformance checks
         r"Mesh_ICI_Bytes",
         r"Mesh_Reshard_Count",
+        # chips the mesh step's output lies on, every batch under a mesh
+        # (a numchips conf that stepped on one chip reads 1)
+        r"Mesh_Chips",
         # model-vs-observed conformance (obs/conformance.py): windowed
         # observed/predicted ratios against the cost-model report
         # embedded in the conf, plus the cumulative drift-event count

@@ -23,6 +23,7 @@ inside the single jitted step.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import logging
@@ -1695,10 +1696,15 @@ class FlowProcessor:
             valid = self._filter_unowned(
                 np_cols.get(self.state_partition_key), valid, spec
             )
-        return TableData(
-            {c: self._put_rows(a) for c, a in np_cols.items()},
-            self._put_rows(valid),
-        )
+        # under a mesh every column goes to each chip's row shard, one
+        # transfer a column a shard: the mesh's own share of ``decode``
+        # (the host's time in the calls; jax completes them behind it)
+        with _trace_span("shard-put") if self.mesh is not None \
+                else contextlib.nullcontext():
+            return TableData(
+                {c: self._put_rows(a) for c, a in np_cols.items()},
+                self._put_rows(valid),
+            )
 
     # -- ingest fast-path helpers -----------------------------------------
     def _count_jsonl_malformed(self, data: bytes, consumed: int,
@@ -2959,6 +2965,10 @@ class PendingBatch:
         retraces = proc.drain_retraces()
         if retraces:
             metrics["Retrace_Count"] = float(retraces)
+        if proc.mesh is not None:
+            # the chips the step's own output lies on: a mesh conf that
+            # silently stepped on one chip reads 1
+            metrics["Mesh_Chips"] = float(_device_count(self.counts_vec))
         # observed mesh communication: the executed program's collective
         # census as per-batch series (the DX510/DX511 inputs). A
         # re-trace re-censuses — the new program may partition
