@@ -493,8 +493,10 @@ def ici_bytes_group(
     """Expected ICI bytes/batch of one GROUP BY under the 1-D data-mesh
     layout (dist/mesh.py): rows shard, outputs replicate.
 
-    - distributed sort (group_ids): each of the N rows' key + aggregated
-      value elements crosses chips with probability (C-1)/C;
+    - distributed sort (``ops.groupby.sort_groups``: one sort whose
+      operands are the N rows' keys and, as payloads, the aggregated
+      value columns): each of those elements crosses chips with
+      probability (C-1)/C;
     - all-gather of the replicated [G]-row group output to every chip:
       G * row_bytes * (C-1).
 

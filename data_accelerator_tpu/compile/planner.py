@@ -26,11 +26,11 @@ import jax.numpy as jnp
 from ..core.config import EngineException
 from ..core.schema import StringDictionary
 from ..ops import (
-    compact_indices,
     distinct_mask,
-    group_ids,
     inner_join_indices,
     segment_aggregate,
+    segment_starts,
+    sort_groups,
 )
 from ..ops.join import left_join_indices
 from .exprs import (
@@ -1292,36 +1292,44 @@ class SelectCompiler:
                 valid = valid & where_fn(env)
 
             keys = [k.fn(env) for k in key_compiled]
-            order, seg, num_groups, first = group_ids(keys, valid)
-            valid_s = valid[order]
+            # every column an aggregate reads in sorted order rides
+            # through the sort as a payload: nothing is gathered by
+            # `order` but the groups' representative rows
+            carry: List[jnp.ndarray] = []
+            agg_slot: Dict[str, int] = {}
+            for key, (fname, _arg, dist) in agg_nodes.items():
+                if agg_args[key] is not None and (fname != "COUNT" or dist):
+                    agg_slot[key] = len(carry)
+                    carry.append(agg_args[key].fn(env))
+            udaf_slot: Dict[str, int] = {}
+            for key, args in udaf_args.items():
+                udaf_slot[key] = len(carry)
+                carry.extend(a.fn(env) for a in args)
+            order, seg, num_groups, _first, valid_s, carried = sort_groups(
+                keys, valid, carry
+            )
 
             # aggregate values
             agg_results: Dict[str, jnp.ndarray] = {}
             for key, (fname, arg, dist) in agg_nodes.items():
-                if fname == "COUNT" and agg_args[key] is None:
+                if fname == "COUNT" and not dist:
                     agg_results[key] = segment_aggregate(
                         None, seg, capacity, "count", valid_s
                     )
                     continue
-                vals = agg_args[key].fn(env)[order]
-                if fname == "COUNT" and dist:
+                vals = carried[agg_slot[key]]
+                if fname == "COUNT":
                     agg_results[key] = _distinct_count(
-                        agg_args[key].fn(env), order, seg, valid_s, capacity
-                    )
-                elif fname == "COUNT":
-                    agg_results[key] = segment_aggregate(
-                        None, seg, capacity, "count", valid_s
+                        vals, seg, valid_s, capacity
                     )
                 elif fname == "SUM":
-                    z = jnp.where(valid_s, vals, jnp.zeros_like(vals))
                     agg_results[key] = segment_aggregate(
-                        z, seg, capacity, "sum", valid_s
+                        vals, seg, capacity, "sum", valid_s
                     )
                 elif fname == "AVG":
-                    zf = jnp.where(valid_s, vals, jnp.zeros_like(vals)).astype(
-                        jnp.float32
+                    s = segment_aggregate(
+                        vals.astype(jnp.float32), seg, capacity, "sum", valid_s
                     )
-                    s = segment_aggregate(zf, seg, capacity, "sum", valid_s)
                     c = segment_aggregate(None, seg, capacity, "count", valid_s)
                     agg_results[key] = s / jnp.maximum(c, 1).astype(jnp.float32)
                 elif fname in ("MIN", "MAX"):
@@ -1337,31 +1345,25 @@ class SelectCompiler:
                         live = live & (vals != 0)
                         rank_t = aux_tables[RANK_KEY]
                         vals = rank_t[jnp.clip(vals, 0, rank_t.shape[0] - 1)]
-                    ident = (
-                        jnp.iinfo(jnp.int32).max if vals.dtype in (jnp.int32,)
-                        else jnp.asarray(jnp.inf, vals.dtype)
-                    )
-                    if fname == "MAX":
-                        ident = (
-                            jnp.iinfo(jnp.int32).min if vals.dtype in (jnp.int32,)
-                            else jnp.asarray(-jnp.inf, vals.dtype)
-                        )
-                    z = jnp.where(live, vals, jnp.full_like(vals, ident))
-                    res = segment_aggregate(z, seg, capacity, op, live)
+                    res = segment_aggregate(vals, seg, capacity, op, live)
                     if is_string:
                         # group with no non-null value -> NULL (rank 0 is
                         # always the null entry, so unrank[0] == id 0)
                         unrank_t = aux_tables[UNRANK_KEY]
-                        res = jnp.where(res == ident, 0, res)
+                        int32 = jnp.iinfo(jnp.int32)
+                        empty = int32.max if op == "min" else int32.min
+                        res = jnp.where(res == empty, 0, res)
                         res = unrank_t[jnp.clip(res, 0, unrank_t.shape[0] - 1)]
                     agg_results[key] = res
             for key, (udf, _args) in udaf_nodes.items():
-                arg_arrays = [a.fn(env)[order] for a in udaf_args[key]]
+                at = udaf_slot[key]
+                arg_arrays = list(carried[at: at + len(udaf_args[key])])
                 agg_results[key] = udf.reduce(arg_arrays, seg, capacity, valid_s)
 
-            # representative row per group (first sorted row)
-            rep_sorted_idx, rep_valid = compact_indices(first, capacity)
-            rep_idx = order[rep_sorted_idx]
+            # representative row per group: the row its segment starts at
+            # (sorted position 0 for the slots no group fills)
+            starts = segment_starts(seg, capacity)[:capacity]
+            rep_idx = order[jnp.where(jnp.arange(capacity) < num_groups, starts, 0)]
 
             rep_scopes = {
                 b: {c: arr[rep_idx] for c, arr in cols.items()}
@@ -1419,13 +1421,19 @@ def _null_tag(null_expr: CompiledExpr, tag: int) -> CompiledExpr:
     return CompiledExpr("long", run, deps=null_expr.deps)
 
 
-def _distinct_count(vals, order, seg, valid_s, capacity):
-    """COUNT(DISTINCT x) per group: sort (seg, x) pairs, count pair-firsts."""
-    x_s = vals[order]
-    pair_order = jnp.lexsort([x_s.astype(jnp.int32), seg])
-    seg_p = seg[pair_order]
-    x_p = x_s[pair_order]
-    valid_p = valid_s[pair_order]
+def _distinct_count(x_s, seg, valid_s, capacity):
+    """COUNT(DISTINCT x) per group: sort (seg, x) pairs, count pair-firsts.
+    ``x_s`` is the argument in group-sorted order; validity rides through
+    the second sort with it."""
+    if x_s.dtype == jnp.int32:
+        seg_p, x_p, valid_p = jax.lax.sort(
+            (seg, x_s, valid_s), num_keys=2, is_stable=True
+        )
+    else:
+        seg_p, _key, x_p, valid_p = jax.lax.sort(
+            (seg, x_s.astype(jnp.int32), x_s, valid_s),
+            num_keys=2, is_stable=True,
+        )
     new_pair = jnp.concatenate(
         [
             jnp.ones((1,), jnp.bool_),
@@ -1433,5 +1441,4 @@ def _distinct_count(vals, order, seg, valid_s, capacity):
         ]
     )
     flags = (new_pair & valid_p).astype(jnp.int32)
-    out = segment_aggregate(flags, seg_p, capacity, "sum", valid_p)
-    return out
+    return segment_aggregate(flags, seg_p, capacity, "sum", valid_p)

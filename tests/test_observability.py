@@ -599,13 +599,11 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_the_tpu_compiler_keeps_the_stage_in_op_name(home_batches, v5e_chip):
-    """What a device trace shows is the compiled program's ``op_name``.
-    Compiled for the v5e under the settings the host runs with
-    (``PersistentCompileCache.enable``: metadata in the cache key, one
-    frame a location), the step's instructions still carry their
-    ``dx.<stage>`` scope. (``jax_include_full_tracebacks_in_locations``
-    off loses it: ``op_name`` then reads ``scatter-add`` alone.)"""
+@pytest.fixture(scope="module")
+def v5e_step_text(home_batches, v5e_chip):
+    """The HomeAutomation step (2,048-row width: a 12,288-row ring, 4,096
+    group slots) compiled for the v5e under the settings the host runs
+    with; the optimized program's text."""
     import jax
 
     proc = home_batches["host"].processor
@@ -617,9 +615,19 @@ def test_the_tpu_compiler_keeps_the_stage_in_op_name(home_batches, v5e_chip):
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        text = jax.jit(proc._step_fn).lower(*avals).compile().as_text()
+        return jax.jit(proc._step_fn).lower(*avals).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def test_the_tpu_compiler_keeps_the_stage_in_op_name(v5e_step_text):
+    """What a device trace shows is the compiled program's ``op_name``.
+    Compiled for the v5e under the settings the host runs with
+    (``PersistentCompileCache.enable``: metadata in the cache key, one
+    frame a location), the step's instructions still carry their
+    ``dx.<stage>`` scope. (``jax_include_full_tracebacks_in_locations``
+    off loses it: ``op_name`` then reads ``scatter-add`` alone.)"""
+    text = v5e_step_text
     named = re.findall(r'op_name="([^"]*)"', text)
     assert len(named) > 100
     scoped = [n for n in named if "/dx." in n]
@@ -628,3 +636,39 @@ def test_the_tpu_compiler_keeps_the_stage_in_op_name(home_batches, v5e_chip):
         r'fusion\([^\n]*kind=kCustom[^\n]*op_name="([^"]*)"', text)]
     assert custom and all("/dx." in n for n in custom), custom[:3]
     assert any("dx.view.HeatAvg" in n for n in custom)
+
+
+def test_the_group_by_gathers_and_scatters_by_group_not_by_row(
+        home_batches, v5e_step_text):
+    """``dx.view.HeatAvg`` groups the whole ring (6 slots x the batch
+    width) into 4,096 slots. In the program the v5e runs, the ring's rows
+    go through the view's sort, and no gather or scatter under the view's
+    scope takes an index a row: the sort carries the columns, segments
+    are reduced in place and read at 4,096 (+ 1) positions."""
+    text = v5e_step_text
+    proc = home_batches["host"].processor
+    rows = 6 * 2048
+    slots = 4096
+    view = {v.name: v for v in proc.pipeline.views}["HeatAvg"]
+    assert (view.plan.input_rows, view.capacity) == (rows, slots)
+    shapes = {name: [int(d) for d in dims.split(",") if d]
+              for name, dims in re.findall(
+                  r"^\s*(?:ROOT )?(%[\w.-]+) = \w+\[([\d,]*)\]", text, re.M)}
+    in_view = [ln for ln in text.splitlines()
+               if 'op_name="' in ln and "dx.view.HeatAvg" in ln]
+    sorts = [ln for ln in in_view if re.search(r"[\])] sort\(%", ln)]
+    assert sorts and all(f"[{rows}]" in ln for ln in sorts), sorts
+    indexed = []
+    for ln in in_view:
+        m = re.search(r" (gather|scatter)\((%[\w.-]+), (%[\w.-]+)", ln)
+        if m:
+            n_indices = shapes[m.group(3)][0]
+            indexed.append((m.group(1), n_indices))
+    # the view still reads its groups' rows and writes nothing by index
+    assert {k for k, _n in indexed} == {"gather"}
+    assert max(n for _k, n in indexed) <= slots + 1 < rows, indexed
+    # the new operations are the view's own: the scan's passes carry
+    # its scope like the sort does
+    fusions = [ln for ln in text.splitlines() if re.search(r" fusion\(", ln)
+               and 'op_name="' in ln]
+    assert sum("dx.view.HeatAvg" in ln for ln in fusions) >= 10

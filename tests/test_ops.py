@@ -4,6 +4,7 @@ the reference lacks entirely (SURVEY.md section 4 takeaway)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from data_accelerator_tpu.ops import (
     compact_indices,
@@ -211,3 +212,155 @@ def test_sort_join_overflow_and_left_outer():
     rows = [(int(li[i]), bool(is_null[i])) for i in range(16) if bool(valid[i])]
     assert rows == [(0, False)] * 3 + [(1, False)] * 3 + [(2, True), (3, True)]
     assert int(dropped) == 0
+
+
+# -- the sorted GROUP BY against numpy: every op over every shape of input
+
+
+def _groupby_case(name):
+    """(k1, k2, valid, live, capacity): ``live`` is what the aggregate is
+    told is valid, in input order (a subset of ``valid``)."""
+    rng = np.random.RandomState(11)
+    n, capacity = 64, 64
+    if name == "odd_length":
+        n = capacity = 333  # blocks of 128 rows, the last one padded
+    elif name == "three_levels":
+        n, capacity = 128 * 128 + 1000, 32  # blocks of blocks of blocks
+    k1 = rng.randint(0, 4, n)
+    k2 = rng.randint(-2, 1, n)
+    valid = np.ones(n, bool)
+    live = None
+    if name == "invalid_interleaved":
+        valid = rng.rand(n) < 0.6
+    elif name == "not_live_mid_segment":
+        live = rng.rand(n) < 0.7
+    elif name == "zero_valid":
+        valid = np.zeros(n, bool)
+    elif name == "more_groups_than_capacity":
+        k1 = rng.randint(0, 40, n)
+        capacity = 8
+    elif name == "one_group":
+        k1 = np.full(n, 3)
+        k2 = np.full(n, -1)
+    return k1, k2, valid, valid if live is None else valid & live, capacity
+
+
+_GROUPBY_CASES = [
+    "all_valid", "invalid_interleaved", "not_live_mid_segment", "zero_valid",
+    "more_groups_than_capacity", "one_group", "odd_length", "three_levels",
+]
+_NP_REDUCE = {
+    "sum_int": np.sum, "min": np.min, "max": np.max, "any": np.any,
+    "all": np.all,
+}
+
+
+@pytest.mark.parametrize("case", _GROUPBY_CASES)
+@pytest.mark.parametrize(
+    "op", ["count", "sum", "sum_int", "min", "max", "any", "all"])
+def test_segment_aggregate_equals_numpy(op, case):
+    from data_accelerator_tpu.ops.groupby import sort_groups
+
+    k1, k2, valid, live, capacity = _groupby_case(case)
+    n = len(valid)
+    rng = np.random.RandomState(3)
+    if op in ("any", "all"):
+        vals = rng.rand(n) < 0.5
+    elif op == "sum_int":
+        vals = rng.randint(-1000, 1000, n).astype(np.int32)
+    else:
+        vals = rng.normal(70.0, 10.0, n).astype(np.float32)
+
+    g = sort_groups(
+        [jnp.asarray(k1, jnp.int32), jnp.asarray(k2, jnp.int32)],
+        jnp.asarray(valid), [jnp.asarray(vals), jnp.asarray(live)],
+    )
+    vals_s, live_s = g.carried
+    order = np.asarray(g.order)
+    # what rode through the sort is what a gather by `order` would give
+    np.testing.assert_array_equal(np.asarray(vals_s), vals[order])
+    np.testing.assert_array_equal(np.asarray(g.valid_s), valid[order])
+    if op == "count":
+        # COUNT takes the sort's own validity (its invalid rows are last)
+        live, live_s = valid, g.valid_s
+    out = np.asarray(segment_aggregate(
+        None if op == "count" else vals_s, g.seg, capacity,
+        op.split("_")[0], live_s,
+    ))
+    assert out.shape == (capacity,)
+
+    groups = sorted({(a, b) for a, b, v in zip(k1, k2, valid) if v})
+    assert int(g.num_groups) == len(groups)
+    for i, (a, b) in enumerate(groups[:capacity]):
+        rows = vals[(k1 == a) & (k2 == b) & live]
+        if op == "count":
+            assert out[i] == len(rows)
+        elif len(rows) == 0:
+            continue  # a group of no live row reads the op's identity
+        elif op == "sum":
+            np.testing.assert_allclose(
+                out[i], np.sum(rows.astype(np.float64)), rtol=1e-6)
+        else:
+            assert out[i] == _NP_REDUCE[op](rows), (op, case, i)
+    if op in ("count", "sum", "sum_int"):
+        # the dummy segment of invalid rows and the unused slots read 0
+        assert not out[len(groups):].any()
+
+
+@pytest.mark.parametrize("case", _GROUPBY_CASES)
+def test_group_order_is_numpys_stable_lexsort(case):
+    k1, k2, valid, _live, _capacity = _groupby_case(case)
+    order, seg, num, first = group_ids(
+        [jnp.asarray(k1, jnp.int32), jnp.asarray(k2, jnp.int32)],
+        jnp.asarray(valid),
+    )
+    # np.lexsort: last key is primary, and it is stable
+    expect = np.lexsort((k2, k1, ~valid))
+    np.testing.assert_array_equal(np.asarray(order), expect)
+    seg, first = np.asarray(seg), np.asarray(first)
+    assert (np.diff(seg) >= 0).all() and (np.diff(seg) <= 1).all()
+    n_valid = int(valid.sum())
+    assert first.sum() == int(num) and not first[n_valid:].any()
+    # dense ids: each group's first row opens the next id
+    np.testing.assert_array_equal(seg[:n_valid], np.cumsum(first)[:n_valid] - 1)
+    assert (seg[n_valid:] == int(num)).all()
+
+
+@pytest.mark.parametrize("n", [1, 100, 128, 129, 1000, 128 * 128 + 77])
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+def test_segmented_scan_equals_numpy(dtype, op, n):
+    """Runs from one row to thousands, crossing blocks and blocks of
+    blocks; integers exact, float sums to 1e-6 of the float64 scan."""
+    from data_accelerator_tpu.ops.groupby import segmented_scan
+
+    rng = np.random.RandomState(n)
+    seg = np.cumsum(rng.rand(n) < (0.3 if n < 2000 else 0.002)).astype(np.int32)
+    vals = (rng.normal(0, 100, n) if dtype == np.float32
+            else rng.randint(-1000, 1000, n)).astype(dtype)
+    got = np.asarray(segmented_scan(jnp.asarray(vals), jnp.asarray(seg), op))
+    acc = {"sum": np.add, "min": np.minimum, "max": np.maximum}[op].accumulate
+    expect = np.concatenate([
+        acc(vals[seg == g].astype(np.float64 if dtype == np.float32 else dtype))
+        for g in np.unique(seg)
+    ])
+    if dtype == np.float32 and op == "sum":
+        scale = np.concatenate([
+            np.add.accumulate(np.abs(vals[seg == g]).astype(np.float64))
+            for g in np.unique(seg)
+        ])
+        assert (np.abs(got - expect) <= 1e-6 * scale).all()
+    else:
+        np.testing.assert_array_equal(got, expect.astype(dtype))
+
+
+def test_group_float_keys_keep_their_equality():
+    """The boundary is read from the sorted key by the key's own ``!=``:
+    0.0 and -0.0 are one group, and a NaN equals nothing, itself neither."""
+    nan = float("nan")
+    k = jnp.asarray([0.0, -0.0, 1.5, nan, -1.5, 1.5, nan], jnp.float32)
+    valid = jnp.ones(7, bool)
+    order, seg, num, _ = group_ids([k], valid)
+    assert int(num) == 5
+    assert np.asarray(order).tolist() == [4, 0, 1, 2, 5, 3, 6]
+    assert np.asarray(seg).tolist() == [0, 1, 1, 2, 2, 3, 4]
