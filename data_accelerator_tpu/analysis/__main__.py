@@ -62,10 +62,10 @@ non-positive or non-integer value exits 2. Same exit contract.
 (``analysis/racecheck.py``): unlike the flow tiers its subject is the
 ENGINE the flow deploys onto — every ``runtime/``, ``lq/`` and
 ``pilot/`` module is abstract-interpreted under a buffer-provenance
-lattice (donated ring / pool slot / transfer slot / plain), emitting
+lattice (donated ring / pool slot / plain), emitting
 the DX8xx lints: escaped donated/pooled views (DX800), unannotated
 zero-copy ``asarray`` (DX801), lockset/lock-ordering violations
-(DX802), slot re-donation before its land ack (DX803), and blocking
+(DX802), and blocking
 syncs on non-blocking threads (DX804). A clean report certifies the
 runtime for ANY flow, so the result is cached per engine-source state.
 Same exit contract — this is the standing CI race gate.
@@ -414,11 +414,9 @@ def main(argv: List[str]) -> int:
             if comp is not None and comp.entries:
                 cd = comp.compile_dict()
                 print(
-                    f"{path}: compile surface: {cd['entries']} entries "
-                    f"(1 step + {cd['helperEntries']} transfer-helper "
-                    f"over buckets {cd['buckets']}), "
-                    f"{'stable' if cd['stable'] else 'OPEN'}, "
-                    f"jit-cache cap {cd['jitCacheCap']}"
+                    f"{path}: compile surface: {cd['entries']} program "
+                    f"(the step), "
+                    f"{'stable' if cd['stable'] else 'OPEN'}"
                 )
             if mesh is not None and mesh.stages:
                 _print_mesh_plan(path, mesh)

@@ -7,32 +7,28 @@ first dispatch. Shipping serialized compiles ahead of time is only safe
 if the set of jit entry points a flow will ever dispatch is **finite
 and statically known** — which is exactly what this tier proves:
 
-- it enumerates every entry point the runtime can dispatch — the fused
-  step function (``runtime/processor.py build_step_fn``), one
-  ``_slice_table``/``_pack_slot`` transfer helper per reachable
-  (output x pow2 capacity bucket) from the sized-transfer lattice
-  (``transfer_buckets``: the EWMA sizing buckets plus the full-capacity
-  overflow fetch; the x2 overflow headroom boost only moves *within*
-  this lattice, so it adds no entries),
+- it enumerates every entry point the runtime can dispatch — one: the
+  fused step function (``runtime/processor.py build_step_fn``; every
+  output crosses to the host at its declared capacity, so no other
+  program runs on the device),
 - derives each entry's trace signature over ``jax.eval_shape`` avals
   and lowers it with ``jax.jit(...).lower()`` — tracing only, no device
   execution, no allocation,
 - emits a **compile manifest**: entry -> aval signature, static args,
   donation pattern, lowering digest, and a cache key
-  (flow-hash x chip count x capacity bucket) — the deployable artifact
+  (flow-hash x chip count x entry) — the deployable artifact
   config generation embeds into the conf
   (``datax.job.process.compile.manifest``) and ``FlowProcessor``
   AOT-warms at init instead of first dispatch.
 
 The byte-exactness contract (DX603): the analyzer builds the step with
-the SAME ``build_step_fn`` the runtime jits and enumerates entries with
-the SAME ``compile_entries_from_avals`` the runtime's
+the SAME ``build_step_fn`` the runtime jits and describes the entry with
+the SAME ``step_compile_entry`` the runtime's
 ``FlowProcessor.derive_compile_entries`` uses — so the emitted manifest
 can only disagree with the real lowering when the flow itself changed.
 
-DX6xx codes: DX600 open trace surface (unbounded signature set), DX601
-capacity-bucket lattice past the helper jit-cache bound (shared
-constant ``DEFAULT_JIT_CACHE_CAP``), DX602 manifest donation/aliasing
+DX6xx codes: DX600 open trace surface (unbounded signature set), DX602
+manifest donation/aliasing
 mismatch, DX603 manifest-vs-lowering drift, DX690 lowering failure,
 DX691 analysis unavailable. DX604 (warm start promised but missed) is
 the *runtime* counterpart, surfaced as ``Compile_WarmMiss_Count``
@@ -46,7 +42,6 @@ through the persistent compilation cache every ``FlowProcessor`` arms
 
 from __future__ import annotations
 
-import functools
 import hashlib
 import json
 from dataclasses import dataclass, field
@@ -58,15 +53,12 @@ import jax.numpy as jnp
 from ..core.config import SettingDictionary, SettingNamespace
 from ..core.schema import StringDictionary
 from ..runtime.processor import (
-    DEFAULT_JIT_CACHE_CAP,
     STEP_DONATE_ARGNUMS,
-    _pack_impl,
-    _slice_impl,
     build_step_fn,
-    compile_entries_from_avals,
     load_reference_data_tables,
     packed_raw_struct,
     source_raw_form,
+    step_compile_entry,
 )
 from .deviceplan import (
     FlowDevicePlan,
@@ -119,7 +111,6 @@ class CompileSurfaceReport:
     manifest: Optional[dict]
     diagnostics: List[Diagnostic]
     stable: bool = True
-    jit_cache_cap: int = DEFAULT_JIT_CACHE_CAP
 
     @property
     def errors(self) -> List[Diagnostic]:
@@ -140,18 +131,11 @@ class CompileSurfaceReport:
         """The compile-surface portion (no diagnostics) — what the
         designer renders beside the diagnostics list and the CLI's
         ``--json`` report carries under ``compile``."""
-        helper = [e for e in self.entries if e["entry"] != "step"]
-        caps = sorted({
-            e["static"]["cap"] for e in helper if "cap" in e["static"]
-        })
         return {
             "flow": self.flow,
             "chips": self.chips,
             "entries": len(self.entries),
-            "helperEntries": len(helper),
-            "buckets": caps,
             "stable": self.stable,
-            "jitCacheCap": self.jit_cache_cap,
             "manifest": self.manifest,
         }
 
@@ -289,37 +273,13 @@ def _source_refdata_names(gui: dict) -> List[str]:
 # ---------------------------------------------------------------------------
 # Digests per entry
 # ---------------------------------------------------------------------------
-def attach_digests(
-    entries: List[dict], step_fn, step_avals: tuple, out_avals: Dict,
-) -> None:
+def attach_digests(entries: List[dict], step_fn, step_avals: tuple) -> None:
     """Lower every enumerated entry and record its StableHLO digest —
     the manifest side of the DX603 drift contract. Mutates in place."""
-    slot_avals: Dict[Tuple[str, int], object] = {}
     for e in entries:
-        name = e["entry"]
-        if name == "step":
-            e["loweringDigest"] = lowering_digest(
-                step_fn, step_avals, tuple(e["donate"])
-            )
-            continue
-        kind, out, cap_s = name.split(":")
-        cap = int(cap_s)
-        t = out_avals[out]
-        if kind == "slice":
-            e["loweringDigest"] = lowering_digest(
-                functools.partial(_slice_impl, cap=cap), (t,)
-            )
-        else:  # pack
-            slot = slot_avals.get((out, cap))
-            if slot is None:
-                slot = jax.eval_shape(
-                    functools.partial(_slice_impl, cap=cap), t
-                )
-                slot_avals[(out, cap)] = slot
-            e["loweringDigest"] = lowering_digest(
-                functools.partial(_pack_impl, cap=cap), (t, slot),
-                donate=(1,),
-            )
+        e["loweringDigest"] = lowering_digest(
+            step_fn, step_avals, tuple(e["donate"])
+        )
 
 
 def build_manifest(
@@ -328,14 +288,11 @@ def build_manifest(
     entries: List[dict],
     chips: int,
     stable: bool,
-    jit_cache_cap: int,
-    sized: bool = True,
-    slots: bool = True,
 ) -> dict:
     """Assemble the deployable manifest. Each entry's ``cacheKey`` is
-    flow-hash x chip count x entry (which carries the capacity bucket)
-    x aval signature — the coordinate a persistent compile cache or a
-    fleet of replicas can dedupe compiled executables on."""
+    flow-hash x chip count x entry x aval signature — the coordinate a
+    persistent compile cache or a fleet of replicas can dedupe compiled
+    executables on."""
     for e in entries:
         e["cacheKey"] = hashlib.sha256(
             f"{flow_hash}|chips={chips}|{e['entry']}|"
@@ -347,9 +304,6 @@ def build_manifest(
         "flowHash": flow_hash,
         "chips": chips,
         "stable": stable,
-        "jitCacheCap": jit_cache_cap,
-        "sized": sized,
-        "slots": slots,
         "entries": entries,
     }
 
@@ -357,13 +311,8 @@ def build_manifest(
 # ---------------------------------------------------------------------------
 # Lints
 # ---------------------------------------------------------------------------
-def _lint_surface(
-    bundle: FlowDevicePlan,
-    entries: List[dict],
-    jit_cache_cap: int,
-    diags: List[Diagnostic],
-) -> bool:
-    """DX600/DX601 over the enumerated surface. Returns ``stable``:
+def _lint_surface(bundle: FlowDevicePlan, diags: List[Diagnostic]) -> bool:
+    """DX600 over the flow's trace surface. Returns ``stable``:
     whether the manifest covers every signature the flow can EVER
     dispatch (False = the initial surface only)."""
     stable = True
@@ -389,27 +338,6 @@ def _lint_surface(
             "step, so the signature set (and the jit cache) grows "
             "without bound; set process.stringdictionary.maxsize to "
             "close the surface",
-        ))
-    # one jitted closure per (helper kind, capacity bucket) — the SAME
-    # key the runtime's LRU-bounded helper cache uses
-    # (runtime/processor.py _helper_jit), so this lint and the runtime
-    # bound can never disagree about what "too many buckets" means
-    helper_keys = {
-        (e["entry"].split(":")[0], e["static"]["cap"])
-        for e in entries
-        if e["entry"] != "step" and "cap" in e["static"]
-    }
-    if len(helper_keys) > jit_cache_cap:
-        diags.append(make(
-            "DX601", "",
-            f"capacity-bucket lattice exceeds the transfer-helper jit "
-            f"cache bound: the reachable sized-transfer buckets alone "
-            f"compile {len(helper_keys)} helper closures but the LRU cap "
-            f"is {jit_cache_cap} (process.compile.jitcachecap, default "
-            f"{DEFAULT_JIT_CACHE_CAP}) — steady-state eviction thrash "
-            f"recompiles helpers mid-stream "
-            f"(Compile_JitCacheEvict_Count); lower the batch capacity "
-            f"or raise the cap",
         ))
     return stable
 
@@ -475,7 +403,6 @@ def analyze_flow_compile(
     chips: Optional[int] = None,
     manifest: Optional[dict] = None,
     digests: bool = True,
-    jit_cache_cap: Optional[int] = None,
 ) -> CompileSurfaceReport:
     """Compile-surface analysis of a flow config (gui JSON or full flow
     document). Pure tracing: compiles with the production planner,
@@ -491,7 +418,6 @@ def analyze_flow_compile(
     diags: List[Diagnostic] = []
     plan_diags: List[Diagnostic] = []
     n_chips = chips or 1
-    cap = jit_cache_cap or _jobconf_cache_cap(gui) or DEFAULT_JIT_CACHE_CAP
     bundle = _plan_from_gui(gui, plan_diags, chips)
     # the bundle builder reports in DX2xx; re-code for this tier
     for d in plan_diags:
@@ -500,7 +426,6 @@ def analyze_flow_compile(
     if bundle is None:
         return CompileSurfaceReport(
             name, n_chips, [], None, _ordered(diags), stable=False,
-            jit_cache_cap=cap,
         )
     try:
         step_avals = _step_input_avals(bundle, gui)
@@ -512,45 +437,31 @@ def analyze_flow_compile(
         ))
         return CompileSurfaceReport(
             name, n_chips, [], None, _ordered(diags), stable=False,
-            jit_cache_cap=cap,
         )
-    sized = slots = n_chips == 1
     try:
         step_fn = _build_step(bundle, gui)
-        out_avals = jax.eval_shape(step_fn, *step_avals)[0]
-        entries = compile_entries_from_avals(
-            step_avals, out_avals, sized=sized, slots=slots
-        )
+        # the trace itself is the check: a step that cannot be traced
+        # over these avals is DX690 whether or not digests are wanted
+        jax.eval_shape(step_fn, *step_avals)
+        entries = [step_compile_entry(step_avals)]
         if digests:
-            attach_digests(entries, step_fn, step_avals, out_avals)
+            attach_digests(entries, step_fn, step_avals)
     except Exception as e:  # noqa: BLE001 — any lowering blowup is a finding
         diags.append(make(
             "DX690", "", f"compile-surface lowering failed: {e}"
         ))
         return CompileSurfaceReport(
             name, n_chips, [], None, _ordered(diags), stable=False,
-            jit_cache_cap=cap,
         )
-    stable = _lint_surface(bundle, entries, cap, diags)
+    stable = _lint_surface(bundle, diags)
     if manifest is not None:
         check_manifest(manifest, entries, diags)
     doc = build_manifest(
-        name, flow_config_hash(gui), entries, n_chips, stable, cap,
-        sized=sized, slots=slots,
+        name, flow_config_hash(gui), entries, n_chips, stable,
     )
     return CompileSurfaceReport(
         name, n_chips, entries, doc, _ordered(diags), stable=stable,
-        jit_cache_cap=cap,
     )
-
-
-def _jobconf_cache_cap(gui: dict) -> Optional[int]:
-    jobconf = ((gui.get("process") or {}).get("jobconfig") or {})
-    v = jobconf.get("jobCompileJitCacheCap")
-    try:
-        return int(v) if v not in (None, "") else None
-    except (TypeError, ValueError):
-        return None
 
 
 def analyze_processor_compile(
@@ -558,34 +469,20 @@ def analyze_processor_compile(
 ) -> CompileSurfaceReport:
     """Compile-surface analysis of an already-built ``FlowProcessor`` —
     the exact step function and device state the runtime dispatches
-    (the drift-test / bench cross-validation path, mirroring
+    (the drift-test cross-validation path, mirroring
     ``deviceplan.analyze_processor``)."""
     diags: List[Diagnostic] = []
     entries = proc.derive_compile_entries()
     if digests:
-        step_avals = proc._step_input_avals()
-        out_avals = jax.eval_shape(proc._step_fn, *step_avals)[0]
-        attach_digests(entries, proc._step_fn, step_avals, out_avals)
+        attach_digests(entries, proc._step_fn, proc._step_input_avals())
     name = proc.dict.get("datax.job.name") or ""
     from .deviceplan import flow_plan_from_processor
 
     bundle = flow_plan_from_processor(proc)
-    cap = DEFAULT_JIT_CACHE_CAP
-    try:
-        cap = (
-            proc.process_conf.get_sub_dictionary("compile.")
-            .get_int_option("jitcachecap") or DEFAULT_JIT_CACHE_CAP
-        )
-    except ValueError:
-        pass
-    stable = _lint_surface(bundle, entries, cap, diags)
+    stable = _lint_surface(bundle, diags)
     if manifest is not None:
         check_manifest(manifest, entries, diags)
-    doc = build_manifest(
-        name, "", entries, 1, stable, cap,
-        sized=proc.sized_transfer, slots=proc.output_slots_enabled,
-    )
+    doc = build_manifest(name, "", entries, 1, stable)
     return CompileSurfaceReport(
         name, 1, entries, doc, _ordered(diags), stable=stable,
-        jit_cache_cap=cap,
     )

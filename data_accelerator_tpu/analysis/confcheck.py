@@ -38,8 +38,8 @@ runtime never saw). This pass makes every hop checkable:
      (``pipeline.depth=0``, a negative TTL, an HBM budget above the
      chip).
    - DX1005 — incompatible-knob combination from the declared
-     constraint table (mesh+sizedtransfer, mesh+backgroundtransfer,
-     ``state.filteringest`` without state partitions).
+     constraint table (``state.filteringest`` without state
+     partitions).
 
 The runtime half lives in ``runtime/confaudit.py`` (DX1006): the same
 registry rows audit the LIVE conf at host/LQ-service init.

@@ -249,33 +249,7 @@ def _truthy(conf: Mapping[str, str], key: str) -> bool:
     return _BOOL_WORDS.get(str(conf.get(key, "")).strip().lower(), False)
 
 
-def _is_mesh(conf: Mapping[str, str]) -> bool:
-    try:
-        chips = int(str(conf.get("numchips", "1") or "1"))
-    except ValueError:
-        chips = 1
-    return chips > 1 or bool(conf.get("mesh.model"))
-
-
 CONSTRAINTS: Tuple[ConfConstraint, ...] = (
-    ConfConstraint(
-        "mesh-sizedtransfer",
-        "pipeline.sizedtransfer=true on a multi-chip mesh job: the "
-        "sized D2H fetch is a single-chip optimization — under a mesh "
-        "every batch fetches the full padded capacity, so the knob is "
-        "silently ignored (the conf half of the DX705 lint)",
-        lambda c: _is_mesh(c) and _truthy(c, "pipeline.sizedtransfer"),
-    ),
-    ConfConstraint(
-        "mesh-backgroundtransfer",
-        "pipeline.backgroundtransfer=true on a multi-chip mesh job: "
-        "the double-buffered background landing path is disabled under "
-        "a mesh (runtime/host.py forces it off), so an explicit 'true' "
-        "documents an intent the engine will not honor",
-        lambda c: _is_mesh(c) and str(
-            c.get("pipeline.backgroundtransfer", "")
-        ).strip().lower() in ("true", "1", "yes", "on"),
-    ),
     ConfConstraint(
         "filteringest-without-partitions",
         "state.filteringest=true without state.partitions: ingest-time "
@@ -355,15 +329,6 @@ CONF_REGISTRY: Tuple[ConfKey, ...] = (
     _K("pipeline.depth", "int", "2", "pipeline", knob="jobPipelineDepth",
        token="guiJobPipelineDepth", source="designer", min=1,
        description="in-flight batch window (decode/dispatch overlap)"),
-    _K("pipeline.sizedtransfer", "bool", "true", "pipeline",
-       source="manual",
-       description="bucketed sized D2H fetch (single-chip only; the "
-                   "mesh-sizedtransfer constraint flags it under a mesh)"),
-    _K("pipeline.backgroundtransfer", "bool", "true", "pipeline",
-       source="manual",
-       description="double-buffered background D2H landing thread"),
-    _K("pipeline.outputslots", "bool", "true", "pipeline", source="manual",
-       description="preallocated pinned output landing slots"),
     _K("ingest.decoderthreads", "int", None, "ingest",
        knob="jobDecoderThreads", token="guiJobDecoderThreads",
        source="designer", min=1,
@@ -417,11 +382,7 @@ CONF_REGISTRY: Tuple[ConfKey, ...] = (
     _K("compile.cacheurl", "url", None, "compile", source="generation",
        description="shared AOT cache object-store URL (S650 embed)"),
     _K("compile.manifest", "path", None, "compile", source="generation",
-       description="compile manifest path (DX601 surface pin)"),
-    _K("compile.jitcachecap", "int", "32", "compile",
-       knob="jobCompileJitCacheCap", token="guiJobCompileJitCacheCap",
-       source="designer", min=1,
-       description="transfer-helper jit cache entry cap"),
+       description="compile manifest path (the AOT warm's surface pin)"),
     # -- debug -------------------------------------------------------------
     _K("debug.nans", "bool", "false", "debug", source="manual",
        description="jax_debug_nans for the flow step"),

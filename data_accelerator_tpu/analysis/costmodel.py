@@ -5,9 +5,8 @@ FLOP count and expected ICI traffic are *closed-form functions* of the
 shapes the planner chose — no execution, no sampling. The formulas here
 consume the ``StagePlan``/``JoinSite`` metadata ``compile/planner.py``
 records at lowering time; ``analysis/deviceplan.py`` cross-checks the
-byte model against ``jax.eval_shape`` over the production lowering (and
-``bench.py`` against the arrays a real batch materializes), so the model
-cannot silently drift from what the compiler actually builds.
+byte model against ``jax.eval_shape`` over the production lowering, so
+the model cannot silently drift from what the compiler actually builds.
 
 Documented in ANALYSIS.md ("Scaling model"): the ICI terms are
 expected bytes over the chip interconnect per batch as a function of
@@ -87,34 +86,10 @@ def d2h_transfer_bytes(
     ``rows_transferred`` rows — the per-batch wire cost of the sync
     stage for that output. The transferred table has exactly the
     view-output layout (schema columns + overflow slots + validity), so
-    the term is ``view_output_bytes`` evaluated at the transfer
-    capacity: the full padded capacity for a plain fetch, or the sized
-    (EWMA-bucketed) capacity under
-    ``datax.job.process.pipeline.sizedtransfer``. See ANALYSIS.md
+    the term is ``view_output_bytes`` evaluated at the output's
+    capacity, the one size an output crosses at. See ANALYSIS.md
     "Scaling model" and the DX206 hint."""
     return view_output_bytes(types, plan, rows_transferred)
-
-
-# donated double-buffered output transfer slots
-# (runtime/processor.py _stage_output): each output dataset keeps this
-# many transfer-ready copies of its table resident in HBM, alternating
-# A/B so batch N+1's jitted pack never clobbers batch N's in-flight
-# background D2H copy
-OUTPUT_SLOT_BUFFERS = 2
-
-
-def output_slot_bytes(
-    types: Dict[str, str], plan: Optional[StagePlan], capacity: int
-) -> int:
-    """Closed-form HBM bytes of one output's donated transfer slots:
-    ``OUTPUT_SLOT_BUFFERS`` resident copies of the view-output layout
-    at the slot capacity. The runtime sizes slots at the adaptive
-    (EWMA-bucketed) transfer capacity, bounded above by the padded
-    output capacity — the static model charges the bound, like every
-    other capacity it accounts. These bytes are persistent (the slots
-    live as long as the flow), so they join the DX2xx/DX4xx HBM totals
-    the fleet placer packs against."""
-    return OUTPUT_SLOT_BUFFERS * view_output_bytes(types, plan, capacity)
 
 
 # fraction of one chip's HBM the LiveQuery serving plane may pin in
@@ -264,8 +239,7 @@ def latency_model(
     ``stages``/``totals`` are dict-shaped (``StageCost.to_dict()`` /
     ``DevicePlanReport.totals()`` or the conf-embedded runtime model).
     Consumed by the ``--device`` report, the designer Validate cost
-    table, bench.py's roofline block, and the host's DX520/DX521
-    predictions."""
+    table and the host's DX520/DX521 predictions."""
     overhead_ms = float(profile.get("dispatch_overhead_us") or 0.0) / 1000.0
     out_stages = []
     compute_ms = 0.0

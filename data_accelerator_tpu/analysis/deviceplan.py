@@ -16,9 +16,9 @@ jits — then derives every stage's static shapes with ``jax.eval_shape``
 
 Two byte numbers per stage keep the model honest: ``hbm_bytes`` comes
 from ``jax.eval_shape`` over the production lowering (ground truth
-shapes), ``model_bytes`` from the closed forms. ``bench.py`` records
-both, and a tier-1 test asserts they match the arrays a real batch
-materializes — the static model can never silently drift from reality.
+shapes), ``model_bytes`` from the closed forms. A tier-1 test asserts
+they match the arrays a real batch materializes — the static model can
+never silently drift from reality.
 """
 
 from __future__ import annotations
@@ -56,9 +56,7 @@ from ..runtime.timewindow import num_slots
 from ..serve.flowbuilder import RuleDefinitionGenerator
 from .costmodel import (
     DEFAULT_MATCH_MATRIX_BUDGET,
-    OUTPUT_SLOT_BUFFERS,
     d2h_transfer_bytes,
-    output_slot_bytes,
     row_bytes,
     stage_flops,
     stage_ici_bytes,
@@ -84,9 +82,8 @@ D2H_OVERSIZE_FACTOR = 64
 _STRUCT_DTYPES = {"double": jnp.float32, "boolean": jnp.bool_}
 
 # stage kinds that persist across batches (device-resident state) vs
-# materialized per batch; "outslot" = the donated double-buffered
-# output transfer slots the runtime keeps resident per output
-PERSISTENT_KINDS = ("ring", "state", "refdata", "outslot")
+# materialized per batch
+PERSISTENT_KINDS = ("ring", "state", "refdata")
 
 
 def table_struct(schema: ViewSchema, rows: int) -> TableData:
@@ -121,15 +118,15 @@ def _table_data_bytes(td: TableData) -> int:
 @dataclass
 class StageCost:
     name: str
-    kind: str  # input | project | ring | window | state | refdata | group | union
+    kind: str  # input | project | ring | window | state | refdata | group | union | sync
     rows: int
     hbm_bytes: int  # from eval_shape over the production lowering
     model_bytes: int  # closed-form prediction (costmodel.py)
     transient_bytes: int = 0  # peak in-stage intermediates (match matrix)
     flops: float = 0.0
     ici_bytes: float = 0.0  # expected interconnect bytes/batch at `chips`
-    # device->host bytes a full-capacity fetch of this stage moves per
-    # batch — non-zero only for OUTPUT views (the sync-stage wire cost)
+    # device->host bytes this stage moves per batch — non-zero only for
+    # OUTPUT views, fetched at their capacity, and the counts vector
     d2h_bytes: int = 0
     detail: str = ""
 
@@ -197,7 +194,7 @@ class DevicePlanReport:
         ``latencyModel`` (closed-form milliseconds under a machine
         profile — the datasheet default here; a *calibrated* profile
         replaces it wherever one is available: the host's DX520
-        predictions and bench.py's roofline block)."""
+        predictions)."""
         return {
             "flow": self.flow,
             "chips": self.chips,
@@ -329,7 +326,7 @@ def combined_report_dict(
 class FlowDevicePlan:
     """Everything the evaluator/linter needs, built from either a flow
     config (``analyze_flow_device``) or a live ``FlowProcessor``
-    (``analyze_processor`` — the bench/test path)."""
+    (``analyze_processor`` — the test path)."""
 
     name: str
     pipeline: Pipeline
@@ -645,7 +642,7 @@ def _plan_from_gui(
 
 
 # ---------------------------------------------------------------------------
-# Builder: from a live FlowProcessor (bench / tier-1 drift test path)
+# Builder: from a live FlowProcessor (tier-1 drift test path)
 # ---------------------------------------------------------------------------
 def flow_plan_from_processor(proc, chips: Optional[int] = None) -> FlowDevicePlan:
     """Bundle an already-built ``FlowProcessor``'s compiled plan — the
@@ -827,31 +824,22 @@ def _stage_walk(
             view, _table_data_bytes(out), plan, plan.pipeline.catalog
         )
         if view.name in plan.output_datasets:
-            # the sync-stage wire cost: a full-capacity fetch of this
-            # output's table crosses the device->host boundary per batch
+            # the sync-stage wire cost: this output's table crosses
+            # the device->host boundary at its capacity, every batch
             stage.d2h_bytes = d2h_transfer_bytes(
                 view.schema.types, view.plan, view.capacity
             )
         stages.append(stage)
-        if view.name in plan.output_datasets:
-            # the donated double-buffered transfer slots the runtime
-            # keeps resident for this output (runtime/processor.py
-            # _stage_output): OUTPUT_SLOT_BUFFERS copies of the output
-            # layout, persistent HBM the placer must pack. Lowered
-            # bytes derive from the same evaluated table as the view
-            # stage, so model == lowering stays exact.
-            stages.append(StageCost(
-                name=f"outslot:{view.name}", kind="outslot",
-                rows=view.capacity,
-                hbm_bytes=OUTPUT_SLOT_BUFFERS * _table_data_bytes(out),
-                model_bytes=output_slot_bytes(
-                    view.schema.types, view.plan, view.capacity
-                ),
-                detail=(
-                    f"{OUTPUT_SLOT_BUFFERS}x donated transfer slots "
-                    f"(A/B double buffer)"
-                ),
-            ))
+    # the counts vector the step packs (runtime/processor.py
+    # build_step_fn: the input count, per output its row count and two
+    # overflow slots, per source target its projected count): the
+    # batch's one blocking read, and part of every batch's D2H bytes
+    n = 1 + 3 * len(plan.output_datasets) + len(plan.target_of)
+    stages.append(StageCost(
+        name="sync:counts", kind="sync", rows=n,
+        hbm_bytes=4 * n, model_bytes=4 * n, d2h_bytes=4 * n,
+        detail="packed int32 counts vector (the batch's sync point)",
+    ))
     return stages
 
 
@@ -917,22 +905,15 @@ def _lint(
                     per_batch = d2h_transfer_bytes(
                         view.schema.types, p, view.capacity
                     )
-                    slot_bytes = output_slot_bytes(
-                        view.schema.types, p, view.capacity
-                    )
                     diags.append(make(
                         "DX206", view.name,
                         f"output capacity {view.capacity} exceeds the "
                         f"modeled group count {product} by more than "
-                        f"{D2H_OVERSIZE_FACTOR}x: a full fetch moves "
-                        f"{per_batch} D2H bytes/batch of mostly padding "
-                        f"through the sync stage, and the "
-                        f"{OUTPUT_SLOT_BUFFERS}x donated transfer slots "
-                        f"pin {slot_bytes} HBM bytes at that padding; "
-                        f"sized output transfer "
-                        f"(process.pipeline.sizedtransfer, default on) "
-                        f"or a tighter process.maxgroups shrinks both to "
-                        f"the wire minimum",
+                        f"{D2H_OVERSIZE_FACTOR}x: every batch moves "
+                        f"{per_batch} D2H bytes of mostly padding "
+                        f"through the sync stage; a tighter "
+                        f"process.maxgroups shrinks it toward the wire "
+                        f"minimum",
                     ))
         for s in p.joins:
             if s.out_rows < s.left_rows:
@@ -1070,8 +1051,7 @@ def analyze_processor(
     match_matrix_budget: int = DEFAULT_MATCH_MATRIX_BUDGET,
 ) -> DevicePlanReport:
     """Device-plan analysis of an already-built ``FlowProcessor`` — the
-    exact compiled views the jitted step runs (bench.py's
-    predicted-vs-measured cross-validation path)."""
+    exact compiled views the jitted step runs."""
     diags: List[Diagnostic] = []
     bundle = flow_plan_from_processor(proc, chips)
     return _analyze(bundle, diags, bundle.name, chips, match_matrix_budget)

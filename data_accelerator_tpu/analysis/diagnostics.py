@@ -136,7 +136,7 @@ CODES: Dict[str, tuple] = {
     "DX205": (SEV_WARNING, "window retention approaches the int32 ring-rebase horizon (~24.8 days of relative millis)",
               "shorten the window/watermark well below a quarter of the 2^31 ms horizon"),
     "DX206": (SEV_WARNING, "output capacity exceeds the modeled row count by >64x: the sync stage transfers mostly padding device->host",
-              "keep sized output transfer on (process.pipeline.sizedtransfer) or tighten process.maxgroups toward the modeled cardinality"),
+              "tighten process.maxgroups (or the batch capacity) toward the modeled cardinality: every output crosses at its declared capacity"),
     "DX290": (SEV_ERROR, "flow fails device lowering: the planner rejected a statement the runtime would also reject",
               "fix the statement per the planner's message (it is the production compiler's own error)"),
     "DX291": (SEV_WARNING, "device analysis unavailable: no concrete input schema or design-time-unloadable UDF",
@@ -152,7 +152,7 @@ CODES: Dict[str, tuple] = {
     "DX402": (SEV_WARNING, "placement feasible but a chip lands above the configured headroom fraction: one capacity bump or retrace OOMs it",
               "rebalance by adding chips or shrinking the co-placed flows, or raise headroomFraction deliberately"),
     "DX403": (SEV_WARNING, "aggregate D2H/ICI bandwidth demand across the fleet exceeds the modeled budget: sync stages will contend",
-              "stagger batch intervals, shrink output capacities (sized transfer), or raise the spec's bandwidth budgets"),
+              "stagger batch intervals, shrink output capacities, or raise the spec's bandwidth budgets"),
     "DX410": (SEV_ERROR, "two flows share a checkpoint/state/output directory: restarts corrupt each other's offsets and window state",
               "give each flow a distinct checkpoint dir and sink folder (flow names key the defaults — rename one flow)"),
     "DX411": (SEV_ERROR, "Kafka/EventHub consumer-group collision on overlapping topics: the broker splits records between the flows",
@@ -192,8 +192,6 @@ CODES: Dict[str, tuple] = {
               "group/join on lower-cardinality keys, shrink output capacities, or raise the spec's iciBytesPerSecPerChip deliberately"),
     "DX704": (SEV_WARNING, "scaling cliff: the stage's modeled per-chip cost is flat or worse in the chip count (replicated compute at batch scale, or collective wire growth outpacing the compute shrink)",
               "reshape the stage so rows stay sharded (shard-friendly keys, no full-capacity replication), or stop adding chips past the cliff"),
-    "DX705": (SEV_WARNING, "sized output transfer and donated output slots auto-disable under a mesh: every output fetch moves the full padded capacity and no background double-buffering applies",
-              "expect full-capacity D2H under the mesh, or keep the flow single-chip until the sharded sized-transfer path exists"),
     "DX790": (SEV_ERROR, "mesh lowering failed or disagrees with the sharding model: the partition plan's closed-form collective bytes do not match what the SPMD partitioner emitted",
               "fix the statement per the lowering error, or regenerate after engine changes — the byte model must match the lowering exactly"),
     "DX791": (SEV_WARNING, "mesh analysis unavailable or unvalidated: no concrete input schema, fewer than two devices to lower the partition plan against, or a Pallas-kernel stage on a backend that is not a TPU",
@@ -204,13 +202,11 @@ CODES: Dict[str, tuple] = {
     #    stable, emit the AOT compile manifest) -----------------------
     "DX600": (SEV_WARNING, "open trace surface: UDF interval refresh or unbounded dictionary growth re-traces the step with new signatures, so the jit cache (and any AOT promise) grows without bound",
               "drop the on_interval refresh or bound the dictionary (process.stringdictionary.maxsize) so the manifest covers every signature the flow can dispatch"),
-    "DX601": (SEV_WARNING, "reachable sized-transfer capacity buckets alone exceed the transfer-helper jit cache bound: steady-state LRU eviction recompiles helpers mid-stream",
-              "lower the batch capacity (fewer pow2 buckets) or raise process.compile.jitcachecap above the lattice size"),
     "DX602": (SEV_ERROR, "manifest donation/aliasing mismatch: a shipped manifest entry's donated argnums disagree with the runtime's donation contract",
               "regenerate the manifest (--compile emits it); never hand-edit donation patterns — they alias live device buffers"),
     "DX603": (SEV_ERROR, "manifest-vs-lowering drift: a shipped manifest's entries/avals/lowering digests no longer match what this flow compiles to",
               "regenerate the manifest after any flow, schema, capacity or engine change (warm starts from a stale manifest recompile at dispatch, surfacing as Compile_WarmMiss_Count)"),
-    "DX690": (SEV_ERROR, "compile-surface lowering failed: the fused step (or a transfer helper) cannot trace/lower over the derived avals",
+    "DX690": (SEV_ERROR, "compile-surface lowering failed: the fused step cannot trace/lower over the derived avals",
               "fix the statement per the lowering error (it is the production compiler's own failure, seen early)"),
     "DX691": (SEV_WARNING, "compile-surface analysis unavailable: no concrete input schema, design-time-unloadable UDF, or unreadable reference data",
               "inline the input schema JSON, make UDF modules importable on the control plane, and keep refdata CSVs readable at design time"),
@@ -226,8 +222,6 @@ CODES: Dict[str, tuple] = {
               "use a real copy, or annotate the site '# dx-race: allow-zero-copy <reason>' if the view provably dies before the buffer is donated/reused"),
     "DX802": (SEV_ERROR, "shared state raced between the dispatch loop and a background thread: an attribute guarded by a lock elsewhere is mutated without that lock, or two locks are acquired in conflicting orders",
               "take the associated lock around the write (or mark a provably pre-thread path '# dx-race: single-threaded <reason>'); keep lock acquisition order consistent with the device-state lock"),
-    "DX803": (SEV_ERROR, "transfer slot re-donated before its land ack: donation of an A/B slot buffer is not dominated by the previous batch's landed-event check, so XLA may free a buffer the background landing thread is still reading",
-              "gate the donation on the previous slot's _landed.is_set()/wait() (the slot-rotation contract the compile manifest's donate pattern assumes)"),
     "DX804": (SEV_ERROR, "blocking device sync on a thread the pipeline model requires non-blocking: block_until_ready/device_get/a blocking wait inside a function marked '# dx-race: non-blocking' stalls the dispatch overlap the depth-N window exists to provide",
               "move the sync to the landing thread (collect_counts is the one sanctioned sync point), use the async copy path, or drop the non-blocking marker if the function is genuinely allowed to block"),
     # -- pass 12: exactly-once delivery protocol (analysis/protocheck.py,
@@ -264,7 +258,7 @@ CODES: Dict[str, tuple] = {
                "align the fallback literal with the registry default (the registry row is the single source of truth)"),
     "DX1004": (SEV_ERROR, "conf type/bounds violation: a concrete flow conf value fails its registry row's type, bounds or choices (pipeline.depth=0, a negative TTL, an HBM budget above the chip)",
                "fix the flow's designer knob / conf value to satisfy the registered type and bounds"),
-    "DX1005": (SEV_ERROR, "incompatible conf combination: a declared mutual-exclusion constraint is violated (mesh+sizedtransfer, mesh+backgroundtransfer, state.filteringest without state partitions)",
+    "DX1005": (SEV_ERROR, "incompatible conf combination: a declared mutual-exclusion constraint is violated (state.filteringest without state partitions)",
                "drop one side of the combination — the constraint table in analysis/confspec.py documents why they cannot compose"),
     "DX1006": (SEV_ERROR, "live conf failed the registry audit: the host/LQ service booted with an unknown or out-of-bounds datax.job.process.* key (runtime/confaudit.py)",
                "regenerate the flow's conf (stale key) or fix the out-of-bounds value; the Conf_{Audited,Unknown,OutOfBounds}_Count metrics carry the counts"),

@@ -643,7 +643,6 @@ def _lint(
     stages: List[MeshStage],
     chips: int,
     spec: FleetSpec,
-    jobconf: Dict[str, object],
     diags: List[Diagnostic],
 ) -> None:
     batch_scale = max(bundle.target_caps.values(), default=0)
@@ -729,25 +728,6 @@ def _lint(
                 f"here (first {chips}-chip victim)",
             ))
 
-    # DX705: single-chip transfer optimizations silently off under mesh
-    def _off(key: str) -> bool:
-        return str(jobconf.get(key, "")).lower() == "false"
-
-    if (
-        chips > 1
-        and bundle.output_datasets
-        and not (_off("jobSizedTransfer") and _off("jobOutputSlots"))
-    ):
-        diags.append(make(
-            "DX705", "",
-            f"sized output transfer and donated output slots "
-            f"auto-disable under a {chips}-chip mesh: every batch "
-            f"fetches the full padded capacity of "
-            f"{sorted(bundle.output_datasets)} and the background "
-            f"double-buffered landing path does not apply — the "
-            f"single-chip D2H optimizations do not compound here yet",
-        ))
-
 
 # ---------------------------------------------------------------------------
 # Entry points
@@ -758,7 +738,6 @@ def _analyze(
     name: str,
     chips: int,
     spec: Optional[FleetSpec],
-    jobconf: Dict[str, object],
     lower: Optional[bool],
 ) -> MeshPlanReport:
     if bundle is None:
@@ -769,7 +748,7 @@ def _analyze(
     except Exception as e:  # noqa: BLE001 — plan inference blowup is a finding
         diags.append(make("DX790", "", f"partition-plan inference failed: {e}"))
         return MeshPlanReport(bundle.name, chips, [], _ordered(diags))
-    _lint(bundle, stages, chips, spec, jobconf, diags)
+    _lint(bundle, stages, chips, spec, diags)
 
     validated = False
     n_dev = len(jax.devices())
@@ -827,7 +806,7 @@ def analyze_flow_mesh(
         code = "DX790" if d.code == "DX290" else "DX791"
         diags.append(make(code, d.table, d.message, d.span))
     return _analyze(
-        bundle, diags, gui.get("name") or "", n_chips, spec, jobconf, lower
+        bundle, diags, gui.get("name") or "", n_chips, spec, lower
     )
 
 
@@ -839,10 +818,10 @@ def analyze_processor_mesh(
 ) -> MeshPlanReport:
     """Mesh-sharding analysis of an already-built ``FlowProcessor`` —
     the exact compiled views the (possibly mesh-sharded) jitted step
-    runs (the bench / MULTICHIP cross-validation path, mirroring
+    runs (the MULTICHIP cross-validation path, mirroring
     ``deviceplan.analyze_processor``)."""
     diags: List[Diagnostic] = []
     n_chips = chips or (proc.mesh.size if proc.mesh is not None else None)
     bundle = flow_plan_from_processor(proc, n_chips)
     n_chips = n_chips or DEFAULT_MESH_CHIPS
-    return _analyze(bundle, diags, bundle.name, n_chips, spec, {}, lower)
+    return _analyze(bundle, diags, bundle.name, n_chips, spec, lower)

@@ -15,7 +15,7 @@ ASTs — except the analyzed ASTs are ``runtime/``, ``lq/`` and
 
 Buffer provenance lattice
 -------------------------
-Every expression carries one of four provenances:
+Every expression carries one of three provenances:
 
 - ``ring``  — a window ring buffer (``self.window_buffers`` and its
   ``cols``/``valid`` members): the step's DONATED argument
@@ -24,9 +24,6 @@ Every expression carries one of four provenances:
   (``pool.acquire()`` results, ``_ingest_pool``/``_ingest_pools``/
   ``_ingest_buffers``): reused for the next decode once its batch
   lands;
-- ``slot``  — an A/B output transfer slot (``self._slots``): donated
-  into the next ``_pack_slot`` once the previous batch's land ack
-  fires;
 - plain — everything else.
 
 Provenance flows through assignments, attribute/subscript loads,
@@ -39,7 +36,7 @@ class rides on.
 
 The checks
 ----------
-- **DX800** — a ``ring``/``pool``/``slot`` value escapes its guarded
+- **DX800** — a ``ring``/``pool`` value escapes its guarded
   scope: returned, stored into an attribute, stored into a container
   that is itself attribute-reachable or returned, or handed to another
   thread (``executor.submit``/``Thread(...)``) — without a real copy.
@@ -51,9 +48,6 @@ The checks
   ``with self.<lock>`` in one method and written WITHOUT that lock in
   another (``__init__`` and marked single-threaded paths exempt),
   plus conflicting lock-acquisition orders within a class.
-- **DX803** — slot re-donated before its land ack: a ``_pack_slot``
-  donation whose argument has ``slot`` provenance is not dominated by
-  an ``is_set()``/``wait()`` land-ack check in the same function.
 - **DX804** — blocking device sync (``block_until_ready``/
   ``device_get``/blocking waits) inside a function the pipeline model
   requires non-blocking (marked ``# dx-race: non-blocking``).
@@ -72,7 +66,7 @@ Line-scoped (same line as the site, or the line directly above):
 
 Function-scoped (any line inside the function):
 
-- ``# dx-race: param <name>=<ring|pool|slot>`` — seeds a parameter's
+- ``# dx-race: param <name>=<ring|pool>`` — seeds a parameter's
   provenance (inter-procedural edge the walk cannot see).
 - ``# dx-race: single-threaded <reason>`` — exempts a provably
   pre-thread/re-init path from the DX802 lockset rule.
@@ -101,7 +95,6 @@ from .diagnostics import Diagnostic, Span, make
 # provenance values
 RING = "ring"
 POOL = "pool"
-SLOT = "slot"
 
 # attribute names that SEED provenance when loaded (the runtime's own
 # ownership roots; see the module docstring's lattice)
@@ -110,7 +103,6 @@ SEED_ATTRS = {
     "_ingest_pools": POOL,
     "_ingest_pool": POOL,
     "_ingest_buffers": POOL,
-    "_slots": SLOT,
 }
 
 # attribute accesses that traverse INTO a provenanced object without
@@ -127,7 +119,7 @@ _BLOCKING_ATTRS = {
 _NUMPY_NAMES = {"np", "numpy", "jnp"}
 
 _MARKER_RE = re.compile(r"#\s*dx-race:\s*([a-z-]+)\s*(.*)$")
-_PARAM_RE = re.compile(r"^(\w+)\s*=\s*(ring|pool|slot)\s*$")
+_PARAM_RE = re.compile(r"^(\w+)\s*=\s*(ring|pool)\s*$")
 
 
 @dataclass
@@ -252,7 +244,6 @@ class _FnRace:
         self.marks = fn_marks
         self.non_blocking = "non-blocking" in fn_marks
         self.single_threaded = "single-threaded" in fn_marks
-        self.land_ack_seen = False
         self.locks_held: Tuple[str, ...] = locks_held
 
     # -- provenance of an expression (also performs call-site checks) --
@@ -324,7 +315,7 @@ class _FnRace:
 
     def _call(self, node: ast.Call) -> Optional[str]:
         func = node.func
-        # walk args for side-effects first (nested calls, land acks)
+        # walk args for side-effects first (nested calls)
         arg_provs = [self._prov(a) for a in node.args]
         kw_provs = {
             (kw.arg or "**"): self._prov(kw.value) for kw in node.keywords
@@ -334,8 +325,6 @@ class _FnRace:
             base, attr = func.value, func.attr
             base_name = _dotted(base)
 
-            if attr in ("is_set", "wait") :
-                self.land_ack_seen = True
             if attr in _BLOCKING_ATTRS:
                 self._check_blocking(node, attr)
             if attr == "asarray" and base_name in _NUMPY_NAMES:
@@ -366,9 +355,6 @@ class _FnRace:
                 return None
             if attr == "acquire" and "pool" in base_name.lower():
                 return POOL
-            if attr.endswith("_pack_slot"):
-                self._check_donation(node, arg_provs)
-                return None
             if attr == "submit" or attr == "apply_async":
                 self._check_thread_handoff(node, arg_provs, kw_provs)
                 return None
@@ -402,18 +388,6 @@ class _FnRace:
             "DX804", node.lineno,
             f"blocking call {what}() inside non-blocking "
             f"{self._where()} (dispatch-path contract)",
-        )
-
-    def _check_donation(self, node: ast.Call, arg_provs) -> None:
-        if SLOT not in [p for p in arg_provs if p]:
-            return
-        if self.land_ack_seen:
-            return
-        self.l.emit(
-            "DX803", node.lineno,
-            f"slot buffer donated in {self._where()} without a "
-            f"preceding land-ack check (is_set()/wait() on the "
-            f"previous batch's landed event)",
         )
 
     def _check_thread_handoff(self, node: ast.Call, arg_provs, kw_provs) -> None:

@@ -156,12 +156,10 @@ class MetricName:
         # waiting for the window's oldest batch
         r"Pipeline_Depth",
         r"Pipeline_Stall_Ms",
-        # sized output transfer (runtime/processor.py PendingBatch):
-        # D2H bytes per batch, valid/transferred row ratio, and the
-        # sized-cap-overflow / slot-contention fallback counters
+        # output transfer (runtime/processor.py PendingBatch): D2H
+        # bytes per batch and the valid/transferred row ratio
         r"Transfer_D2HBytes",
         r"Transfer_Efficiency",
-        r"Transfer_(Overflow|SlotContended)_Count",
         # buffer sanitizer (runtime/sanitizer.py, armed via
         # process.debug.buffersanitizer): buffers guarded per collect,
         # and use-after-release detections — runtime DX805, the dynamic
@@ -251,14 +249,12 @@ class MetricName:
         # (runtime/processor.py process.compile.*): init-time warm cost,
         # persistent-cache hit/miss counts at cache-entry granularity,
         # warm-start promises missed (a dispatch compiled after an AOT
-        # warm — the runtime face of DX604), shipped-manifest drift
-        # detected at warm time (the runtime face of DX603), and
-        # LRU evictions from the bounded transfer-helper jit caches
+        # warm — the runtime face of DX604) and shipped-manifest drift
+        # detected at warm time (the runtime face of DX603)
         r"Compile_ColdStart_Ms",
         r"Compile_Cache_(Hit|Miss)_Count",
         r"Compile_WarmMiss_Count",
         r"Compile_ManifestDrift_Count",
-        r"Compile_JitCacheEvict_Count",
         # alert engine (obs/alerts.py): count of currently-firing rules,
         # exported every evaluation so dashboards can chart alert state
         r"Alerts_Firing",

@@ -52,14 +52,13 @@ a sustained drift is one event, not one per batch; the cumulative
 observability substrate ROADMAP item 5's controller reads: you cannot
 act on drift you cannot see.
 
-Device-resident result path note: with background transfer
-(``process.pipeline.backgroundtransfer``) ``observe()`` is called from
-the host's landing thread, one call per batch finish in strict FIFO
-order — the windowed series it judges (``Transfer_D2HBytes``, which
-includes the counts vector's ``Sync_CountsBytes``, per-output
-occupancy, retraces) are unchanged by the split, and the modeled
-``d2hBytesPerBatch`` it compares against stays a wire-bytes term (the
-donated output-slot HBM lives in the model's ``hbmBytes``, not here).
+Result path note: in the pipelined loop on one chip ``observe()`` is
+called from the host's landing thread, one call per batch finish in
+strict FIFO order. Every output crosses at its declared capacity, so
+``Transfer_D2HBytes`` (the outputs plus the counts vector's
+``Sync_CountsBytes``) equals the modeled ``d2hBytesPerBatch`` to the
+byte on every batch; DX501 fires when an engine change moves bytes the
+model does not know.
 """
 
 from __future__ import annotations
@@ -87,9 +86,8 @@ DRIFT_CODES: Dict[str, str] = {
     "DX522": "hbm-footprint-drift",
 }
 
-# observed/predicted ratio above which DX501 fires (sized transfer makes
-# observed < predicted the healthy direction; exceeding the model means
-# the model missed traffic)
+# observed/predicted ratio above which DX501 fires (the healthy ratio
+# is 1.0; exceeding the model means the model missed traffic)
 DEFAULT_D2H_RATIO_HIGH = 1.5
 # observed/predicted ratio above which DX510 fires. The DX7xx model
 # prices the planned-layout gathers; GSPMD legitimately trades them for

@@ -1,8 +1,7 @@
-"""Fixed-bucket latency histograms: one measurement path, three readers.
+"""Fixed-bucket latency histograms: one measurement path, two readers.
 
 The per-stage latency decomposition (decode -> dispatch -> device step ->
-completion sync -> collect) previously existed only offline in bench.py;
-this type makes it a live, queryable distribution:
+completion sync -> collect) as a live, queryable distribution:
 
 - **Prometheus exposition** reads the fixed cumulative buckets
   (``/metrics`` renders ``_bucket``/``_sum``/``_count`` series so any
@@ -12,9 +11,6 @@ this type makes it a live, queryable distribution:
   recent raw samples — exact over the window, not bucket-interpolated,
   so the numbers match what an offline ``np.percentile`` over the same
   samples would say.
-- **bench.py** observes its sequential-latency stages into the same
-  type, so BENCH_*.json and the live dashboard cannot drift: one
-  ``observe()``, one ``percentile()``.
 
 reference analog: AppInsights aggregates the ``streaming/batch/*``
 timings server-side; here the aggregation is in-process and the

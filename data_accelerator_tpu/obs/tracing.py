@@ -21,8 +21,7 @@ Design notes:
 - A thread-local *active trace* lets deep code (sinks, checkpointers,
   the processor's collect path) attach child spans without threading a
   context object through every signature: ``with tracing.span("x"):``
-  is a no-op when no trace is active (e.g. bench.py driving the
-  processor directly).
+  is a no-op when no trace is active.
 - Cross-thread stages (the pipelined decode-ahead worker) re-activate
   the batch's context explicitly via ``ctx.activate()``.
 - Every finished span also feeds the per-stage latency histograms

@@ -191,8 +191,7 @@ class BatchHost:
         ``process.pipeline.depth`` chunks stay in flight (the
         generalized P6 overlap shared with
         ``StreamingHost.run_pipelined``); finishes are strictly FIFO so
-        state-table commits happen in chunk order. With
-        ``process.pipeline.backgroundtransfer`` (default on) a finish
+        state-table commits happen in chunk order. On one chip a finish
         blocks only on the chunk's counts vector — the streamed output
         tables land and sinks run on a dedicated background landing
         worker (still FIFO: one worker, submission order), so file
@@ -208,11 +207,7 @@ class BatchHost:
         files = self.list_files_to_process()
         cap = self.processor.batch_capacity
         depth = max(1, self.processor.pipeline_depth)
-        background = (
-            (self.dict.get_sub_dictionary("datax.job.process.pipeline.")
-             .get_or_else("backgroundtransfer", "true") or "")
-            .lower() != "false"
-        ) and self.processor.mesh is None
+        background = self.processor.mesh is None
         totals: Dict[str, float] = {"Batch_Files_Count": float(len(files))}
         batch_time_ms = int(t0 * 1000)
         pending = deque()  # FIFO window of (handle, trace) in flight
@@ -226,7 +221,7 @@ class BatchHost:
         def land(handle, trace) -> None:
             """The chunk tail behind the counts sync: resolve streamed
             tables, sinks, commit. Runs on the landing worker (or
-            inline when background transfer is off)."""
+            inline under a mesh)."""
             if landing_failed:
                 handle.abandon()
                 trace.end(status="aborted")

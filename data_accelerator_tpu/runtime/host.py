@@ -348,18 +348,10 @@ class StreamingHost:
         # commit -> ack -> metrics -> checkpoint) runs on this dedicated
         # single-thread landing executor — one worker, so landings stay
         # strictly FIFO while the dispatch loop keeps feeding the
-        # device. Conf datax.job.process.pipeline.backgroundtransfer
-        # (default on); off under a mesh like sized transfer.
-        pipe_conf = dict_.get_sub_dictionary(
-            SettingNamespace.JobProcessPrefix + "pipeline."
-        )
-        self.background_transfer = (
-            (pipe_conf.get_or_else("backgroundtransfer", "true") or "")
-            .lower() != "false"
-        ) and self.processor.mesh is None
+        # device. Off under a mesh, where the tail runs inline.
         self._landing_pool = (
             ThreadPoolExecutor(1, thread_name_prefix="landing")
-            if self.background_transfer else None
+            if self.processor.mesh is None else None
         )
         self._landings = deque()  # futures of submitted landings, FIFO
         self._landing_failed: Optional[BaseException] = None
@@ -1026,10 +1018,10 @@ class StreamingHost:
           that produced it, so deep windows decode against their own
           compiled shapes.
 
-        With ``process.pipeline.backgroundtransfer`` (default on) each
-        finish blocks only on the counts vector; the streamed output
-        tables land and sinks ack on the background landing thread,
-        bounded to at most ``depth`` queued landings (backpressure)."""
+        On one chip each finish blocks only on the counts vector; the
+        streamed output tables land and sinks ack on the background
+        landing thread, bounded to at most ``depth`` queued landings
+        (backpressure). Under a mesh the tail runs inline."""
         if depth is None:
             # resume from the COMMANDED depth: a pilot retarget from an
             # earlier run persists across loop restarts (== the conf'd
@@ -1038,7 +1030,7 @@ class StreamingHost:
         depth = max(1, depth)
         self._depth_target = None
         self._live_depth = depth
-        background = self.background_transfer and self._landing_pool is not None
+        background = self._landing_pool is not None
         # FIFO window of (PendingBatch, consumed, batch_time_ms, t0, trace)
         pending = deque()
         pool = ThreadPoolExecutor(1)
@@ -1131,7 +1123,7 @@ class StreamingHost:
                 fut_trace.end(status="aborted")
             for item in pending:
                 item[4].end(status="aborted")  # idempotent
-                item[0].abandon()  # release transfer slots
+                item[0].abandon()  # return its pooled ingest matrices
             self._settle_landings()
             for s in self.sources.values():
                 s.requeue_unacked()

@@ -24,13 +24,11 @@ inside the single jitted step.
 from __future__ import annotations
 
 import contextlib
-import functools
 import json
 import logging
 import os
 import threading
 import time
-from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple, Union
 
@@ -65,40 +63,12 @@ logger = logging.getLogger(__name__)
 # copies land; finish/commit stays strictly FIFO
 DEFAULT_PIPELINE_DEPTH = 2
 
-# sized output transfer: adapt the per-output D2H copy to the rows a
-# flow actually produces (EWMA of observed counts, bucketed to powers
-# of two) instead of the full padded capacity
-TRANSFER_EWMA_ALPHA = 0.25
-TRANSFER_HEADROOM = 4  # sized cap >= HEADROOM * EWMA (burst absorption)
-MIN_TRANSFER_ROWS = 256  # below this, shrinking saves nothing
-# after an overflow re-fetch, the output's headroom factor doubles for
-# the next N batches so back-to-back bursts can't thrash the two-phase
-# fallback (the EWMA jump alone only covers the observed count, not a
-# still-climbing one)
-OVERFLOW_BOOST_FACTOR = 2
-OVERFLOW_BOOST_BATCHES = 8
-
-# donated double-buffered output slots: the jitted slot-pack writes each
-# output's transfer view into one of two resident, transfer-ready buffer
-# sets per (output, capacity bucket), alternating A/B so batch N+1's
-# step never clobbers batch N's in-flight background D2H copy
-OUTPUT_SLOT_BUFFERS = 2
-
 # donation contract of the fused step jit: the window rings (positional
 # arg 1) are donated so XLA updates them in place; nothing else is.
 # The compile-surface analyzer (analysis/compilecheck.py) records this
 # pattern per manifest entry — DX602 fires when a shipped manifest
 # disagrees with it.
 STEP_DONATE_ARGNUMS = (1,)
-
-# bound on the per-capacity-bucket jit caches of the transfer helpers
-# (_slice_table/_pack_slot): one jitted closure per (helper kind, pow2
-# capacity bucket), LRU-evicted above this cap so a wandering EWMA can
-# never grow the cache forever. Conf datax.job.process.compile.
-# jitcachecap overrides; the DX601 compile-surface lint uses the SAME
-# constant to flag flows whose reachable bucket lattice alone already
-# exceeds the bound (analysis/compilecheck.py).
-DEFAULT_JIT_CACHE_CAP = 32
 
 _CTYPE_TO_PLAN = {
     ColType.LONG: "long",
@@ -444,24 +414,6 @@ def build_step_fn(
     return step
 
 
-def transfer_buckets(full_cap: int) -> List[int]:
-    """Every sized-transfer capacity an output of padded capacity
-    ``full_cap`` can ever be fetched at: the pow2 lattice
-    ``transfer_capacity`` buckets to (engaging only while the sized cap
-    at least halves the copy), plus the full capacity itself (the
-    pre-EWMA / overflow / sized-off fetch). Finite by construction —
-    the compile manifest enumerates the ``_slice_table``/``_pack_slot``
-    entries per bucket from this same lattice, and DX601 fires when it
-    alone outgrows the helper jit-cache bound."""
-    caps: List[int] = []
-    c = _pow2_ceil(MIN_TRANSFER_ROWS)
-    while c * 2 <= full_cap:
-        caps.append(c)
-        c *= 2
-    caps.append(int(full_cap))
-    return caps
-
-
 def source_raw_form(input_type: Optional[str], mesh=None) -> str:
     """``packed`` when production dispatch ships a source of this input
     type as the single-matrix PackedRaw (native decoder hot path:
@@ -514,58 +466,19 @@ def aval_signature(tree) -> dict:
     }
 
 
-def compile_entries_from_avals(
-    step_avals: tuple,
-    out_avals: Dict[str, TableData],
-    sized: bool,
-    slots: bool,
-) -> List[dict]:
-    """Enumerate every jit entry point a flow dispatches — the fused
-    step plus one ``_slice_table``/``_pack_slot`` per (output, capacity
-    bucket) — as manifest-shaped dicts. Shared by the runtime
+def step_compile_entry(step_avals: tuple) -> dict:
+    """The one jit entry point a flow dispatches, the fused step, as a
+    manifest-shaped dict. Shared by the runtime
     (``FlowProcessor.derive_compile_entries``, which feeds the AOT
     warm) and the static analyzer (``analysis/compilecheck.py``, which
     emits the manifest), so the two can only disagree when the flow
     itself changed (the DX603 drift signal)."""
-    entries: List[dict] = [{
+    return {
         "entry": "step",
         "donate": list(STEP_DONATE_ARGNUMS),
         "static": {},
         "avals": aval_signature(step_avals),
-    }]
-    for name in sorted(out_avals):
-        t = out_avals[name]
-        full_cap = int(t.valid.shape[0])
-        sliceable = all(
-            tuple(v.shape[:1]) == tuple(t.valid.shape)
-            for v in t.cols.values()
-        )
-        caps = transfer_buckets(full_cap) if sized else [full_cap]
-        for cap in caps:
-            if slots and sliceable:
-                entries.append({
-                    "entry": f"slice:{name}:{cap}",
-                    "donate": [],
-                    "static": {"cap": cap},
-                    "avals": aval_signature(t),
-                })
-                slot_aval = jax.eval_shape(
-                    functools.partial(_slice_impl, cap=cap), t
-                )
-                entries.append({
-                    "entry": f"pack:{name}:{cap}",
-                    "donate": [1],
-                    "static": {"cap": cap},
-                    "avals": aval_signature((t, slot_aval)),
-                })
-            elif cap < full_cap:
-                entries.append({
-                    "entry": f"slice:{name}:{cap}",
-                    "donate": [],
-                    "static": {"cap": cap},
-                    "avals": aval_signature(t),
-                })
-    return entries
+    }
 
 
 @dataclass
@@ -673,11 +586,8 @@ class FlowProcessor:
         # into the DATAX-<flow>:UdfRefreshError metric at collect()
         self.udf_refresh_errors = 0
 
-        # pipelining + sized output transfer conf
-        # (datax.job.process.pipeline.*): `depth` is the in-flight
-        # window of the pipelined hosts; `sizedtransfer` adapts the
-        # per-output D2H copy to observed row counts (off under a mesh,
-        # whose sharded outputs would gather on the slice)
+        # datax.job.process.pipeline.depth: the in-flight window of the
+        # pipelined hosts
         pipe_conf = process_conf.get_sub_dictionary("pipeline.")
         depth = pipe_conf.get_int_option("depth")
         if depth is None:
@@ -701,26 +611,6 @@ class FlowProcessor:
                 f"{decoder_threads}"
             )
         self.decoder_threads = decoder_threads
-        self.sized_transfer = (
-            (pipe_conf.get_or_else("sizedtransfer", "true") or "").lower()
-            != "false"
-        ) and self.mesh is None
-        # per-output EWMA of observed valid row counts — the sized
-        # transfer capacity tracks this, bucketed to powers of two
-        self.transfer_ewma: Dict[str, float] = {}
-        # counters drained into Transfer_<name>_Count metrics at collect
-        self.transfer_stats: Dict[str, int] = {}
-        # outputs still riding the post-overflow doubled headroom:
-        # name -> batches remaining
-        self.transfer_boost: Dict[str, int] = {}
-        # donated double-buffered output slots (off under a mesh, whose
-        # sharded outputs can't alias a single-device buffer):
-        # (output, capacity) -> [slot A, slot B], each slot the
-        # (TableData, landed-event of the batch that last shipped it)
-        self.output_slots_enabled = (
-            (pipe_conf.get_or_else("outputslots", "true") or "").lower()
-            != "false"
-        ) and self.mesh is None
         # observed mesh communication (datax.job.process.mesh.observe,
         # default on): under a mesh the compiled step's collective
         # census (dist/mesh.py collective_summary) exports per batch as
@@ -738,8 +628,6 @@ class FlowProcessor:
         # None = not yet censused; False = census failed (don't retry
         # every batch); else a dist.mesh.MeshCollectives
         self.mesh_collectives = None
-        self._slots: Dict[Tuple[str, int], list] = {}
-        self._slot_parity: Dict[str, int] = {}
         # serializes ring/state donation in dispatch against the
         # window-state snapshot a background landing thread may take at
         # checkpoint time (snapshotting a ring the next dispatch has
@@ -814,17 +702,8 @@ class FlowProcessor:
         # one directory compile/aotcache.py resolves (operator env, else
         # the checkout), so restarts and preemption recovery deserialize
         # instead of recompiling; `cacheurl` adds the shared object
-        # store layer. `jitcachecap` bounds the transfer-helper jit
-        # caches (shared default with the DX601 lint).
+        # store layer.
         comp_conf = process_conf.get_sub_dictionary("compile.")
-        cap_conf = comp_conf.get_int_option("jitcachecap")
-        if cap_conf is not None:
-            if cap_conf < 1:
-                raise EngineException(
-                    f"process.compile.jitcachecap must be >= 1, got "
-                    f"{cap_conf}"
-                )
-            set_jit_cache_cap(cap_conf)
         self.compile_manifest: Optional[dict] = None
         manifest_raw = _read_maybe_file(comp_conf.get("manifest"))
         if manifest_raw:
@@ -1249,8 +1128,7 @@ class FlowProcessor:
         self._decode_rows_per_sec: Optional[float] = None
         # which decode engine served the last encode_json_bytes call:
         # "native-sharded" (packed pool path) / "native-mt" (row-layout
-        # native, under a mesh) — bench.py records it in BENCH_CONTEXT
-        # and the regression gate refuses cross-path comparisons
+        # native, under a mesh)
         self.last_decoder_path: Optional[str] = None
 
     def _place_rings(self, buf: WindowBuffers) -> WindowBuffers:
@@ -2072,7 +1950,7 @@ class FlowProcessor:
             if getattr(r, "_ingest_pool", None) is not None
         ]
         # child span of the host's "dispatch" when a batch trace is
-        # active (obs/tracing.py); a no-op under bench/LiveQuery drivers
+        # active (obs/tracing.py); a no-op under LiveQuery drivers
         try:
             with _trace_span("device-enqueue"), self._debug_guard(), \
                     self._device_state_lock:
@@ -2092,89 +1970,23 @@ class FlowProcessor:
             for pool, mat in ingest_buffers:
                 pool.release(mat)
             raise
-        # sized output transfer: shrink each output's D2H copy to its
-        # adaptive capacity (power-of-two bucket over the count EWMA),
-        # written into the output's donated A/B transfer slot so the
-        # buffers the background copies stream from stay resident.
-        # The device has already compacted valid rows to the front, so
-        # the slice keeps every real row as long as the cap holds; the
-        # full-capacity table stays referenced for the two-phase
-        # overflow fallback in collect().
-        fetch_tables: Dict[str, TableData] = {}
-        fetch_caps: Dict[str, int] = {}
-        staged_slots = []  # (slot key, parity) filled below the handle
-        for n, t in out_datasets.items():
-            full_cap = int(t.valid.shape[0])
-            cap = self.transfer_capacity(n, full_cap)
-            fetch_caps[n] = cap
-            fetch_tables[n] = self._stage_output(n, t, cap, full_cap,
-                                                 staged_slots)
         handle = PendingBatch(
             self, self.pipeline, out_datasets, new_state, counts_vec,
             batch_time_ms, new_base_ms, t0,
             out_names=list(self.output_datasets),
             target_names=[s.target for s in self.specs.values()],
-            fetch_tables=fetch_tables,
-            fetch_caps=fetch_caps,
         )
         # this batch's pooled ingest matrices: released by the handle
         # when the batch lands/abandons, never before the step is done
         # dx-race: owner-handoff pool slots ride the PendingBatch; its
         # collect/abandon path is the unique releaser
         handle._ingest_buffers = ingest_buffers
-        # each staged slot is owned by THIS batch until its transfer
-        # lands: record the handle's landed-event so the dispatch that
-        # next rotates onto the slot knows whether donation is safe
-        for key, parity in staged_slots:
-            table, _ev = self._slots[key][parity]
-            # dx-race: owner-handoff slot ownership moves to this handle;
-            # _stage_output checks the landed event before re-donating
-            self._slots[key][parity] = (table, handle._landed)
         # begin the device->host result copies NOW (async enqueue, free):
         # by the time collect() runs — typically one pipelined iteration
         # later — the data has already crossed the boundary, so collect
         # pays no synchronous transport round trip.
         handle.start_fetch()
         return handle
-
-    def _stage_output(
-        self, name: str, t: TableData, cap: int, full_cap: int,
-        staged_slots: list,
-    ) -> TableData:
-        """Build output ``name``'s transfer view at capacity ``cap``.
-
-        With output slots enabled the view is written into one of the
-        output's two resident transfer slots (A/B rotation): the slot
-        buffer is DONATED into the jitted pack, so XLA writes the sliced
-        rows straight into the transfer-ready memory the background D2H
-        copy will stream from — batch N+1 packs into the other slot, so
-        an in-flight transfer of batch N is never clobbered. A slot
-        whose previous transfer has not landed yet (deep backlog, or an
-        abandoned handle) falls back to a fresh buffer instead of
-        blocking the dispatch loop — correctness first, reuse when safe.
-        """
-        if not self.output_slots_enabled or not all(
-            v.shape[:1] == t.valid.shape for v in t.cols.values()
-        ):
-            return _slice_table(t, cap) if cap < full_cap else t
-        key = (name, cap)
-        ring = self._slots.setdefault(key, [None] * OUTPUT_SLOT_BUFFERS)
-        parity = self._slot_parity.get(name, 0) % OUTPUT_SLOT_BUFFERS
-        self._slot_parity[name] = parity + 1
-        prev = ring[parity]
-        if prev is not None and prev[1].is_set():
-            # the batch that last shipped this slot has landed its host
-            # copy: donate the buffers back into the pack
-            staged = _pack_slot(t, prev[0], cap)
-        else:
-            # first use of this (output, cap) slot, or its transfer is
-            # still in flight: allocate fresh transfer buffers
-            if prev is not None:
-                self._bump_transfer_stat("SlotContended")
-            staged = _slice_table(t, cap)
-        ring[parity] = (staged, _SET_EVENT)
-        staged_slots.append((key, parity))
-        return staged
 
     def process_batch(
         self,
@@ -2187,47 +1999,6 @@ class FlowProcessor:
         incl. the metric names it emits (:344-379).
         """
         return self.dispatch_batch(raw, batch_time_ms).collect()
-
-    # -- sized output transfer --------------------------------------------
-    def transfer_capacity(self, name: str, full_cap: int) -> int:
-        """Adaptive D2H transfer capacity for output ``name``: the EWMA
-        of observed valid counts with ``TRANSFER_HEADROOM`` x burst
-        margin (doubled for ``OVERFLOW_BOOST_BATCHES`` batches after an
-        overflow re-fetch), bucketed to a power of two. Engages only
-        once counts have been observed and only when it at least halves
-        the copy (otherwise the full fetch is simpler and no slower)."""
-        if not self.sized_transfer:
-            return full_cap
-        ewma = self.transfer_ewma.get(name)
-        if ewma is None:
-            return full_cap
-        headroom = TRANSFER_HEADROOM * (
-            OVERFLOW_BOOST_FACTOR if self.transfer_boost.get(name, 0) > 0
-            else 1
-        )
-        cap = _pow2_ceil(
-            max(int(ewma * headroom) + 1, MIN_TRANSFER_ROWS)
-        )
-        return cap if cap * 2 <= full_cap else full_cap
-
-    def observe_transfer_counts(self, counts: Dict[str, int]) -> None:
-        """Feed observed per-output valid counts into the EWMA (called
-        from ``PendingBatch.collect``; an overflow re-fetch also bumps
-        the EWMA straight to the observed count so the very next batch
-        sizes correctly). Each observation also burns one batch off any
-        post-overflow headroom boost."""
-        a = TRANSFER_EWMA_ALPHA
-        for n, c in counts.items():
-            prev = self.transfer_ewma.get(n)
-            self.transfer_ewma[n] = (
-                float(c) if prev is None else a * c + (1.0 - a) * prev
-            )
-            boost = self.transfer_boost.get(n, 0)
-            if boost > 0:
-                self.transfer_boost[n] = boost - 1
-
-    def _bump_transfer_stat(self, key: str) -> None:
-        self.transfer_stats[key] = self.transfer_stats.get(key, 0) + 1
 
     # -- retrace accounting ------------------------------------------------
     def _step_cache_size(self) -> Optional[int]:
@@ -2313,55 +2084,20 @@ class FlowProcessor:
                 aux)
 
     def derive_compile_entries(self) -> List[dict]:
-        """Every jit entry point this processor can ever dispatch, as
-        manifest-shaped dicts (entry name, aval signature, static args,
-        donation pattern) — the runtime side of the DX603 byte-
-        exactness contract: the compile analyzer derives the same list
-        statically from the flow config."""
-        step_avals = self._step_input_avals()
-        out_avals = jax.eval_shape(self._step_fn, *step_avals)[0]
-        return compile_entries_from_avals(
-            step_avals, out_avals,
-            sized=self.sized_transfer, slots=self.output_slots_enabled,
-        )
-
-    def _warm_helpers(self) -> None:
-        """Execute every reachable transfer-helper entry once — one
-        ``_slice_table``/``_pack_slot`` per (output, capacity bucket)
-        from the same lattice the manifest enumerates — so sized
-        transfer never pays a first-use trace mid-stream."""
-        step_avals = self._step_input_avals()
-        out_avals = jax.eval_shape(self._step_fn, *step_avals)[0]
-        for name in sorted(out_avals):
-            t = out_avals[name]
-            full_cap = int(t.valid.shape[0])
-            sliceable = all(
-                tuple(v.shape[:1]) == tuple(t.valid.shape)
-                for v in t.cols.values()
-            )
-            zero_full = TableData(
-                {c: jnp.zeros(a.shape, a.dtype) for c, a in t.cols.items()},
-                jnp.zeros(t.valid.shape, t.valid.dtype),
-            )
-            caps = (
-                transfer_buckets(full_cap) if self.sized_transfer
-                else [full_cap]
-            )
-            for cap in caps:
-                if self.output_slots_enabled and sliceable:
-                    sliced = _slice_table(zero_full, cap)
-                    _pack_slot(zero_full, sliced, cap)  # donates `sliced`
-                elif cap < full_cap:
-                    _slice_table(zero_full, cap)
+        """Every jit entry point this processor can ever dispatch (the
+        fused step, and nothing else), as manifest-shaped dicts (entry
+        name, aval signature, static args, donation pattern) — the
+        runtime side of the DX603 byte-exactness contract: the compile
+        analyzer derives the same list statically from the flow
+        config."""
+        return [step_compile_entry(self._step_input_avals())]
 
     def _aot_warm(self) -> None:
         """AOT-compile every manifest entry at init instead of first
         dispatch: run one zero-filled batch through the jitted step
         (the exact production trace signature, so the first real
-        dispatch hits a warm jit cache) and execute every reachable
-        (output x capacity bucket) transfer helper once. The XLA
-        compiles inside the warm resolve from the persistent
-        compilation cache, and with ``process.compile.cacheurl`` newly
+        dispatch hits a warm jit cache). The XLA compiles inside the
+        warm resolve from the persistent compilation cache, and with ``process.compile.cacheurl`` newly
         compiled entries are pushed back through ``objstore://`` so
         the NEXT start (restart, preemption recovery, scale-out
         replica) deserializes instead of compiling. A warm failure
@@ -2394,26 +2130,20 @@ class FlowProcessor:
                 )
                 self.compile_stats["ManifestDrift_Count"] = float(drift)
             # compile the fused step at the exact production trace
-            # signature (zero-filled batch, production raw form) and
-            # every reachable transfer helper. The warm batch is NEVER
-            # collected: collect_tables() would overwrite the state
-            # tables' standby snapshot with warm-derived rows — only
-            # the counts sync (which completes the device work) runs.
+            # signature (zero-filled batch, production raw form). The
+            # warm batch is NEVER collected: collect_tables() would
+            # overwrite the state tables' standby snapshot with
+            # warm-derived rows — only the counts sync (which completes
+            # the device work) runs.
             handle = self.dispatch_batch(self._warm_raw(), batch_time_ms=0)
             handle.collect_counts()
             handle.abandon()
-            self._warm_helpers()
             self._aot_warmed = True
         except Exception:  # noqa: BLE001 — warm must never fail the flow
             logger.exception("AOT warm failed; first dispatch will compile")
         finally:
-            # the warm batch must leave no trace in adaptive state: a
-            # zero-count EWMA would size the first real batches at the
-            # minimum bucket and force overflow re-fetches
+            # the warm batch must leave no trace in device state
             self.reset_state()
-            self.transfer_ewma.clear()
-            self.transfer_boost.clear()
-            self.transfer_stats.clear()
         if self._compile_cache is not None:
             self._compile_cache.push()
         self._warm_step_mark = self._step_cache_size()
@@ -2498,117 +2228,8 @@ def _host_sort(rows: List[dict], order: List[Tuple[str, bool]]) -> None:
         rows.sort(key=kf, reverse=not asc)
 
 
-def _pow2_ceil(n: int) -> int:
-    """Smallest power of two >= n (n >= 1)."""
-    return 1 << max(int(n) - 1, 0).bit_length()
-
-
-# placeholder for "no transfer in flight" while a freshly staged slot
-# waits for its owning PendingBatch to be constructed
-_SET_EVENT = threading.Event()
-_SET_EVENT.set()
-
-
-def _pack_impl(t: TableData, slot: TableData, cap: int) -> TableData:
-    del slot  # consumed via donation: provides the output buffers
-    return TableData(
-        {c: v[:cap] if v.shape[:1] == t.valid.shape else v
-         for c, v in t.cols.items()},
-        t.valid[:cap],
-    )
-
-
-def _slice_impl(t: TableData, cap: int) -> TableData:
-    return TableData(
-        {c: v[:cap] if v.shape[:1] == t.valid.shape else v
-         for c, v in t.cols.items()},
-        t.valid[:cap],
-    )
-
-
-# per-capacity-bucket jit cache of the transfer helpers: one jitted
-# closure per (helper kind, cap), LRU-evicted above the conf'd cap so
-# a wandering EWMA (or many outputs x buckets) can never grow the
-# cache — and its compiled executables — forever. Evictions are
-# counted and drained into Compile_JitCacheEvict_Count at collect.
-_HELPER_JIT_LOCK = threading.Lock()
-_HELPER_JITS: "OrderedDict[Tuple[str, int], object]" = OrderedDict()
-_jit_cache_cap = DEFAULT_JIT_CACHE_CAP
-_jit_cache_evictions = 0
-
-
-def set_jit_cache_cap(cap: int) -> None:
-    global _jit_cache_cap
-    _jit_cache_cap = max(1, int(cap))
-
-
-def drain_jit_evictions() -> int:
-    """Helper-jit LRU evictions since the last drain (process-wide)."""
-    global _jit_cache_evictions
-    with _HELPER_JIT_LOCK:
-        n = _jit_cache_evictions
-        _jit_cache_evictions = 0
-        return n
-
-
-def helper_jit_cache_size() -> int:
-    with _HELPER_JIT_LOCK:
-        return len(_HELPER_JITS)
-
-
-def _helper_jit(kind: str, cap: int):
-    global _jit_cache_evictions
-    key = (kind, cap)
-    with _HELPER_JIT_LOCK:
-        fn = _HELPER_JITS.get(key)
-        if fn is not None:
-            _HELPER_JITS.move_to_end(key)
-            return fn
-        if kind == "slice":
-            fn = jax.jit(functools.partial(_slice_impl, cap=cap))
-        else:
-            fn = jax.jit(
-                functools.partial(_pack_impl, cap=cap), donate_argnums=(1,)
-            )
-        _HELPER_JITS[key] = fn
-        while len(_HELPER_JITS) > _jit_cache_cap:
-            _HELPER_JITS.popitem(last=False)
-            _jit_cache_evictions += 1
-        return fn
-
-
-def _pack_slot(t: TableData, slot: TableData, cap: int) -> TableData:
-    """Device-side pack of an (already compacted) output table into its
-    donated transfer slot: identical math to ``_slice_table``, but the
-    ``slot`` argument's buffers are DONATED, so XLA writes the result
-    into the resident transfer-ready memory instead of allocating — the
-    background D2H stream then always reads from one of two stable
-    buffer sets per output. The caller guarantees the donated slot's
-    previous transfer has landed (PendingBatch._landed)."""
-    return _helper_jit("pack", cap)(t, slot)
-
-
-def _slice_table(t: TableData, cap: int) -> TableData:
-    """Device-side shrink of an (already compacted) output table to its
-    sized transfer capacity — the D2H copy then moves ``cap`` rows
-    instead of the full padded capacity. One compiled slice per
-    (table layout, cap) pair; caps are power-of-two buckets
-    (``transfer_buckets``), so the trace count stays logarithmic AND
-    bounded (LRU above the jit-cache cap). The full-capacity source is
-    deliberately NOT donated into the slice: the two-phase overflow
-    fallback re-fetches it when ``counts_vec`` reveals the sized cap
-    undershot."""
-    return _helper_jit("slice", cap)(t)
-
-
 def _host_table_nbytes(t: TableData) -> int:
     return sum(a.nbytes for a in t.cols.values()) + t.valid.nbytes
-
-
-# batches at or below this capacity fetch counts + whole outputs in one
-# device_get instead of syncing counts first and slicing on device —
-# one host<->device round-trip instead of two (latency mode)
-SMALL_FETCH_ROWS = 16384
 
 
 @dataclass
@@ -2631,24 +2252,23 @@ class PendingBatch:
     """An in-flight micro-batch: device work queued, results not yet
     fetched.
 
-    Two-phase result path (the device-resident tail): the packed
-    ``counts_vec`` and the (sized, slot-staged) output tables all start
-    streaming device->host at dispatch; ``collect_counts()`` is the only
-    BLOCKING device read — it resolves the counts vector (a few hundred
-    bytes) and is the batch's sync point. ``collect_tables()`` then
-    resolves the already-streaming table copies, materializes rows and
-    persists state — typically on a background landing thread, so sinks
-    ack out-of-band while the dispatch loop keeps feeding the device.
-    ``collect()`` = counts + tables, the synchronous back-compat path
-    (byte-identical results, golden-tested)."""
+    One result path, on one chip as under a mesh: the packed
+    ``counts_vec`` and the step's output tables, at their declared
+    capacity, all start streaming device->host at dispatch;
+    ``collect_counts()`` is the only BLOCKING device read — it resolves
+    the counts vector (a few hundred bytes) and is the batch's sync
+    point. ``collect_tables()`` then resolves the already-streaming
+    table copies, slices each on the host to the count the sync
+    learned, materializes rows and persists state — on the pipelined
+    hosts' landing thread, so sinks ack out-of-band while the dispatch
+    loop keeps feeding the device. ``collect()`` = counts + tables as
+    plain row lists."""
 
     def __init__(
         self, proc: "FlowProcessor", pipeline, out_datasets, state,
         counts_vec, batch_time_ms: int, base_ms: int, t0: float,
         out_names: Optional[List[str]] = None,
         target_names: Optional[List[str]] = None,
-        fetch_tables: Optional[Dict[str, TableData]] = None,
-        fetch_caps: Optional[Dict[str, int]] = None,
     ):
         self.proc = proc
         # THIS batch's pipeline: a UDF onInterval refresh may rebuild
@@ -2666,30 +2286,14 @@ class PendingBatch:
             else [s.target for s in proc.specs.values()]
         )
         self.out_datasets = out_datasets
-        # sized-transfer views: what start_fetch copies and collect
-        # reads first; out_datasets stays the full-capacity fallback
-        self.fetch_tables = (
-            fetch_tables if fetch_tables is not None else dict(out_datasets)
-        )
-        self.fetch_caps = fetch_caps or {
-            n: int(t.valid.shape[0]) for n, t in self.fetch_tables.items()
-        }
         self.state = state  # THIS batch's state, for the A/B overwrite
         self.counts_vec = counts_vec
         self.batch_time_ms = batch_time_ms
         self.base_ms = base_ms
         self.t0 = t0
-        self._prefetched = False
-        # D2H accounting for this batch (Transfer_* metrics)
-        self._d2h_bytes = 0
-        self._transferred_rows = 0
         # parsed counts vector, cached by collect_counts (the sync
         # point happens at most once per batch)
         self._counts: Optional[BatchCounts] = None
-        # set once the host copies of the fetch tables have landed (or
-        # the batch is abandoned): the signal slot rotation checks
-        # before donating this batch's transfer buffers to a new pack
-        self._landed = threading.Event()
         # pooled ingest matrices this batch's raw inputs live in
         # (set by dispatch_batch); released exactly once, at landing or
         # abandon — the decode buffer pool's reuse gate
@@ -2702,8 +2306,7 @@ class PendingBatch:
 
     def abandon(self) -> None:
         """Mark a batch that will never be collected (window requeued
-        after a failure): releases its transfer slots for donation and
-        unblocks anyone coordinating on the landing."""
+        after a failure): returns its pooled ingest matrices."""
         if self._ingest_buffers:
             # the step may still be consuming the zero-copied ingest
             # matrices; wait for device completion before the pool may
@@ -2713,23 +2316,17 @@ class PendingBatch:
             except Exception:  # noqa: BLE001 — a failed step frees its inputs
                 pass
         self._release_ingest()
-        self._landed.set()
 
     def start_fetch(self) -> None:
         """Enqueue async device->host copies of everything collect()
-        reads (counts + the SIZED output tables). Transport then
-        overlaps the host's next-batch work instead of being paid as a
-        blocking sync inside collect(). Transfers are latency-bound AND
-        byte-bound — so the sized (power-of-two bucketed)
-        tables stream ahead of time, and only an overflow (detected from
-        ``counts_vec`` at collect) pays a second round trip for the full
-        table. Transfer errors are NOT swallowed — they propagate to
-        the batch loop for retry."""
+        reads (counts + the output tables). Transport then overlaps the
+        host's next-batch work instead of being paid as a blocking sync
+        inside collect(). Transfer errors are NOT swallowed — they
+        propagate to the batch loop for retry."""
         self.counts_vec.copy_to_host_async()
-        for t in self.fetch_tables.values():
+        for t in self.out_datasets.values():
             for a in (*t.cols.values(), t.valid):
                 a.copy_to_host_async()
-        self._prefetched = True
 
     def block_until_evaluated(self) -> None:
         """Wait for the device step to COMPLETE (rule evaluation done,
@@ -2776,22 +2373,16 @@ class PendingBatch:
 
     def collect(self) -> Tuple[Dict[str, List[dict]], Dict[str, float]]:
         """Synchronous result path: counts sync + table landing in one
-        call, each ``collect_tables()`` batch as its plain row list
-        (golden-tested in tests/test_sized_transfer.py)."""
+        call, each ``collect_tables()`` batch as its plain row list."""
         datasets, metrics = self.collect_tables()
         return {n: b.rows() for n, b in datasets.items()}, metrics
 
     def collect_tables(self) -> Tuple[Dict[str, ColumnBatch], Dict[str, float]]:
-        """Resolve the background-streamed output tables and persist
-        state; returns (one ColumnBatch an output, what sinks get; metrics).
-
-        With a prior ``start_fetch()`` (the default from
-        ``dispatch_batch``) every device read below hits an
-        already-landed host copy — this is the landing half the
-        streaming host runs on its background transfer thread.
-        Otherwise the device-compacted outputs are sliced to the true
-        row counts ``collect_counts`` learned, so only real rows cross
-        the device->host boundary, fetched in one batched device_get.
+        """Resolve the output tables streaming since dispatch and
+        persist state; returns (one ColumnBatch an output, what sinks
+        get; metrics). Every output crosses at its declared capacity and
+        is sliced here to the count ``collect_counts`` learned (the
+        device compacted the valid rows to the front).
         """
         proc = self.proc
         bc = self.collect_counts()
@@ -2803,79 +2394,27 @@ class PendingBatch:
         names = self.out_names
         try:
             with _trace_span("device-fetch"):
-                if self._prefetched or proc.batch_capacity <= SMALL_FETCH_ROWS:
-                    # sized/slot-staged tables, already streaming since
-                    # dispatch — prefetched, or small enough that the
-                    # extra bytes cost less than a second device slice
-                    host_full = jax.device_get(self.fetch_tables)
-                else:
-                    host_full = None
-            if host_full is not None:
-                self._d2h_bytes = counts.nbytes + sum(
-                    _host_table_nbytes(t) for t in host_full.values()
-                )
-                self._transferred_rows = sum(
-                    int(t.valid.shape[0]) for t in host_full.values()
-                )
-                host_tables: Dict[str, TableData] = {}
-                for n, t in host_full.items():
-                    cnt = dataset_counts[n]
-                    if cnt > int(t.valid.shape[0]):
-                        # two-phase fallback: the sized prefetch undershot
-                        # (count exceeds the adaptive capacity) — re-fetch
-                        # the full-capacity table sliced to the true count.
-                        # Rare by construction (EWMA + headroom + pow2
-                        # bucket), loud in Transfer_Overflow_Count.
-                        proc._bump_transfer_stat("Overflow")
-                        # jump the EWMA straight to the observed count so
-                        # the very next batch sizes above it, and double
-                        # the headroom factor for the next
-                        # OVERFLOW_BOOST_BATCHES batches so back-to-back
-                        # bursts can't thrash the two-phase fetch
-                        proc.transfer_ewma[n] = float(cnt)
-                        proc.transfer_boost[n] = OVERFLOW_BOOST_BATCHES
-                        full = self.out_datasets[n]
-                        with _trace_span("device-refetch"):
-                            t = jax.device_get(TableData(
-                                {c: v[:cnt]
-                                 if v.shape[:1] == full.valid.shape else v
-                                 for c, v in full.cols.items()},
-                                full.valid[:cnt],
-                            ))
-                        self._d2h_bytes += _host_table_nbytes(t)
-                        self._transferred_rows += cnt
-                        host_tables[n] = t
-                    else:
-                        host_tables[n] = TableData(
-                            {c: v[:cnt] if v.shape[:1] == t.valid.shape else v
-                             for c, v in t.cols.items()},
-                            t.valid[:cnt],
-                        )
-            else:
-                # counts-first path (large batch, no prefetch): slice on
-                # device to the exact counts, then one batched device_get —
-                # already the wire minimum, sized transfer adds nothing
-                sliced = {
-                    n: TableData(
-                        {c: v[: dataset_counts[n]]
-                         if v.shape[:1] == t.valid.shape else v
-                         for c, v in t.cols.items()},
-                        t.valid[: dataset_counts[n]],
-                    )
-                    for n, t in self.out_datasets.items()
-                }
-                host_tables = jax.device_get(sliced)
-                self._d2h_bytes = counts.nbytes + sum(
-                    _host_table_nbytes(t) for t in host_tables.values()
-                )
-                self._transferred_rows = sum(dataset_counts.values())
+                host_full = jax.device_get(self.out_datasets)
         finally:
             # host copies landed (or the fetch failed): this batch's
-            # transfer slots are safe to donate to a future pack, and
-            # its pooled ingest matrices (fully consumed by the step,
-            # which completed at the counts sync) return to the pool
+            # pooled ingest matrices (fully consumed by the step, which
+            # completed at the counts sync) return to the pool
             self._release_ingest()
-            self._landed.set()
+        # D2H accounting for this batch (Transfer_* metrics)
+        d2h_bytes = counts.nbytes + sum(
+            _host_table_nbytes(t) for t in host_full.values()
+        )
+        transferred_rows = sum(
+            int(t.valid.shape[0]) for t in host_full.values()
+        )
+        host_tables: Dict[str, TableData] = {}
+        for n, t in host_full.items():
+            cnt = dataset_counts[n]
+            host_tables[n] = TableData(
+                {c: v[:cnt] if v.shape[:1] == t.valid.shape else v
+                 for c, v in t.cols.items()},
+                t.valid[:cnt],
+            )
 
         # armed sanitizer: every landed host table is scanned for
         # sentinel leakage BEFORE materialization — a poisoned pool slot
@@ -2991,11 +2530,8 @@ class PendingBatch:
                     + float(cur - proc._warm_step_mark)
                 )
                 proc._warm_step_mark = cur
-        # transfer-helper jit LRU evictions + one-shot compile stats
-        # (cold-start ms, persistent-cache hits/misses, warm misses)
-        evictions = drain_jit_evictions()
-        if evictions:
-            metrics["Compile_JitCacheEvict_Count"] = float(evictions)
+        # one-shot compile stats (cold-start ms, persistent-cache
+        # hits/misses, warm misses)
         if proc._compile_cache is not None:
             hits, misses = proc._compile_cache.take_counts()
             if hits or misses:
@@ -3014,14 +2550,13 @@ class PendingBatch:
             for k, v in proc.compile_stats.items():
                 metrics[f"Compile_{k}"] = float(v)
             proc.compile_stats.clear()
-        # sized-transfer accounting: bytes actually moved D2H for this
-        # batch and the valid/transferred row ratio (1.0 = wire minimum)
+        # transfer accounting: bytes moved D2H for this batch and the
+        # valid/transferred row ratio (1.0 = wire minimum)
         if names:
             valid_rows = sum(dataset_counts.values())
-            metrics["Transfer_D2HBytes"] = float(self._d2h_bytes)
+            metrics["Transfer_D2HBytes"] = float(d2h_bytes)
             metrics["Transfer_Efficiency"] = (
-                valid_rows / self._transferred_rows
-                if self._transferred_rows else 1.0
+                valid_rows / transferred_rows if transferred_rows else 1.0
             )
         # partitioned-state accounting: the partition geometry this
         # replica runs (gauges) plus the deltas since the last collect
@@ -3043,10 +2578,4 @@ class PendingBatch:
         # and (only when nonzero — silence is health) poison hits
         if proc.buffer_sanitizer is not None:
             metrics.update(proc.buffer_sanitizer.drain_metric_deltas())
-        if proc.transfer_stats:
-            for k, v in proc.transfer_stats.items():
-                metrics[f"Transfer_{k}_Count"] = float(v)
-            proc.transfer_stats.clear()
-        # feed the adaptive capacity for the NEXT batches
-        proc.observe_transfer_counts(dataset_counts)
         return datasets, metrics

@@ -27,8 +27,7 @@ end at the batch level instead.
 
 Armed via conf ``datax.job.process.debug.protocolmonitor`` (a debug
 mode like the buffer sanitizer: the cost is a few appends + one list
-scan per batch — bench.py's ``protocheck`` block keeps the overhead a
-committed number). Armed in every chaos drill, asserting the engine
+scan per batch). Armed in every chaos drill, asserting the engine
 holds its ordering under preemption, sink outage, slowdown, partition
 loss and rescale.
 """

@@ -31,8 +31,7 @@ the flight recorder beside conformance drift — and bumps
 ``Sanitizer_PoisonHit_Count``; everything the sanitizer guarded bumps
 ``Sanitizer_GuardedViews_Count``. Armed via conf
 ``datax.job.process.debug.buffersanitizer`` (a debug mode: poisoning
-costs one memset per released slot — bench.py's ``sanitizer`` block
-keeps the overhead a committed number).
+costs one memset per released slot).
 """
 
 from __future__ import annotations

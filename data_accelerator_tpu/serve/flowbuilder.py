@@ -74,7 +74,6 @@ DEFAULT_JOB_COMMON_TOKENS: Dict[str, str] = {
     "jobPipelineDepth": "_S_{guiJobPipelineDepth}",
     "jobDecoderThreads": "_S_{guiJobDecoderThreads}",
     "jobObservabilityPort": "_S_{guiJobObservabilityPort}",
-    "jobCompileJitCacheCap": "_S_{guiJobCompileJitCacheCap}",
     "processedSchemaPath": "_S_{processedSchemaPath}",
 }
 

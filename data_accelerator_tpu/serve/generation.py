@@ -208,12 +208,6 @@ class RuntimeConfigGeneration:
             "guiJobObservabilityPort": str(
                 jobconf.get("jobObservabilityPort") or ""
             ),
-            # bound on the transfer-helper jit caches; empty = engine
-            # default (runtime/processor.py DEFAULT_JIT_CACHE_CAP, the
-            # same constant the DX601 compile-surface lint uses)
-            "guiJobCompileJitCacheCap": str(
-                jobconf.get("jobCompileJitCacheCap") or ""
-            ),
             "processedSchemaPath": os.path.join(
                 self.runtime.resolve(flow_dir), "processedschema.json"
             ),
@@ -732,9 +726,6 @@ class RuntimeConfigGeneration:
             if ctx.get("compile_cache_url"):
                 extra["datax.job.process.compile.cacheurl"] = (
                     ctx["compile_cache_url"])
-            if jt.get("jobCompileJitCacheCap"):
-                extra["datax.job.process.compile.jitcachecap"] = str(
-                    jt.get("jobCompileJitCacheCap"))
             for b_i, b in enumerate(ctx.get("batch_inputs") or []):
                 ns = f"datax.job.input.batch.blob.{b_i}"
                 for k, v in b.items():

@@ -219,14 +219,11 @@ route("#/flow/", async (view, hash) => {
     // ships precompiled and restarts warm-start in sub-second
     if (!c || !c.entries) return null;
     return h("div", { class: "muted" },
-      `compile surface: ${c.entries} entries (1 step + ` +
-      `${c.helperEntries} transfer-helper over ` +
-      `${(c.buckets || []).length} bucket(s)) — ` +
+      `compile surface: ${c.entries} program (the step) — ` +
       (c.stable ? "stable (AOT manifest covers every dispatch; " +
                   "warm starts skip first-dispatch compiles)"
                 : "OPEN (manifest covers the initial surface only; " +
-                  "runtime re-traces surface as Retrace_Count)") +
-      `, jit-cache cap ${c.jitCacheCap}`);
+                  "runtime re-traces surface as Retrace_Count)"));
   };
   const renderShardingTable = (m) => {
     // mesh tier (flow/validate mesh: true): the static SPMD partition

@@ -1,7 +1,7 @@
 """End-to-end coverage of the BASELINE.md staged configs 2-5.
 
 Config 1 (SimulatedData IoT hello-world threshold alert) is
-tests/test_onebox_e2e.py + bench.py. These exercise the rest:
+tests/test_onebox_e2e.py. These exercise the rest:
 
 2. tumbling-window COUNT/AVG over the event stream (TIMEWINDOW tables)
 3. accumulator state + sliding-window join (raw-row retention on device)

@@ -14,11 +14,9 @@
   (``Compile_WarmMiss_Count``)
 - persistent compilation cache: misses on first start, hits on
   restart, shared through a real ``objstore://`` store
-- LRU-bounded transfer-helper jit caches: cap honored, evictions
-  counted, ONE constant shared with the DX601 lint
 - CLI ``--compile``/``--all`` + REST ``"compile"``/``"all"`` parity
 - tier-1 self-lint: every shipped scenario/baseline flow passes
-  ``--compile`` clean with a stable, drift-free manifest
+  ``--compile`` clean, and its manifest holds one entry, the step
 """
 
 import copy
@@ -40,15 +38,7 @@ from data_accelerator_tpu.analysis import (
 )
 from data_accelerator_tpu.analysis.compilecheck import check_manifest
 from data_accelerator_tpu.core.config import SettingDictionary
-from data_accelerator_tpu.runtime.processor import (
-    DEFAULT_JIT_CACHE_CAP,
-    FlowProcessor,
-    drain_jit_evictions,
-    helper_jit_cache_size,
-    pack_raw,
-    set_jit_cache_cap,
-    _slice_table,
-)
+from data_accelerator_tpu.runtime.processor import FlowProcessor, pack_raw
 from data_accelerator_tpu.serve.scenarios import shipped_flow_guis
 
 FLOWS_DIR = os.path.join(os.path.dirname(__file__), "data", "flows")
@@ -110,7 +100,6 @@ def conf_for_gui(gui: dict, extra: dict = None) -> SettingDictionary:
 # ---------------------------------------------------------------------------
 COMPILE_GOLDEN = [
     ("dx600_open_surface", "DX600", SEV_WARNING),
-    ("dx601_bucket_blowup", "DX601", SEV_WARNING),
     ("dx602_manifest_donation", "DX602", SEV_ERROR),
     ("dx603_manifest_drift", "DX603", SEV_ERROR),
     ("dx690_lowering_failure", "DX690", SEV_ERROR),
@@ -160,16 +149,6 @@ def test_golden_compile_clean_twins():
     ]
     report = analyze_flow_compile(twin)
     assert report.diagnostics == [] and report.stable
-    # DX601's twin: the same flow at a sane batch capacity
-    flow = load_flow("dx601_bucket_blowup")
-    twin = copy.deepcopy(flow)
-    twin["process"]["jobconfig"]["jobBatchCapacity"] = "65536"
-    report = analyze_flow_compile(twin)
-    assert "DX601" not in report.codes()
-    # ...and raising the conf'd cap clears DX601 on the bad fixture
-    # (the lint honors the SAME knob the runtime bound reads)
-    report = analyze_flow_compile(flow, jit_cache_cap=64)
-    assert "DX601" not in report.codes()
 
 
 def test_dx600_message_names_the_refresh_udf():
@@ -188,8 +167,8 @@ def test_manifest_matches_runtime_lowering_byte_exact():
     """The statically emitted manifest equals what a real FlowProcessor
     derives from its live device state — entries, avals, donation AND
     lowering digests — because both sides share build_step_fn and
-    compile_entries_from_avals. Asserted on the DX603 fixture flow (a
-    windowed group-by, i.e. rings + helpers in play)."""
+    step_compile_entry. Asserted on the DX603 fixture flow (a
+    windowed group-by, i.e. donated rings in play)."""
     flow = load_flow("dx603_manifest_drift")
     static = analyze_flow_compile(flow)
     assert static.ok and static.stable
@@ -198,7 +177,7 @@ def test_manifest_matches_runtime_lowering_byte_exact():
     runtime = analyze_processor_compile(proc)
     s = {e["entry"]: e for e in static.entries}
     r = {e["entry"]: e for e in runtime.entries}
-    assert set(s) == set(r)
+    assert set(s) == set(r) == {"step"}
     for name in s:
         for field in ("donate", "static", "avals", "loweringDigest"):
             assert s[name][field] == r[name][field], (name, field)
@@ -221,37 +200,36 @@ def test_step_entry_records_ring_donation_contract():
     from data_accelerator_tpu.runtime.processor import STEP_DONATE_ARGNUMS
 
     report = analyze_flow_compile(load_flow("dx603_manifest_drift"))
-    step = [e for e in report.entries if e["entry"] == "step"][0]
+    (step,) = report.entries
+    assert step["entry"] == "step"
     assert step["donate"] == list(STEP_DONATE_ARGNUMS)
-    packs = [e for e in report.entries if e["entry"].startswith("pack:")]
-    assert packs and all(e["donate"] == [1] for e in packs)
-    slices = [e for e in report.entries if e["entry"].startswith("slice:")]
-    assert slices and all(e["donate"] == [] for e in slices)
-    # every entry carries the deployable coordinates
-    for e in report.entries:
-        assert e["cacheKey"] and e["loweringDigest"] and e["avals"]["leaves"]
+    # the entry carries the deployable coordinates
+    assert step["cacheKey"] and step["loweringDigest"]
+    assert step["avals"]["leaves"]
 
 
 # ---------------------------------------------------------------------------
 # tier-1 self-lint: shipped flows must ship precompilable
 # ---------------------------------------------------------------------------
-def test_compile_self_lint_shipped_and_baseline_flows():
+def _baseline_flow(name: str) -> dict:
+    if name.endswith(".json"):
+        with open(os.path.join(FLOWS_DIR, name)) as f:
+            return json.load(f)
+    return next(g for g in shipped_flow_guis() if g.get("name") == name)
+
+
+@pytest.mark.parametrize("flow", [
+    "probe-deploy", *map(os.path.basename, clean_flow_paths()),
+])
+def test_compile_surface_is_the_step_alone(flow):
     """Every shipped scenario flow AND every clean baseline-mirror
-    fixture passes ``--compile`` with zero error diagnostics and emits
-    a manifest with at least the step entry."""
-    flows = [(g.get("name"), g) for g in shipped_flow_guis()]
-    for path in clean_flow_paths():
-        with open(path) as f:
-            flows.append((os.path.basename(path), json.load(f)))
-    assert len(flows) >= 6
-    for name, flow in flows:
-        report = analyze_flow_compile(flow)
-        assert report.errors == [], (
-            f"{name}: {[d.render() for d in report.errors]}"
-        )
-        assert report.manifest is not None, name
-        entries = [e["entry"] for e in report.manifest["entries"]]
-        assert "step" in entries, name
+    fixture passes ``--compile`` with zero error diagnostics, and the
+    manifest it emits holds one entry: a flow dispatches its step and
+    no other program, so there is nothing else to warm or to miss."""
+    report = analyze_flow_compile(_baseline_flow(flow))
+    assert report.errors == [], [d.render() for d in report.errors]
+    assert [e["entry"] for e in report.manifest["entries"]] == ["step"]
+    assert report.compile_dict()["entries"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -479,56 +457,10 @@ def test_cache_dir_comes_from_the_environment_or_the_checkout(monkeypatch):
     assert jax.config.jax_compilation_cache_dir == armed
 
     assert "tempfile" not in inspect.getsource(aotcache)
-    for name in ("bench.py", "tests/conftest.py"):
-        with open(os.path.join(checkout, name), encoding="utf-8") as f:
-            src = f.read()
-        assert "compile.cachedir" not in src and "dxtpu-jax-cache" not in src
-
-
-# ---------------------------------------------------------------------------
-# LRU-bounded transfer-helper jit caches (shared DX601 constant)
-# ---------------------------------------------------------------------------
-def test_helper_jit_cache_lru_bound_and_evictions():
-    from data_accelerator_tpu.compile.planner import TableData
-    import jax.numpy as jnp
-
-    drain_jit_evictions()
-    set_jit_cache_cap(4)
-    try:
-        t = TableData({"x": jnp.zeros((4096,), jnp.int32)},
-                      jnp.zeros((4096,), jnp.bool_))
-        for cap in (8, 16, 32, 64, 128, 256, 512, 1024):
-            _slice_table(t, cap)
-        assert helper_jit_cache_size() <= 4
-        assert drain_jit_evictions() >= 4
-        # LRU: re-slicing a recent cap compiles nothing new
-        _slice_table(t, 1024)
-        assert drain_jit_evictions() == 0
-    finally:
-        set_jit_cache_cap(DEFAULT_JIT_CACHE_CAP)
-
-
-def test_dx601_and_runtime_share_one_constant():
-    """The DX601 lint's default bound IS the runtime's default cap —
-    one constant, imported by both sides."""
-    from data_accelerator_tpu.analysis import compilecheck
-
-    assert compilecheck.DEFAULT_JIT_CACHE_CAP is DEFAULT_JIT_CACHE_CAP
-    report = analyze_flow_compile(load_flow("dx601_bucket_blowup"))
-    helper_keys = {
-        (e["entry"].split(":")[0], e["static"]["cap"])
-        for e in report.entries if e["entry"] != "step"
-    }
-    assert len(helper_keys) > DEFAULT_JIT_CACHE_CAP
-    assert "DX601" in report.codes()
-
-
-def test_jitcachecap_conf_validation():
-    flow = load_flow("dx602_manifest_donation")
-    with pytest.raises(Exception, match="jitcachecap"):
-        FlowProcessor(conf_for_gui(flow, {
-            "datax.job.process.compile.jitcachecap": "0",
-        }))
+    with open(os.path.join(checkout, "tests/conftest.py"),
+              encoding="utf-8") as f:
+        src = f.read()
+    assert "compile.cachedir" not in src and "dxtpu-jax-cache" not in src
 
 
 # ---------------------------------------------------------------------------
@@ -626,7 +558,7 @@ def test_validate_endpoint_compile_and_all(flow_ops):
     ]
     # a tampered shipped manifest reaches DX603 through the endpoint
     bad = copy.deepcopy(cli.manifest)
-    bad["entries"][1]["avals"]["leaves"][0][0][0] += 1
+    bad["entries"][0]["avals"]["leaves"][0][0][0] += 1
     status, out = api.dispatch(
         "POST", "api/flow/validate",
         body={"flow": flow, "compile": True, "compileManifest": bad},

@@ -115,16 +115,16 @@ def test_engine_conf_lattice_clean_with_pinned_inventory():
     # ``# dx-conf:`` marker or registry row must adjust these numbers
     # consciously (and justify itself in review)
     assert cd["analyzedFiles"] == 93
-    assert cd["readSites"] == 102
-    assert cd["readKeys"] == 96
-    assert cd["producedKeys"] == 52
-    assert cd["knobTokens"] == 6
-    assert cd["registryKeys"] == len(CONF_REGISTRY) == 108
-    assert cd["constraints"] == len(CONSTRAINTS) == 3
+    assert cd["readSites"] == 97
+    assert cd["readKeys"] == 92
+    assert cd["producedKeys"] == 51
+    assert cd["knobTokens"] == 5
+    assert cd["registryKeys"] == len(CONF_REGISTRY) == 104
+    assert cd["constraints"] == len(CONSTRAINTS) == 1
 
 
 def test_registry_covers_every_runtime_read_site_exactly():
-    """100% read-site coverage, by exact count: every one of the 102
+    """100% read-site coverage, by exact count: every one of the 97
     scanned read sites resolves to a registry row (a DX1000 would also
     fail the self-lint above — this pins the count the other way)."""
     report = analyze_conf_modules(conf_module_paths())
@@ -133,7 +133,7 @@ def test_registry_covers_every_runtime_read_site_exactly():
         if (rows_matching_family(r.key) if "*" in r.key
             else match_key(r.key) is not None)
     ]
-    assert len(covered) == len(report.read_sites) == 102
+    assert len(covered) == len(report.read_sites) == 97
 
 
 def test_registry_parity_rows_are_exactly_the_azurefunction_family():
@@ -251,8 +251,8 @@ def test_audit_counts_unknown_value_and_constraint_findings():
     audit = audit_conf({
         "datax.job.process.bogus.key": "1",          # unknown
         "datax.job.process.pipeline.depth": "0",     # bounds
-        "datax.job.process.numchips": "4",           # } constraint
-        "datax.job.process.pipeline.sizedtransfer": "true",
+        "datax.job.process.state.filteringest": "true",  # constraint
+        "datax.job.process.numchips": "4",
     })
     assert not audit.ok
     assert audit.audited == 4
@@ -348,8 +348,8 @@ def test_cli_conf_json_and_all_fold_in():
     assert proc.returncode == 0, proc.stderr
     report = json.loads(proc.stdout)
     assert report["schemaVersion"] == REPORT_SCHEMA_VERSION == 5
-    assert report["conf"]["readSites"] == 102
-    assert report["conf"]["registryKeys"] == 108
+    assert report["conf"]["readSites"] == 97
+    assert report["conf"]["registryKeys"] == 104
     # --all includes the conf block (one CI call, every tier)
     proc2 = _run_cli(["--all", "--json", path])
     assert proc2.returncode == 0, proc2.stderr

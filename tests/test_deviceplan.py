@@ -4,8 +4,8 @@
 - abstract-eval purity: ``--device`` analysis derives shapes without
   executing anything (no real arrays are produced)
 - the tier-1 drift gate (acceptance criterion): for every baseline
-  config shape — including the EXACT flow bench.py measures
-  (``__graft_entry__._build``) — the predicted per-stage HBM footprint
+  config shape — including the flow ``__graft_entry__._build``
+  builds — the predicted per-stage HBM footprint
   matches the arrays a real batch materializes, within the stated
   bound: EXACT byte equality (0 tolerance); the closed-form model, the
   ``jax.eval_shape`` derivation and the materialized arrays must agree.
@@ -222,7 +222,7 @@ def test_predicted_hbm_matches_materialized(tmp_path, shape):
 
 
 def test_bench_flow_model_matches_materialized():
-    """The EXACT flow bench.py measures (__graft_entry__._build, both
+    """The flow __graft_entry__._build builds (both
     the single-source headline flow and the two-source windowed-join
     variant) passes the same exact-byte drift gate."""
     import __graft_entry__ as ge

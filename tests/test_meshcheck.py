@@ -64,7 +64,6 @@ MESH_GOLDEN = [
     ("dx702_perchip_hbm", "DX702", SEV_ERROR, _TINY_HBM),
     ("dx703_ici_budget", "DX703", SEV_WARNING, _TINY_ICI),
     ("dx704_scaling_cliff", "DX704", SEV_WARNING, None),
-    ("dx705_mesh_transfer", "DX705", SEV_WARNING, None),
     ("dx790_mesh_lowering", "DX790", SEV_ERROR, None),
     ("dx791_mesh_unavailable", "DX791", SEV_WARNING, None),
 ]

@@ -194,15 +194,22 @@ def test_background_transfer_metrics_are_registered():
     """The device-resident result path's series (runtime/processor.py
     collect_counts/collect_tables + runtime/host.py background landing)
     resolve through the registry: the counts-only sync's wire bytes,
-    the landing backlog/latency gauges, and the slot-contention
-    counter."""
+    the landing backlog/latency gauges; the counters of the deleted
+    sized-transfer lattice no longer resolve."""
     for m in (
         "Sync_CountsBytes",
+        "Transfer_D2HBytes",
+        "Transfer_Efficiency",
         "Transfer_Background_Pending",
         "Transfer_Background_LandMs",
-        "Transfer_SlotContended_Count",
     ):
         assert MetricName.is_runtime_metric(m), m
+    for m in (
+        "Transfer_SlotContended_Count",
+        "Transfer_Overflow_Count",
+        "Compile_JitCacheEvict_Count",
+    ):
+        assert not MetricName.is_runtime_metric(m), m
     assert not MetricName.is_runtime_metric("Transfer_Background_Bogus")
     assert not MetricName.is_runtime_metric("Sync_Bogus")
 

@@ -435,7 +435,7 @@ def test_background_landing_failure_drains_and_requeues(tmp_path, depth):
 
     host, src, sink = _depth_host(tmp_path, depth)
     try:
-        assert host.background_transfer  # default on
+        assert host._landing_pool is not None  # one chip: background landing
         # spy on the batch tail so the test can prove it ran on the
         # background landing worker, not the dispatch loop
         tail_threads = []
