@@ -144,6 +144,10 @@ class MetricName:
         # source lag inside the program (runtime/host.py _traced_poll):
         # rows the sources still held after the batch's poll
         r"Source_Backlog_Rows",
+        # the socket source's receive buffers (runtime/sources.py):
+        # times since the last batch that one was reallocated or a
+        # delivered blob was copied a second time; 0 in steady state
+        r"Source_Buffer_Grow_Count",
         r"Output_[A-Za-z0-9_.]+_Events_Count",
         r"Output_[A-Za-z0-9_.]+_(GroupsDropped|JoinRowsDropped)",
         r"Sink_[a-z]+",

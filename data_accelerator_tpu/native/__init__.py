@@ -6,6 +6,7 @@ from .decoder import (
     load_library,
     native_available,
     native_crc32c,
+    scan_lines,
 )
 
 __all__ = [
@@ -16,4 +17,5 @@ __all__ = [
     "load_library",
     "native_available",
     "native_crc32c",
+    "scan_lines",
 ]

@@ -183,6 +183,11 @@ def load_library():
         ]
         lib.dx_crc32c.restype = ctypes.c_uint32
         lib.dx_crc32c.argtypes = [ctypes.c_char_p, ctypes.c_int64]
+        lib.dx_scan_lines.restype = ctypes.c_int64
+        lib.dx_scan_lines.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ]
         lib.dx_bad_timestamps.restype = ctypes.c_int64
         lib.dx_bad_timestamps.argtypes = [ctypes.c_void_p]
         lib.dx_dict_size.restype = ctypes.c_int64
@@ -215,6 +220,31 @@ def native_crc32c(data: bytes) -> Optional[int]:
     if not native_available():
         return None
     return int(load_library().dx_crc32c(data, len(data)))
+
+
+def scan_lines(
+    buf: bytearray, start: int, stop: int, max_lines: int
+) -> Tuple[int, int, int]:
+    """Walk the whole lines of ``buf[start:stop]`` until ``max_lines``
+    of them that hold more than whitespace are passed: returns (those
+    lines' count, the index just past the last line passed, the
+    whitespace-only lines passed on the way). An unterminated tail is
+    never passed. No object a line, and the interpreter lock is
+    released for the walk."""
+    if not 0 <= start <= stop <= len(buf):
+        raise ValueError(f"scan_lines [{start}, {stop}) of {len(buf)} bytes")
+    if start == stop:
+        return 0, start, 0
+    lib = load_library()
+    # the export pins ``buf`` (no resize, no free) for the call
+    first = ctypes.c_char.from_buffer(buf, start)
+    cut = ctypes.c_int64(0)
+    blank = ctypes.c_int64(0)
+    lines = lib.dx_scan_lines(
+        ctypes.byref(first), stop - start, max_lines,
+        ctypes.byref(cut), ctypes.byref(blank),
+    )
+    return int(lines), start + int(cut.value), int(blank.value)
 
 
 def _decode_threads(conf_threads: Optional[int] = None) -> int:

@@ -376,9 +376,9 @@ def feed_socket(source, payload: bytes, expect_events: Optional[int] = None,
     if expect_events is None:
         expect_events = payload.count(b"\n")
     deadline = time.time() + timeout_s
-    while time.time() < deadline and len(source._buf) < expect_events:
+    while time.time() < deadline and source.buffered_rows < expect_events:
         time.sleep(0.01)
-    if len(source._buf) < expect_events:
+    if source.buffered_rows < expect_events:
         raise TimeoutError(
-            f"socket source buffered {len(source._buf)}/{expect_events}"
+            f"socket source buffered {source.buffered_rows}/{expect_events}"
         )

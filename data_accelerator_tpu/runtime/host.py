@@ -898,14 +898,22 @@ class StreamingHost:
         explicit activation there)."""
         with trace.activate(), tracing.span("decode"):
             polled = self._poll_and_encode()
-        backlog = [
-            s.backlog_rows for s in self.sources.values()
-            if s.backlog_rows is not None
-        ]
-        if backlog:
-            # source lag, inside the program: rows the sources still
-            # held when this batch's poll returned
-            trace.counters["Source_Backlog_Rows"] = float(sum(backlog))
+        # what the sources say about the poll, on the batch's end event
+        # (a source with no notion of one reports None: no counter).
+        # Source_Backlog_Rows: source lag inside the program, the rows
+        # still held when this batch's poll returned.
+        # Source_Buffer_Grow_Count: 0 says every byte of the batch was
+        # copied once on its way from the socket to the decoder.
+        for counter, attr in (
+            ("Source_Backlog_Rows", "backlog_rows"),
+            ("Source_Buffer_Grow_Count", "buffer_grows"),
+        ):
+            said = [
+                getattr(s, attr) for s in self.sources.values()
+                if getattr(s, attr) is not None
+            ]
+            if said:
+                trace.counters[counter] = float(sum(said))
         return polled
 
     def _dispatch_traced(self, trace, raw, batch_time_ms):

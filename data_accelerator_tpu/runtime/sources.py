@@ -20,6 +20,7 @@ import glob
 import json
 import os
 import re
+import select
 import socket
 import threading
 import time
@@ -27,6 +28,7 @@ from datetime import datetime, timedelta, timezone
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from ..core.schema import Schema, StringDictionary
+from ..native import load_library, scan_lines
 from ..utils import fs
 from ..utils.datagen import DataGenerator
 
@@ -73,6 +75,10 @@ class StreamingSource:
     # rows left in the source after the latest poll (the host reports
     # it as Source_Backlog_Rows); None: the source has no notion of it
     backlog_rows: Optional[int] = None
+    # times since the poll before the latest one that the source had to
+    # reallocate its receive buffer or copy a delivered blob a second
+    # time (Source_Buffer_Grow_Count); None: no such buffer
+    buffer_grows: Optional[int] = None
 
     def start(self, positions: Dict[Tuple[str, int], int]) -> None:
         """Apply checkpointed starting positions (source, partition)->seq."""
@@ -218,18 +224,114 @@ class FileSource(StreamingSource):
         }
 
 
+# A connection's receive buffer starts at this size and is replaced by
+# one twice as large whenever the bytes it holds fill over half of it;
+# a recv is never given less room than _RECV_ROOM_BYTES.
+_RECV_BUFFER_BYTES = 1 << 20
+_RECV_ROOM_BYTES = 1 << 16
+_ALL_LINES = 1 << 62
+
+
+class _Receiver:
+    """One connection's received bytes, contiguous in ``buf``:
+    ``[head, whole)`` is whole lines not yet delivered (``rows`` of them
+    hold more than whitespace, ``blank`` do not), ``[whole, tail)`` the
+    unterminated tail of the newest line. No object a line: lines are
+    counted where they lie (``native.scan_lines``). Every field is
+    guarded by the source's lock."""
+
+    def __init__(self):
+        self.buf = bytearray(_RECV_BUFFER_BYTES)
+        self.view = memoryview(self.buf)
+        self.head = self.whole = self.tail = 0
+        self.rows = self.blank = 0
+        self.closed = False
+
+    def make_room(self) -> bool:
+        """Room for the next recv. True when that took a fresh buffer
+        (the end was reached with lines still waiting): the bytes held
+        were copied to its front."""
+        if len(self.buf) - self.tail >= _RECV_ROOM_BYTES:
+            return False
+        held = self.tail - self.head
+        buf = bytearray(len(self.buf) * (2 if held * 2 > len(self.buf) else 1))
+        view = memoryview(buf)
+        view[:held] = self.view[self.head:self.tail]
+        self.buf, self.view = buf, view
+        self.whole -= self.head
+        self.head, self.tail = 0, held
+        return True
+
+    def received(self, n: int) -> None:
+        """``n`` bytes arrived at ``tail``: count the lines they ended
+        (the last newline is looked for from the end, so bytes that end
+        no line are not walked again and again)."""
+        newline = self.buf.rfind(b"\n", self.tail, self.tail + n)
+        self.tail += n
+        if newline >= 0:
+            rows, self.whole, blank = scan_lines(
+                self.buf, self.whole, newline + 1, _ALL_LINES
+            )
+            self.rows += rows
+            self.blank += blank
+
+    def end_of_stream(self) -> None:
+        """The peer closed: a last line without its newline is
+        delivered, as ``for line in f`` delivers it."""
+        if self.tail > self.whole:
+            self.buf[self.tail] = 0x0A  # make_room() left room for it
+            self.received(1)
+        self.closed = True
+
+    def take(self, max_lines: int) -> Tuple[memoryview, int, int]:
+        """Hand over the oldest waiting lines, at most ``max_lines``
+        non-blank ones: (their bytes, the non-blank lines among them,
+        the blank ones). The bytes are a view: copy them before the
+        lock is released or ``rewind`` is called."""
+        if self.rows <= max_lines:
+            rows, cut, blank = self.rows, self.whole, self.blank
+        else:
+            rows, cut, blank = scan_lines(
+                self.buf, self.head, self.whole, max_lines
+            )
+        part = self.view[self.head:cut]
+        self.head = cut
+        self.rows -= rows
+        self.blank -= blank
+        return part, rows, blank
+
+    def rewind(self) -> None:
+        """Once every whole line is delivered the next bytes land at
+        the front again (the unterminated tail, as a rule under a line
+        long, moves there), so a source that keeps up never reaches the
+        end of its buffer."""
+        if 0 < self.head == self.whole:
+            rest = self.tail - self.whole
+            self.view[:rest] = self.view[self.whole:self.tail]
+            self.head = self.whole = 0
+            self.tail = rest
+
+
 class SocketSource(StreamingSource):
     """Newline-delimited JSON over TCP — the ingest-over-DCN stand-in for
-    the EventHub/Kafka receivers. A background thread accepts connections
-    and buffers events; poll() drains up to max_events."""
+    the EventHub/Kafka receivers. A background thread accepts
+    connections; one reader a connection receives the wire's bytes into
+    that connection's contiguous buffer (``recv_into``), and
+    ``poll_raw`` hands the decoder a cut of it that ends at a line
+    boundary: no Python object a line between the socket and the
+    decoder, and one copy of a batch's bytes (the one that lets the
+    delivered blob outlive the buffer until its ``ack``)."""
 
     def __init__(self, host: str = "127.0.0.1", port: int = 0, name: str = "socket"):
         self.name = name
-        self._buf: List[bytes] = []
-        # un-acked delivered batches (from_seq, lines); ack() releases
-        # the oldest — a pipelined host holds several in flight
-        self._fifo = UnackedFifo()
+        load_library()  # the line scan: a build that fails raises here
+        # guards the receivers and the counters beside them
         self._lock = threading.Lock()
+        self._receivers: List[_Receiver] = []
+        self._grows = 0
+        # un-acked delivered batches (from_seq, blob, rows); ack()
+        # releases the oldest — a pipelined host holds several in flight
+        self._fifo = UnackedFifo()
         self._seq = 0
         self._server = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._server.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
@@ -251,37 +353,81 @@ class SocketSource(StreamingSource):
             ).start()
 
     def _reader(self, conn):
+        rx = _Receiver()
+        with self._lock:
+            self._receivers.append(rx)
+        readable = select.poll()
+        readable.register(conn, select.POLLIN)
         with conn:
-            f = conn.makefile("rb")
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
+            while True:
+                # wait outside the lock; the recv itself then cannot
+                # block, so the buffer only ever changes under the lock
+                readable.poll()
                 with self._lock:
-                    self._buf.append(line)
+                    self._grows += rx.make_room()
+                    try:
+                        n = conn.recv_into(rx.view[rx.tail:])
+                    except OSError:
+                        n = 0  # a reset connection ends like a closed one
+                    if n == 0:
+                        rx.end_of_stream()
+                        return
+                    rx.received(n)
+
+    @property
+    def buffered_rows(self) -> int:
+        """Whole non-blank lines received and not yet polled."""
+        with self._lock:
+            return sum(rx.rows for rx in self._receivers)
 
     def poll_raw(self, max_events: int) -> Tuple[bytes, int, Offsets]:
-        """Drain up to max_events raw JSON lines as one newline-joined
-        blob for the native decoder — no per-event Python parse.
+        """Up to max_events raw JSON lines as one blob of whole,
+        newline-terminated lines for the native decoder — no per-event
+        Python parse. Blank lines are neither delivered nor counted; a
+        line still without its newline waits for it. When more lines
+        than max_events wait, the cut is after the max_events-th and
+        the rest stays for the next poll (``backlog_rows``).
 
-        Delivered lines join an in-flight FIFO until their ``ack()``;
+        Delivered blobs join an in-flight FIFO until their ``ack()``;
         after ``requeue_unacked()`` (a failed batch) the next polls
-        re-deliver the un-acked batches in order (at-least-once within
-        the process; cross-restart replay needs a replayable upstream
-        like the file/blob source)."""
+        re-deliver the un-acked batches byte for byte, in order
+        (at-least-once within the process; cross-restart replay needs a
+        replayable upstream like the file/blob source)."""
         requeued = self._fifo.next_redelivery()
         if requeued is not None:
-            frm, lines = requeued
+            frm, blob, n = requeued
         else:
             with self._lock:
-                lines = self._buf[:max_events]
-                self._buf = self._buf[max_events:]
-                self.backlog_rows = len(self._buf)
+                parts, n, blank = [], 0, 0
+                for rx in self._receivers:
+                    part, rows, blanks = rx.take(max_events - n)
+                    if rows:
+                        parts.append(part)
+                        n += rows
+                        blank += blanks
+                blob = b"".join(parts)  # the batch's one copy
+                if blank:
+                    # rare: what ``line.strip()`` did, by a second copy
+                    blob = b"".join(
+                        line + b"\n" for line in blob.split(b"\n")
+                        if line.strip()
+                    )
+                    self._grows += 1
+                for rx in self._receivers:
+                    rx.rewind()
+                self._receivers = [
+                    rx for rx in self._receivers
+                    if not (rx.closed and rx.head == rx.tail)
+                ]
+                if len(self._receivers) > 1:
+                    # the connection served first takes turns
+                    self._receivers.append(self._receivers.pop(0))
+                self.backlog_rows = sum(rx.rows for rx in self._receivers)
+                self.buffer_grows, self._grows = self._grows, 0
                 frm = self._seq
-                self._seq += len(lines)
-        self._fifo.deliver((frm, lines))
-        blob = b"\n".join(lines) + (b"\n" if lines else b"")
-        return blob, len(lines), {(self.name, 0): (frm, frm + len(lines))}
+                self._seq += n
+        self._fifo.deliver((frm, blob, n))
+        return blob, n, {(self.name, 0): (frm, frm + n)}
 
     def ack(self) -> None:
         self._fifo.ack_oldest()

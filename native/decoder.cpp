@@ -1215,6 +1215,36 @@ uint32_t dx_crc32c(const char* buf, int64_t len) {
   return crc32c((const uint8_t*)buf, (size_t)len);
 }
 
+// Walk the whole (newline-terminated) lines of buf[0, len) until
+// max_lines of them that hold more than whitespace have been passed;
+// an unterminated tail is never passed. Returns those lines' count,
+// *cut = the offset just past the last line passed, *blank = the
+// whitespace-only lines passed on the way. The socket source's line
+// accounting (runtime/sources.py): what `for line in f: line.strip()`
+// did there, without an object a line.
+int64_t dx_scan_lines(const char* buf, int64_t len, int64_t max_lines,
+                      int64_t* cut, int64_t* blank) {
+  const char* p = buf;
+  const char* end = buf + len;
+  int64_t lines = 0;
+  int64_t blanks = 0;
+  while (p < end && lines < max_lines) {
+    const char* nl = static_cast<const char*>(memchr(p, '\n', end - p));
+    if (!nl) break;
+    const char* q = p;
+    while (q < nl && (*q == ' ' || (*q >= '\t' && *q <= '\r'))) ++q;
+    if (q == nl) {
+      ++blanks;
+    } else {
+      ++lines;
+    }
+    p = nl + 1;
+  }
+  *cut = p - buf;
+  *blank = blanks;
+  return lines;
+}
+
 // Rows dropped by the last decode because a string timestamp was
 // unparseable (matches the Python encoder's bad_timestamps stat).
 int64_t dx_bad_timestamps(void* dv) {

@@ -472,9 +472,9 @@ def home_batches(tmp_path_factory):
             conn.sendall(traffic.EventStream(cell["flow"], 7).render(
                 0, sent, time.time()))
         deadline = time.time() + 30
-        while len(src._buf) < sent and time.time() < deadline:
+        while src.buffered_rows < sent and time.time() < deadline:
             time.sleep(0.01)
-        assert len(src._buf) == sent
+        assert src.buffered_rows == sent
         for _ in range(3):
             host.run_batch()
     finally:
@@ -541,6 +541,11 @@ def test_source_backlog_rows_is_rows_sent_less_rows_polled(home_batches):
     from data_accelerator_tpu.constants import MetricName
 
     assert MetricName.is_runtime_metric("Source_Backlog_Rows")
+    # the receive buffer's own counter rides beside it: whatever the
+    # 5,000 lines cost on the way in, a poll that only cuts costs none
+    assert MetricName.is_runtime_metric("Source_Buffer_Grow_Count")
+    assert home_batches["ends"][-1]["measurements"][
+        "Source_Buffer_Grow_Count"] == 0
 
 
 def test_every_operation_of_the_step_lies_under_a_stage_scope(home_batches):

@@ -171,7 +171,7 @@ def test_socket_source_depth2_inflight_ack_and_requeue():
         conn = socket.create_connection(("127.0.0.1", src.port), timeout=5)
         conn.sendall(b'{"a": 1}\n{"a": 2}\n{"a": 3}\n{"a": 4}\n')
         deadline = _time.time() + 5
-        while _time.time() < deadline and len(src._buf) < 4:
+        while _time.time() < deadline and src.buffered_rows < 4:
             _time.sleep(0.01)
 
         b1, n1, _ = src.poll_raw(2)   # batch 1: a=1,2

@@ -327,9 +327,9 @@ def _feed_socket(src, n_events):
     conn.sendall(payload)
     conn.close()
     deadline = _time.time() + 5
-    while _time.time() < deadline and len(src._buf) < n_events:
+    while _time.time() < deadline and src.buffered_rows < n_events:
         _time.sleep(0.01)
-    assert len(src._buf) == n_events
+    assert src.buffered_rows == n_events
 
 
 def _delivered_ks(blob):
