@@ -228,6 +228,9 @@ def _step_input_avals(bundle: FlowDevicePlan, gui: dict) -> tuple:
             },
             jax.ShapeDtypeStruct((slots, cap), jnp.bool_),
         )
+    for vname, ws in bundle.pipeline.window_states.items():
+        # per-slot partial aggregates of a decomposed windowed GROUP BY
+        rings[vname] = jax.eval_shape(ws.init)
     state = {
         n: table_struct(schema, cap) for n, (schema, cap) in bundle.state.items()
     }
@@ -259,6 +262,7 @@ def _build_step(bundle: FlowDevicePlan, gui: dict):
         ],
         proj_views=dict(bundle.projection_views),
         primary_target=primary,
+        window_states=dict(bundle.pipeline.window_states),
     )
 
 

@@ -50,6 +50,7 @@ from ..runtime.processor import (
     default_projection,
     projection_select,
     schema_to_view,
+    window_inputs,
     window_target,
 )
 from ..runtime.timewindow import num_slots
@@ -548,7 +549,7 @@ def _plan_from_gui(
 
         # windows over projected tables (ring retention model)
         windows: Dict[str, Tuple[str, float]] = {}
-        ring_slots: Dict[str, int] = {}
+        table_slots: Dict[str, int] = {}
         for wname, duration in rc.time_windows.items():
             table = window_target(wname, targets)
             if table not in target_schemas:
@@ -563,7 +564,7 @@ def _plan_from_gui(
                 )
             windows[wname] = (table, dur_s)
             slots = num_slots(dur_s, watermark_s, interval_s)
-            ring_slots[table] = max(ring_slots.get(table, 1), slots)
+            table_slots[table] = max(table_slots.get(table, 1), slots)
 
         # accumulation tables
         state: Dict[str, Tuple[ViewSchema, int]] = {}
@@ -578,9 +579,23 @@ def _plan_from_gui(
         for wname, (table, _d) in windows.items():
             inputs[wname] = (
                 target_schemas[table],
-                ring_slots[table] * target_caps[table],
+                table_slots[table] * target_caps[table],
             )
-        pipeline = pc.compile_transform(rc.code, inputs, state)
+        projections: Dict[str, List[List[str]]] = {}
+        for sname, _sprops, target in sources:
+            projections.setdefault(target, []).append(
+                [snippets[sname]] if snippets[sname]
+                else [default_projection(schemas[sname], ts_col)]
+            )
+        pipeline = pc.compile_transform(
+            rc.code, inputs, state,
+            windows=window_inputs(windows, table_slots, projections, ts_col),
+        )
+        # a window the planner holds as partial aggregates keeps no ring
+        ring_slots = {
+            table: table_slots[table] for wname, (table, _d) in windows.items()
+            if wname not in pipeline.partial_windows
+        }
     except EngineException as e:
         diags.append(make("DX290", "", str(e)))
         return None
@@ -785,7 +800,31 @@ def _stage_walk(
             detail=f"{slots} slots x {plan.target_caps[table]} rows "
                    "(device-resident window state)",
         ))
+    for vname, ws in plan.pipeline.window_states.items():
+        # a windowed GROUP BY the planner decomposed: K slots of per-group
+        # partial aggregates instead of the rows (the combined groups are
+        # what the view's select reads, under the prefixed name)
+        from types import SimpleNamespace
+
+        from ..compile.planner import WINDOW_PARTIALS_PREFIX
+
+        def combined(_env, _base, now, ws=ws):
+            state = ws.init()
+            return ws.combine(state, state.parts["n"][0], now)
+
+        env[WINDOW_PARTIALS_PREFIX + vname] = eval_view(
+            SimpleNamespace(fn=combined), env
+        )
+        stages.append(StageCost(
+            name=f"window-state:{vname}", kind="ring", rows=ws.groups,
+            hbm_bytes=ws.state_bytes, model_bytes=ws.state_bytes,
+            detail=f"{ws.slots} slots x {ws.groups} groups x "
+                   f"{len(ws.parts)} partial aggregates over {ws.window} "
+                   "(device-resident window state)",
+        ))
     for wname, (table, dur_s) in plan.windows.items():
+        if table not in plan.ring_slots:
+            continue
         rows = plan.ring_slots[table] * plan.target_caps[table]
         schema = plan.target_schemas[table]
         t = make_table(schema, rows)

@@ -372,7 +372,17 @@ def _infer_plan(
             detail=f"{slots} slots x {bundle.target_caps[table]} rows, "
                    "capacity dim sharded",
         ))
+    for vname, ws in bundle.pipeline.window_states.items():
+        stages.append(MeshStage(
+            name=f"window-state:{vname}", kind="ring", axis=AXIS_REPLICATED,
+            scaling=SCALE_REPLICATED, rows=ws.groups,
+            hbm_bytes=ws.state_bytes, per_chip_bytes=ws.state_bytes,
+            detail=f"{ws.slots} slots x {ws.groups} groups of partial "
+                   "aggregates (replicated)",
+        ))
     for wname, (table, dur_s) in bundle.windows.items():
+        if table not in bundle.ring_slots:
+            continue
         rows = bundle.ring_slots[table] * bundle.target_caps[table]
         schema = bundle.target_schemas[table]
         b = table_bytes(schema.types, rows)
@@ -528,8 +538,20 @@ def _cross_check(
         def body(tables, _view=view, _aux=aux):
             t = dict(tables)
             t["__aux"] = _aux
-            return _view.fn(t, jnp.asarray(0, jnp.int32),
-                            jnp.asarray(0, jnp.int32))
+            zero = jnp.asarray(0, jnp.int32)
+            ws = _view.window_state
+            if ws is not None:
+                # a decomposed windowed GROUP BY: the batch folded into
+                # an empty state and combined is its stage body
+                from ..compile.planner import WINDOW_PARTIALS_PREFIX
+
+                st, rows, dropped = ws.fold(
+                    t[ws.table], ws.init(), zero, zero, zero, zero, _aux
+                )
+                t[WINDOW_PARTIALS_PREFIX + _view.name] = ws.combine(
+                    st, rows, dropped
+                )
+            return _view.fn(t, zero, zero)
 
         if p is not None and p.unshardable_udfs \
                 and jax.default_backend() != "tpu":

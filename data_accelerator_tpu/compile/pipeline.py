@@ -26,9 +26,12 @@ from ..core.schema import StringDictionary
 from .planner import (
     CompiledView,
     PlannerConfig,
+    RawWindowNeeded,
     SelectCompiler,
     TableData,
     ViewSchema,
+    WindowInput,
+    WindowPartialsPlan,
 )
 from .sqlparser import parse_select
 from .transform_parser import COMMAND_TYPE_QUERY, ParsedResult, TransformParser
@@ -45,6 +48,20 @@ class Pipeline:
     # the runtime materializes AuxTableBuilder(aux_registry, dictionary)
     # .tables() per batch and passes it as tables["__aux"]
     aux_registry: Optional[object] = None
+    # TIMEWINDOW tables held as per-slot partial aggregates, not as raw
+    # rows: every statement that reads one is a GROUP BY the planner
+    # could decompose (compile/planner.py _compile_window_partials)
+    partial_windows: Tuple[str, ...] = ()
+
+    @property
+    def window_states(self) -> Dict[str, WindowPartialsPlan]:
+        """View name -> the partial-aggregate state it keeps between
+        batches (the last definition of a name, as ``run`` has it)."""
+        return {
+            v.name: v.window_state for v in self.views
+            if v.window_state is not None
+            and self.view_by_name(v.name) is v
+        }
 
     def run(
         self, tables: Dict[str, TableData], base_s, now_rel_ms, aux=None
@@ -152,18 +169,48 @@ class PipelineCompiler:
         transform: str | ParsedResult,
         inputs: Dict[str, Tuple[ViewSchema, int]],
         state_tables: Optional[Dict[str, Tuple[ViewSchema, int]]] = None,
+        windows: Optional[Dict[str, WindowInput]] = None,
     ) -> Pipeline:
         """Compile a full transform script.
 
         inputs: table name -> (schema, capacity) for source tables
         (DataXProcessedInput, its TIMEWINDOW variants, reference data).
         state_tables: accumulation tables (previous-state inputs).
+        windows: which of the inputs are TIMEWINDOW tables. A window
+        whose rows share their batch's time, whose state is not handed
+        off by key partition and whose every reader is a decomposable
+        GROUP BY is held as per-slot partial aggregates;
+        the planner decides from the statements alone (no conf key): it
+        starts from every such window and gives one up when a statement
+        turns out to need its rows.
         """
         parsed = (
             transform
             if isinstance(transform, ParsedResult)
             else TransformParser.parse_text(transform)
         )
+        windows = windows or {}
+        selects = [
+            parse_select(c.text) for c in parsed.commands
+            if c.command_type == COMMAND_TYPE_QUERY and c.name is not None
+        ]
+        read = {t for sel in selects for t in _referenced_tables(sel)}
+        partial = {
+            w for w, info in windows.items()
+            if info.slot_uniform_time and not info.handoff_by_key
+            and w in read
+        }
+        while True:
+            try:
+                return self._compile(
+                    parsed, inputs, state_tables, windows, partial
+                )
+            except RawWindowNeeded as e:
+                partial.discard(e.window)
+
+    def _compile(
+        self, parsed, inputs, state_tables, windows, partial_windows
+    ) -> Pipeline:
         catalog: Dict[str, ViewSchema] = {}
         capacities: Dict[str, int] = {}
         for name, (schema, cap) in inputs.items():
@@ -198,7 +245,8 @@ class PipelineCompiler:
                     )
             compiler = SelectCompiler(
                 catalog, capacities, self.dictionary, self.udfs, self.config,
-                aux=self.aux,
+                aux=self.aux, windows=windows,
+                partial_windows=partial_windows,
             )
             view = compiler.compile_select(cmd.name, sel)
             if view.host_order and view.host_limit is not None:
@@ -216,4 +264,5 @@ class PipelineCompiler:
             input_names=list(inputs) + state_names,
             state_tables=state_names,
             aux_registry=self.aux,
+            partial_windows=tuple(sorted(partial_windows)),
         )

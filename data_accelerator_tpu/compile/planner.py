@@ -34,6 +34,7 @@ from ..ops import (
 )
 from ..ops.join import left_join_indices
 from .exprs import (
+    _DTYPES,
     AGGREGATE_FNS,
     ArrayValue,
     CompiledExpr,
@@ -188,6 +189,87 @@ class StagePlan:
     order_keys: int = 0
     limit: Optional[int] = None
     union_branches: int = 1
+    # device bytes of the per-slot partial aggregates a windowed GROUP BY
+    # keeps between batches (0: the view keeps no window state)
+    window_state_bytes: int = 0
+
+
+# ---------------------------------------------------------------------------
+# Window state: what the planner is told about a TIMEWINDOW table, and
+# what it decides for a GROUP BY over one
+# ---------------------------------------------------------------------------
+@dataclass(frozen=True)
+class WindowInput:
+    """One ``TIMEWINDOW`` table as the runtime declares it."""
+
+    table: str  # the projected table whose batches the window retains
+    slots: int  # batches retained (runtime/timewindow.py num_slots)
+    duration_ms: int
+    ts_col: str  # the flow's timestamp column
+    # every row of a batch carries the batch's one time (the timestamp
+    # column is the ``current_timestamp()`` projection): a slot is then
+    # wholly inside or outside the window
+    slot_uniform_time: bool
+    # the runtime hands this window's state to rescale successors by key
+    # partition (``process.state.snapshoturl``: rows re-packed a
+    # partition, runtime/statepartition.py), which needs the rows
+    handoff_by_key: bool = False
+
+
+class RawWindowNeeded(Exception):
+    """A statement reads the rows of a window that was going to be held
+    as partial aggregates: the window stays a raw-row ring."""
+
+    def __init__(self, window: str, why: str):
+        super().__init__(f"{window}: {why}")
+        self.window = window
+        self.why = why
+
+
+# the combined groups of a partial-aggregate view, handed to the view's
+# function under this table name (the step folds and combines outside the
+# view's own scope: runtime/processor.py build_step_fn)
+WINDOW_PARTIALS_PREFIX = "__window_partials."
+
+
+@dataclass
+class WindowPartialsPlan:
+    """The per-slot partial aggregates one windowed GROUP BY keeps
+    (``runtime/timewindow.py WindowPartials``) and how a batch is folded
+    into them."""
+
+    window: str
+    table: str
+    slots: int
+    groups: int
+    duration_ms: int
+    key_dtypes: Tuple[object, ...]
+    parts: Dict[str, Tuple[str, object]]  # partial name -> (op, dtype)
+    # fold(batch, state, slot, delta_ms, base_s, now_rel_ms, aux)
+    #   -> (new state, live rows a column, groups dropped)
+    fold: Callable = None
+
+    @property
+    def ops(self) -> Dict[str, str]:
+        return {n: op for n, (op, _dt) in self.parts.items()}
+
+    def init(self):
+        from ..runtime.timewindow import make_partials
+
+        return make_partials(
+            self.key_dtypes, self.parts, self.slots, self.groups
+        )
+
+    def combine(self, state, rows, dropped) -> "TableData":
+        from ..runtime.timewindow import combine_partials
+
+        return combine_partials(state, self.ops, rows, dropped)
+
+    @property
+    def state_bytes(self) -> int:
+        per_group = sum(jnp.dtype(dt).itemsize for dt in self.key_dtypes) + 1
+        per_cell = sum(jnp.dtype(dt).itemsize for _op, dt in self.parts.values())
+        return self.groups * (per_group + self.slots * per_cell) + self.slots * 5
 
 
 @dataclass
@@ -208,6 +290,10 @@ class CompiledView:
     # lowering decisions, for static cost analysis (None for views built
     # outside the select compiler, e.g. raw inputs)
     plan: Optional[StagePlan] = None
+    # set when the view is a GROUP BY over a window held as per-slot
+    # partial aggregates: ``fn`` then reads the combined groups from
+    # ``tables[WINDOW_PARTIALS_PREFIX + name]``
+    window_state: Optional[WindowPartialsPlan] = None
 
 
 # ---------------------------------------------------------------------------
@@ -287,6 +373,26 @@ def _has_aggregate(e: Expr) -> bool:
     return False
 
 
+def _walk_exprs(node, stop=lambda n: False):
+    """Every AST node under ``node``, itself first; nothing below a node
+    ``stop`` holds for."""
+    if isinstance(node, (tuple, list)):
+        for el in node:
+            yield from _walk_exprs(el, stop)
+        return
+    if not hasattr(node, "__dataclass_fields__"):
+        return
+    yield node
+    if stop(node):
+        return
+    for f in node.__dataclass_fields__:
+        yield from _walk_exprs(getattr(node, f), stop)
+
+
+def _is_aggregate_call(node) -> bool:
+    return isinstance(node, Func) and node.name in AGGREGATE_FNS
+
+
 # ---------------------------------------------------------------------------
 # Planner config
 # ---------------------------------------------------------------------------
@@ -317,9 +423,16 @@ class SelectCompiler:
         udfs: Optional[dict] = None,
         config: PlannerConfig = PlannerConfig(),
         aux: Optional["AuxRegistry"] = None,
+        windows: Optional[Dict[str, WindowInput]] = None,
+        partial_windows: Sequence[str] = (),
     ):
         self.catalog = catalog
         self.capacities = capacities
+        # TIMEWINDOW tables, and those of them held as per-slot partial
+        # aggregates: a statement that needs such a window's rows raises
+        # RawWindowNeeded and the pipeline compiler keeps the ring
+        self.windows = windows or {}
+        self.partial_windows = frozenset(partial_windows)
         self.dictionary = dictionary
         self.udfs = udfs or {}
         self.config = config
@@ -380,7 +493,10 @@ class SelectCompiler:
         # SQL) applies to the whole union — hoist it
         order_by, limit = branches[-1].order_by, branches[-1].limit
         branches[-1] = replace(branches[-1], order_by=(), limit=None)
-        compiled = [self._compile_single(f"{name}${i}", b) for i, b in enumerate(branches)]
+        compiled = [
+            self._compile_single(f"{name}${i}", b, in_union=True)
+            for i, b in enumerate(branches)
+        ]
         first = compiled[0]
         names0 = list(first.schema.types) + list(first.schema.deferred)
         for c in compiled[1:]:
@@ -431,9 +547,12 @@ class SelectCompiler:
         return view
 
     # -- single select ---------------------------------------------------
-    def _compile_single(self, name: str, sel: Select) -> CompiledView:
+    def _compile_single(
+        self, name: str, sel: Select, in_union: bool = False
+    ) -> CompiledView:
         if sel.from_table is None:
             raise EngineException(f"SELECT without FROM not supported ({name})")
+        partial = self._partials_window(sel, in_union)
 
         # 1. FROM/JOIN scope
         scope, build_scope, scope_capacity, join_sites = self._compile_from(sel)
@@ -471,7 +590,12 @@ class SelectCompiler:
             )
             if having_c is not None and not is_device(having_c):
                 raise EngineException("HAVING must be device-computable")
-            view = self._compile_grouped(
+            view = self._compile_window_partials(
+                name, sel, scope, compiler, partial, where_fn, out_types,
+                deferred, flat_outputs,
+                having_fn=having_c.fn if having_c is not None else None,
+                from_tables=from_tables,
+            ) if partial is not None else self._compile_grouped(
                 name, sel, scope, compiler, build_scope, scope_capacity,
                 where_fn, out_types, deferred, flat_outputs, out_values,
                 having_fn=having_c.fn if having_c is not None else None,
@@ -1214,6 +1338,186 @@ class SelectCompiler:
             view.name, view.schema, capacity, run,
             select_values=view.select_values,
             plan=plan,
+            window_state=view.window_state,
+        )
+
+    # -- windowed GROUP BY over per-slot partial aggregates ---------------
+    def _partials_window(self, sel: Select, in_union: bool) -> Optional[str]:
+        """The window this statement can serve from partial aggregates,
+        None when it reads none that is held so. A statement that reads
+        such a window any other way needs its rows: RawWindowNeeded."""
+        read = [sel.from_table.name] + [j.table.name for j in sel.joins]
+        held = [t for t in read if t in self.partial_windows]
+        if not held:
+            return None
+        why = None
+        if sel.joins:
+            why = "a join reads the window's rows"
+        elif in_union:
+            why = "a UNION branch reads the window's rows"
+        elif not sel.group_by:
+            why = "no GROUP BY: the statement reads the window's rows"
+        elif sel.distinct:
+            why = "SELECT DISTINCT"
+        elif any(isinstance(i.expr, Star) for i in sel.items):
+            why = "SELECT * reads a representative row"
+        if why is not None:
+            raise RawWindowNeeded(held[0], why)
+        return held[0]
+
+    def _compile_window_partials(
+        self, name, sel, scope, compiler, wname, where_fn, out_types,
+        deferred, flat_outputs, having_fn=None, from_tables=(),
+    ) -> CompiledView:
+        """GROUP BY over a window held as per-slot partial aggregates
+        (``runtime/timewindow.py``): each batch is folded into its slot
+        once, the view reads the groups combined over the live slots.
+        The rows it returns are the raw ring's: what cannot be shown to
+        decompose raises RawWindowNeeded instead."""
+        from ..runtime.timewindow import ROWS, fold_partials
+
+        win = self.windows[wname]
+
+        def raw(why: str):
+            return RawWindowNeeded(wname, why)
+
+        def time_free(e: Expr) -> bool:
+            """No function call and no read of the timestamp column: the
+            value of a row is the same when its batch is folded as when
+            the window is read."""
+            for n in _walk_exprs(e):
+                if isinstance(n, (Func, Star)):
+                    return False
+                if isinstance(n, Col) and scope.resolve(n.parts)[1] == win.ts_col:
+                    return False
+            return True
+
+        plain = self._expr_compiler(scope)
+        # group keys: plain device columns (an alias of one resolves too)
+        alias_map = {
+            i.alias.lower(): i.expr for i in sel.items if i.alias is not None
+        }
+        key_cols: List[Tuple[str, str]] = []
+        key_compiled: List[CompiledExpr] = []
+        for g in sel.group_by:
+            if isinstance(g, Col) and len(g.parts) == 1 \
+                    and g.parts[0].lower() in alias_map:
+                g = alias_map[g.parts[0].lower()]
+            if not isinstance(g, Col):
+                raise raw(f"group key {g!r} is not a plain column")
+            v = plain.compile(g)
+            if not is_device(v) or v.type not in (
+                "long", "double", "boolean", "string"
+            ):
+                raise raw(f"group key {g.dotted} is not a device column")
+            ref = scope.resolve(g.parts)
+            if ref[1] == win.ts_col:
+                raise raw("the timestamp column is a group key")
+            key_cols.append(ref)
+            key_compiled.append(v)
+
+        # aggregates: COUNT / SUM / AVG / MIN / MAX of numeric row values
+        if compiler.udaf_nodes:
+            raise raw("an aggregate UDF reads the window's rows")
+        parts: Dict[str, Tuple[str, object]] = {ROWS: ("sum", jnp.int32)}
+        agg_args: Dict[str, CompiledExpr] = {}
+        for key, (fname, arg, dist) in compiler.agg_nodes.items():
+            if dist:
+                raise raw(f"{fname}(DISTINCT ...) does not decompose")
+            if fname == "COUNT":
+                continue
+            if not time_free(arg):
+                raise raw(f"{fname} argument {arg!r} is not a row value")
+            a = plain.compile_device(arg, f"{fname} argument")
+            if a.type not in ("long", "double"):
+                raise raw(f"{fname} of a {a.type} column")
+            agg_args[key] = a
+            dt = jnp.float32 if fname == "AVG" or a.type == "double" \
+                else jnp.int32
+            parts[key] = ("sum" if fname in ("SUM", "AVG") else fname.lower(), dt)
+        if sel.where is not None and not time_free(sel.where):
+            raise raw("WHERE is not a row predicate")
+        # what the select list and HAVING read outside their aggregates
+        # must be a group key: there is no representative row
+        outside = [i.expr for i in sel.items]
+        if sel.having is not None:
+            outside.append(sel.having)
+        for n in _walk_exprs(outside, stop=_is_aggregate_call):
+            if isinstance(n, Col) and scope.resolve(n.parts) not in key_cols:
+                raise raw(f"{n.dotted} is read from a representative row")
+
+        cap_t = self.capacities[win.table]
+        # the raw ring's group bound, so the rows are the same
+        groups = min(win.slots * cap_t, self.config.max_group_capacity)
+        binding = sel.from_table.binding
+        ops = {n: op for n, (op, _dt) in parts.items()}
+
+        def fold(batch, state, slot, delta_ms, base_s, now_rel_ms, aux):
+            env = EvalEnv(
+                {binding: batch.cols, "__aux": aux}, base_s, now_rel_ms,
+                batch.valid.shape,
+            )
+            valid = batch.valid
+            if where_fn is not None:
+                valid = valid & where_fn(env)
+            return fold_partials(
+                state, ops, [k.fn(env) for k in key_compiled], valid,
+                {key: a.fn(env).astype(parts[key][1])
+                 for key, a in agg_args.items()},
+                slot, delta_ms, now_rel_ms, win.duration_ms,
+            )
+
+        key_dtypes = tuple(_DTYPES[k.type] for k in key_compiled)
+        state = WindowPartialsPlan(
+            window=wname, table=win.table, slots=win.slots, groups=groups,
+            duration_ms=win.duration_ms, key_dtypes=key_dtypes, parts=parts,
+            fold=fold,
+        )
+        agg_nodes = dict(compiler.agg_nodes)
+
+        def run(tables, base_s, now_rel_ms):
+            t = tables[WINDOW_PARTIALS_PREFIX + name]
+            rows = t.cols[ROWS]
+            agg_results = {}
+            for key, (fname, _arg, _dist) in agg_nodes.items():
+                if fname == "COUNT":
+                    agg_results[key] = rows
+                elif fname == "AVG":
+                    agg_results[key] = t.cols[key] / jnp.maximum(
+                        rows, 1
+                    ).astype(jnp.float32)
+                else:
+                    agg_results[key] = t.cols[key]
+            scopes: Dict[str, Dict[str, jnp.ndarray]] = {}
+            for i, (b, c) in enumerate(key_cols):
+                scopes.setdefault(b, {})[c] = t.cols[f"key{i}"]
+            scopes["__agg"] = agg_results
+            self._inject_aux(scopes, tables)
+            group_env = EvalEnv(scopes, base_s, now_rel_ms, (groups,))
+            cols = {n: fn(group_env) for n, fn in flat_outputs}
+            out_valid = t.valid
+            if having_fn is not None:
+                out_valid = out_valid & having_fn(group_env)
+            cols["__overflow.groups"] = t.cols["__overflow.groups"]
+            return TableData(cols, out_valid)
+
+        return CompiledView(
+            name, ViewSchema(out_types, deferred), groups, run,
+            plan=StagePlan(
+                kind="group",
+                # the batch's rows are what is sorted, once
+                input_rows=cap_t,
+                output_rows=groups,
+                # what it reads every batch is the batch's own table
+                sources=(win.table,),
+                grouped=True,
+                group_keys=len(key_cols),
+                group_key_cols=tuple(sorted({c for _b, c in key_cols})),
+                n_aggregates=len(agg_nodes),
+                groups_bound=groups,
+                window_state_bytes=state.state_bytes,
+            ),
+            window_state=state,
         )
 
     # -- grouped path ----------------------------------------------------

@@ -212,6 +212,12 @@ class MetricName:
         # chips the mesh step's output lies on, every batch under a mesh
         # (a numchips conf that stepped on one chip reads 1)
         r"Mesh_Chips",
+        # window state: its device bytes (rings + per-slot partial
+        # aggregates), the slots inside the window this batch (partial
+        # aggregates only), the bytes the last window checkpoint wrote
+        r"Window_State_Bytes",
+        r"Window_Slots_Live",
+        r"Checkpoint_Window_Bytes",
         # model-vs-observed conformance (obs/conformance.py): windowed
         # observed/predicted ratios against the cost-model report
         # embedded in the conf, plus the cumulative drift-event count

@@ -68,7 +68,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def step_shardings(mesh: Mesh):
+def step_shardings(mesh: Mesh, rings=(), partials=()):
     """(in_shardings, out_shardings) pytree prefixes for
     ``FlowProcessor``'s step signature:
 
@@ -81,10 +81,19 @@ def step_shardings(mesh: Mesh):
 
     The prefixes apply leaf-wise over the dict pytrees, so N sources and
     N rings inherit the same layout without per-flow sharding code.
+
+    The window-state argument also carries, by view name, the per-slot
+    partial aggregates of the windowed GROUP BYs the planner decomposed
+    (``partials``; their leaves are [slots, groups], [groups] and
+    [slots], replicated: a group bound is small beside a batch, and
+    partitioned aggregation is ROADMAP S6's). A flow that has any names
+    its entries: ``rings`` the tables that keep raw rows.
     """
     row = row_sharding(mesh)
     ring = ring_sharding(mesh)
     rep = replicated(mesh)
+    if partials:
+        ring = {**{t: ring for t in rings}, **{v: rep for v in partials}}
     in_shardings = (row, ring, rep, rep, rep, rep, rep, rep, rep)
     out_shardings = (rep, ring, rep, rep)
     return in_shardings, out_shardings

@@ -60,6 +60,14 @@ def snapshot_arrays(snap: Dict) -> Dict:
             arrays[f"ring/{table}/cap"] = np.asarray(
                 int(ring["cap"]), np.int64
             )
+    for view, part in snap.get("partials", {}).items():
+        # the head of a view's per-slot partial aggregates: its key
+        # directory and slot times. The slots' rows are not here: whole
+        # in a partition payload ("parts"), in slot files a checkpoint
+        for i, a in enumerate(part["keys"]):
+            arrays[f"partial/{view}/key/{i}"] = a
+        for field_ in ("used", "slot_ts", "slot_live"):
+            arrays[f"partial/{view}/{field_}"] = part[field_]
     arrays["slot_counter"] = np.asarray(int(snap.get("slot_counter", 0)),
                                         np.int64)
     base = snap.get("base_ms")
@@ -90,6 +98,18 @@ def arrays_to_snapshot(z) -> Dict:
             ring["cap"] = int(z[key])
         else:
             ring["cols"][kind.split("/", 1)[1]] = z[key]
+    partials: Dict[str, Dict] = {}
+    for key in z.files:
+        if not key.startswith("partial/"):
+            continue
+        _, view, kind = key.split("/", 2)
+        part = partials.setdefault(view, {"keys": {}})
+        if kind.startswith("key/"):
+            part["keys"][int(kind[4:])] = z[key]
+        elif kind in ("used", "slot_ts", "slot_live"):
+            part[kind] = z[key]
+    for part in partials.values():
+        part["keys"] = [part["keys"][i] for i in sorted(part["keys"])]
     base = int(z["base_ms"])
     out = {
         "rings": rings,
@@ -100,6 +120,8 @@ def arrays_to_snapshot(z) -> Dict:
         out["dictionary"] = _json.loads(
             z["dictionary_json"].tobytes().decode("utf-8")
         )
+    if partials:
+        out["partials"] = partials
     return out
 
 
@@ -193,27 +215,47 @@ class OffsetCheckpointer:
 
 
 class WindowStateCheckpointer:
-    """Persist/restore the device window ring buffers across restarts.
+    """Persist/restore the device window state across restarts.
 
-    The offsets file above only replays the LAST batch; TIMEWINDOW ring
-    buffers hold up to window+watermark of history that a restart would
+    The offsets file above only replays the LAST batch; TIMEWINDOW state
+    holds up to window+watermark of history that a restart would
     otherwise silently zero. The reference keeps that state in the Spark
     StreamingContext checkpoint (datax-host host/StreamingHost.scala:83-89
-    ``StreamingContext.getOrCreate(checkpointDir, ...)``); here the rings
-    are plain arrays, so the snapshot is one ``window.npz`` written with
-    the same atomic-replace + ``.old`` backup semantics as offsets.txt.
+    ``StreamingContext.getOrCreate(checkpointDir, ...)``); here it is
+    plain arrays.
 
-    Serialized layout (all numpy): per ring table
-    ``ring/<table>/col/<name>`` + ``ring/<table>/valid``, plus the slot
-    counter and the time base the ring's relative timestamps refer to.
+    ``window.npz`` is the head, written last with the same
+    atomic-replace + ``.old`` backup semantics as offsets.txt: the slot
+    counter, the time base, the dictionary, every raw-row ring whole
+    (``ring/<table>/col/<name>`` + ``ring/<table>/valid``) and, for a
+    view that keeps per-slot partial aggregates, its key directory, slot
+    times and the names of the slot files that hold its slots' rows
+    (``partial/<view>/...``). Those rows are written a slot at a time:
+    one file a checkpoint under ``window-slots/`` with the slots written
+    since the last head landed (a slot's generation is the counter of
+    the batch that wrote it), never the whole state. A slot file is
+    named once and never rewritten, and is deleted only when neither the
+    head nor ``.old`` names it: whichever of the two a restart reads
+    finds every file it names, so a kill at any point restores a state
+    some completed checkpoint described.
     """
 
     FILE = "window.npz"
     BACKUP = "window.npz.old"
+    SLOTS_DIR = "window-slots"
 
     def __init__(self, checkpoint_dir: str):
         self.dir = checkpoint_dir
         os.makedirs(checkpoint_dir, exist_ok=True)
+        # slot counter of the head this object last wrote or loaded:
+        # what ``FlowProcessor.snapshot_window_state(since=...)`` may
+        # leave out. None: nothing on disk this process may build on
+        self.landed_counter: Optional[int] = None
+        # view -> [(first generation, last generation, file name)] as the
+        # landed head names them, and as the head now in ``.old`` does
+        self._slot_files: Dict[str, List[Tuple[int, int, str]]] = {}
+        self._old_slot_files: Dict[str, List[Tuple[int, int, str]]] = {}
+        self.last_bytes = 0  # bytes the last save wrote (head + slots)
 
     @property
     def path(self) -> str:
@@ -223,29 +265,125 @@ class WindowStateCheckpointer:
     def backup_path(self) -> str:
         return os.path.join(self.dir, self.BACKUP)
 
+    @property
+    def slots_dir(self) -> str:
+        return os.path.join(self.dir, self.SLOTS_DIR)
+
+    def forget(self) -> None:
+        """The processor did not take what ``load`` returned: the next
+        snapshot is whole and builds on no slot file."""
+        self.landed_counter = None
+        self._slot_files = {}
+
     def save(self, snap: Dict) -> None:
         """snap: FlowProcessor.snapshot_window_state() output."""
         with _trace_span("checkpoint/window"):
             self._save(snap)
 
     def _save(self, snap: Dict) -> None:
+        import json as _json
+
         import numpy as np
 
         arrays = snapshot_arrays(snap)
-        if os.path.exists(self.path):
+        counter = int(snap.get("slot_counter", 0))
+        written = 0
+        files: Dict[str, List[Tuple[int, int, str]]] = {}
+        if snap.get("partials"):
+            with _trace_span("checkpoint/window-slots"):
+                for view, part in snap["partials"].items():
+                    files[view], n = self._save_slots(view, part, counter)
+                    written += n
+            for view, part in snap["partials"].items():
+                arrays[f"partial/{view}/files_json"] = np.frombuffer(
+                    _json.dumps({
+                        "slots": int(part["slots"]),
+                        "groups": int(part["groups"]),
+                        "dtypes": {n: str(a.dtype)
+                                   for n, a in part["parts"].items()},
+                        "files": files[view],
+                    }).encode("utf-8"), dtype=np.uint8,
+                )
+        had_head = os.path.exists(self.path)
+        if had_head:
             shutil.copyfile(self.path, self.backup_path)
         tmp = self.path + ".tmp"
         with open(tmp, "wb") as f:
             np.savez(f, **arrays)
             f.flush()
             os.fsync(f.fileno())
+        written += os.path.getsize(tmp)
         _durable_replace(tmp, self.path)
+        self._old_slot_files = self._slot_files if had_head else {}
+        self._slot_files = files
+        self.landed_counter = counter
+        self.last_bytes = written
+        self._drop_unnamed_slot_files()
+
+    def _save_slots(
+        self, view: str, part: Dict, counter: int
+    ) -> Tuple[List[Tuple[int, int, str]], int]:
+        """Write the rows of the slots a snapshot brought (generations
+        ``first_gen`` on) as one new file; returns the slot files the new
+        head names for this view (those of the landed head that still
+        hold a live generation, then the new one) and the bytes
+        written."""
+        import numpy as np
+
+        first = int(part["first_gen"])
+        n = len(next(iter(part["parts"].values())))
+        oldest = max(0, counter - int(part["slots"]))
+        named = [
+            (a, b, name) for a, b, name in self._slot_files.get(view, [])
+            if oldest <= b < first
+        ]
+        covered = named[0][0] if named else first
+        if (named and covered > oldest) or (not named and first > oldest) \
+                or first + n != counter:
+            raise ValueError(
+                f"window checkpoint of {view}: the snapshot brings "
+                f"generations {first}..{first + n - 1} of "
+                f"{oldest}..{counter - 1} and the landed head names "
+                f"{[(a, b) for a, b, _ in named]}"
+            )
+        if n == 0:
+            return named, 0
+        os.makedirs(self.slots_dir, exist_ok=True)
+        name = (f"{view.replace(os.sep, '_')}.{first}-{counter - 1}."
+                f"{time.time_ns():x}.npz")
+        path = os.path.join(self.slots_dir, name)
+        with open(path + ".tmp", "wb") as f:
+            np.savez(f, **part["parts"])
+            f.flush()
+            os.fsync(f.fileno())
+        size = os.path.getsize(path + ".tmp")
+        _durable_replace(path + ".tmp", path)
+        return named + [(first, counter - 1, name)], size
+
+    def _drop_unnamed_slot_files(self) -> None:
+        """Delete the slot files (and torn temp files) that neither the
+        landed head nor the one in ``.old`` names."""
+        if not os.path.isdir(self.slots_dir):
+            return
+        keep = {
+            name
+            for files in (self._slot_files, self._old_slot_files)
+            for entries in files.values() for _a, _b, name in entries
+        }
+        for name in os.listdir(self.slots_dir):
+            if name not in keep:
+                try:
+                    os.remove(os.path.join(self.slots_dir, name))
+                except OSError:
+                    pass
 
     def load(self) -> Optional[Dict]:
         """Restore a snapshot dict, falling back to the backup; None when
         no (readable) snapshot exists — including when a crash left only
         a torn ``window.npz.tmp`` behind (the tmp is never read; the
-        previous complete checkpoint wins)."""
+        previous complete checkpoint wins). A head whose slot files are
+        missing or torn is as unreadable as a torn head. Partial
+        aggregates come back whole (``parts``: [slots, groups])."""
         import numpy as np
 
         for path in (self.path, self.backup_path):
@@ -253,7 +391,46 @@ class WindowStateCheckpointer:
                 continue
             try:
                 with np.load(path) as z:
-                    return arrays_to_snapshot(z)
+                    snap = arrays_to_snapshot(z)
+                    files = self._load_slots(z, snap)
             except Exception:
                 continue
+            self.landed_counter = int(snap["slot_counter"])
+            self._slot_files = files
+            return snap
         return None
+
+    def _load_slots(self, z, snap: Dict) -> Dict[str, List]:
+        """Fill every view's ``parts`` from the slot files its head
+        names; returns those names. Raises when one cannot be read."""
+        import json as _json
+
+        import numpy as np
+
+        files: Dict[str, List[Tuple[int, int, str]]] = {}
+        counter = int(snap["slot_counter"])
+        for view, part in snap.get("partials", {}).items():
+            head = _json.loads(
+                z[f"partial/{view}/files_json"].tobytes().decode("utf-8")
+            )
+            k, d = head["slots"], head["groups"]
+            files[view] = [tuple(e) for e in head["files"]]
+            # a slot no file holds was never written: it is not live, and
+            # the batch that takes it overwrites its whole row
+            parts = {
+                n: np.full((k, d), 0, np.dtype(dt))
+                for n, dt in head["dtypes"].items()
+            }
+            held = np.zeros(k, bool)
+            for first, last, name in files[view]:
+                with np.load(os.path.join(self.slots_dir, name)) as zs:
+                    for g in range(max(first, counter - k), last + 1):
+                        for n in parts:
+                            parts[n][g % k] = zs[n][g - first]
+                        held[g % k] = True
+            want = np.zeros(k, bool)
+            want[[g % k for g in range(max(0, counter - k), counter)]] = True
+            if (want & ~held).any():
+                raise ValueError(f"{view}: slots named by no file")
+            part["parts"] = parts
+        return files

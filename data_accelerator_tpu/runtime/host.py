@@ -300,12 +300,20 @@ class StreamingHost:
         # index owns and merges them — the handoff path
         self.window_restored_from: Optional[str] = None
         if self.window_checkpointer:
+            t_load = time.time()
             snap = self.window_checkpointer.load()
             if snap is not None:
                 if self.processor.restore_window_state(snap):
                     self.window_restored_from = "local"
-                    logger.info("restored window state from checkpoint")
+                    logger.info(
+                        "restored window state from checkpoint in %.2f s "
+                        "(slot counter %d)", time.time() - t_load,
+                        snap["slot_counter"],
+                    )
                 else:
+                    # nothing on disk this process builds on: its first
+                    # snapshot is whole
+                    self.window_checkpointer.forget()
                     logger.warning(
                         "window-state checkpoint incompatible with current "
                         "flow config; starting with empty windows"
@@ -675,6 +683,13 @@ class StreamingHost:
             metrics["IngestRateScale"] = self._rate_scale
             metrics["Pipeline_Depth"] = float(inflight_depth)
             metrics["Pipeline_Stall_Ms"] = stall_ms
+            if self.window_checkpointer \
+                    and self.window_checkpointer.last_bytes:
+                # bytes the last window checkpoint wrote: the head and
+                # the slots written since the one before, not the state
+                metrics["Checkpoint_Window_Bytes"] = float(
+                    self.window_checkpointer.last_bytes
+                )
             metrics.update(trace.counters)
             if backlog is not None:
                 # background landing accounting: landings still queued when
@@ -826,7 +841,12 @@ class StreamingHost:
                     # rings that already contain them (at-least-once
                     # duplicates); the reverse order would resume PAST events
                     # the restored rings never saw — a hole in window history
-                    snap = self.processor.snapshot_window_state()
+                    # per-slot partial aggregates cross a slot at a
+                    # time: only the slots written since the head this
+                    # checkpointer last landed
+                    snap = self.processor.snapshot_window_state(
+                        since=self.window_checkpointer.landed_counter
+                    )
                     # armed sanitizer: a checkpoint must be REAL copies
                     # — shared memory with the live rings (or sentinel
                     # residue) is the PR 13 bug, caught before the

@@ -37,7 +37,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..compile.pipeline import Pipeline, PipelineCompiler, parse_state_table_schema
-from ..compile.planner import PlannerConfig, TableData, ViewSchema
+from ..compile.planner import (
+    WINDOW_PARTIALS_PREFIX,
+    PlannerConfig,
+    TableData,
+    ViewSchema,
+    WindowInput,
+)
 from ..compile.sqlparser import parse_select
 from ..compile.transform_parser import TransformParser
 from ..constants import ColumnName, DatasetName
@@ -49,6 +55,7 @@ from .materialize import ColumnBatch
 from .statetable import StateTable
 from .timewindow import (
     WindowBuffers,
+    WindowPartials,
     make_buffers,
     num_slots,
     update_buffers,
@@ -102,6 +109,66 @@ def projection_select(step_text: str, from_table: str):
         if ln.strip() and not ln.strip().startswith("--")
     ]
     return parse_select("SELECT " + ", ".join(items) + f" FROM {from_table}")
+
+
+def batch_constant_time(steps: List[str], ts_col: Optional[str]) -> bool:
+    """Is the projected timestamp column the ``current_timestamp()``
+    projection? Every row of a batch then carries the batch's one time,
+    so a window slot lies wholly inside or outside a window: what lets
+    the planner hold a windowed GROUP BY as per-slot partial aggregates.
+    A payload time column (or anything computed from one) is not."""
+    from ..compile.sqlparser import Col, Func, Star
+
+    col = ts_col
+    for i in reversed(range(len(steps))):
+        sel = projection_select(steps[i], "Raw")
+        found = None
+        for item in sel.items:
+            named = item.alias or (
+                item.expr.parts[-1] if isinstance(item.expr, Col) else None
+            )
+            if named == col:
+                found = item.expr
+        if isinstance(found, Func) and found.name == "CURRENT_TIMESTAMP" \
+                and not found.args:
+            return True
+        if i > 0 and isinstance(found, Col):
+            col = found.parts[-1]  # renamed from the step before
+        elif i > 0 and found is None and any(
+            isinstance(it.expr, Star) for it in sel.items
+        ):
+            pass  # carried through by a star
+        else:
+            return False
+    return False
+
+
+def window_inputs(
+    windows: Dict[str, Tuple[str, float]],
+    table_slots: Dict[str, int],
+    projections: Dict[str, List[List[str]]],
+    ts_col: Optional[str],
+    handoff_by_key: bool = False,
+) -> Dict[str, WindowInput]:
+    """What the planner is told of every TIMEWINDOW table (window name ->
+    (table, seconds)); ``projections``: per projected table, the
+    projection steps of each source that feeds it; ``handoff_by_key``:
+    the runtime ships window state to a snapshot mirror by key partition
+    (the rescale handoff re-packs rows). The ONE definition
+    the runtime and the device-plan analyzer share, so both see the
+    planner make the same choice of window state."""
+    return {
+        wname: WindowInput(
+            table=table, slots=table_slots[table],
+            duration_ms=int(dur_s * 1000), ts_col=ts_col,
+            slot_uniform_time=all(
+                batch_constant_time(steps, ts_col)
+                for steps in projections.get(table, [[]])
+            ),
+            handoff_by_key=handoff_by_key,
+        )
+        for wname, (table, dur_s) in windows.items()
+    }
 
 
 def window_target(wname: str, targets: List[str]) -> str:
@@ -301,6 +368,7 @@ def build_step_fn(
     source_targets: List[Tuple[str, str]],  # (source name, target table)
     proj_views: Dict[str, list],
     primary_target: str,
+    window_states: Optional[Dict[str, object]] = None,
 ):
     """Build the fused per-batch step function from its compiled parts.
 
@@ -313,7 +381,9 @@ def build_step_fn(
 
     def step(
         raw: Dict[str, TableData],
-        rings: Dict[str, WindowBuffers],
+        # window state, donated: a raw-row ring a windowed table, and per
+        # view in ``window_states`` its per-slot partial aggregates
+        rings: Dict[str, object],
         state: Dict[str, TableData],
         refdata: Dict[str, TableData],
         base_s: jnp.ndarray,
@@ -358,8 +428,30 @@ def build_step_fn(
         tables: Dict[str, TableData] = dict(projected)
         with jax.named_scope("dx.window"):
             for wname, (table, dur_s) in windows.items():
-                tables[wname] = window_table(
-                    new_rings[table], int(dur_s * 1000), now_rel_ms, ts_col
+                if table in new_rings:
+                    tables[wname] = window_table(
+                        new_rings[table], int(dur_s * 1000), now_rel_ms,
+                        ts_col,
+                    )
+        # windowed GROUP BYs held as per-slot partial aggregates: the
+        # batch's rows fold into its slot (``dx.window.partial``), the
+        # view reads the groups combined over the live slots
+        # (``dx.window.combine``); outside the view's own scope so a
+        # device trace tells the window's state from the view's select
+        slots_live = []
+        for vname, ws in (window_states or {}).items():
+            with jax.named_scope("dx.window.partial"):
+                slot = jax.lax.rem(counter, jnp.asarray(ws.slots, jnp.int32))
+                new_rings[vname], live_rows, dropped = ws.fold(
+                    projected[ws.table], rings[vname], slot, delta_ms,
+                    base_s, now_rel_ms, aux,
+                )
+            with jax.named_scope("dx.window.combine"):
+                tables[WINDOW_PARTIALS_PREFIX + vname] = ws.combine(
+                    new_rings[vname], live_rows, dropped
+                )
+                slots_live.append(
+                    new_rings[vname].slot_live.astype(jnp.int32).sum()
                 )
         for rname in refdata_names:
             tables[rname] = refdata[rname]
@@ -405,6 +497,8 @@ def build_step_fn(
             # per-target projected input counts (multi-source metrics)
             for _sname, target_ in source_targets:
                 counts.append(projected[target_].count())
+            # live slots of every partial-aggregate window state
+            counts.extend(slots_live)
             counts_vec = jnp.stack(
                 [jnp.asarray(c, jnp.int32) for c in counts]
             )
@@ -1015,7 +1109,7 @@ class FlowProcessor:
         ]
 
         # 2. window slots per windowed target table
-        self.ring_slots: Dict[str, int] = {}
+        table_slots: Dict[str, int] = {}
         for wname, (table, dur_s) in self.windows.items():
             if self.timestamp_column not in self.target_schemas[table].types:
                 raise EngineException(
@@ -1023,7 +1117,7 @@ class FlowProcessor:
                     f"{self.timestamp_column!r} in table {table}"
                 )
             slots = num_slots(dur_s, self.watermark_s, self.interval_s)
-            self.ring_slots[table] = max(self.ring_slots.get(table, 1), slots)
+            table_slots[table] = max(table_slots.get(table, 1), slots)
 
         # 3. main pipeline inputs
         target_caps = {s.target: s.capacity for s in self.specs.values()}
@@ -1033,8 +1127,11 @@ class FlowProcessor:
         for wname, (table, _dur) in self.windows.items():
             inputs[wname] = (
                 self.target_schemas[table],
-                self.ring_slots[table] * target_caps[table],
+                table_slots[table] * target_caps[table],
             )
+        projections: Dict[str, List[List[str]]] = {}
+        for s in self.specs.values():
+            projections.setdefault(s.target, []).append(s.projection_steps)
         for rname, (rschema, rtable) in self.refdata.items():
             inputs[rname] = (rschema, rtable.capacity)
         state_inputs = {
@@ -1042,8 +1139,23 @@ class FlowProcessor:
         }
 
         self.pipeline: Pipeline = pc.compile_transform(
-            self.transform_text, inputs, state_inputs
+            self.transform_text, inputs, state_inputs,
+            windows=window_inputs(
+                self.windows, table_slots, projections,
+                self.timestamp_column,
+                handoff_by_key=self.state_mirror is not None,
+            ),
         )
+        # the planner's choice, from the statements alone: a window every
+        # reader of which is a decomposable GROUP BY keeps per-slot
+        # partial aggregates (a state a view); any other keeps its
+        # table's raw-row ring
+        self.window_states = self.pipeline.window_states
+        self.ring_slots: Dict[str, int] = {
+            table: table_slots[table]
+            for wname, (table, _dur) in self.windows.items()
+            if wname not in self.pipeline.partial_windows
+        }
         from ..compile.stringops import AuxTableBuilder
 
         from ..compile.stringops import _MAX_ROUNDS
@@ -1087,12 +1199,7 @@ class FlowProcessor:
     def _init_device_state(self):
         # dx-race: single-threaded init/reset path — runs before the host
         # starts the landing worker (or with it quiesced on LQ reset)
-        self.window_buffers: Dict[str, WindowBuffers] = {}
-        target_caps = {s.target: s.capacity for s in self.specs.values()}
-        for table, slots in self.ring_slots.items():
-            self.window_buffers[table] = self._place_rings(make_buffers(
-                self.target_schemas[table], target_caps[table], slots
-            ))
+        self.window_buffers = self._fresh_window_state()
         # state load is the handoff-critical path of a successor
         # replica (pull owned partitions from the mirror): time it once
         # so State_Handoff_Ms reports what the rescale actually cost
@@ -1131,19 +1238,41 @@ class FlowProcessor:
         # native, under a mesh)
         self.last_decoder_path: Optional[str] = None
 
-    def _place_rings(self, buf: WindowBuffers) -> WindowBuffers:
-        """Under a mesh, lay a ring out as the step's in/out shardings
-        expect (capacity dim over the data axis) — each chip holds only
-        its shard from the start, and the first step's donation finds
-        buffers it can reuse. Single chip: unchanged."""
+    def _fresh_window_state(self) -> Dict[str, object]:
+        """Empty window state as the step takes it: a raw-row ring a
+        windowed table the planner left as rows, per-slot partial
+        aggregates a view it decomposed (keys: table names, view names)."""
+        target_caps = {s.target: s.capacity for s in self.specs.values()}
+        state: Dict[str, object] = {
+            table: make_buffers(
+                self.target_schemas[table], target_caps[table], slots
+            )
+            for table, slots in self.ring_slots.items()
+        }
+        for vname, ws in self.window_states.items():
+            state[vname] = ws.init()
+        return {n: self._place_rings(b) for n, b in state.items()}
+
+    def _place_rings(self, buf):
+        """Under a mesh, lay window state out as the step's in/out
+        shardings expect (a ring's capacity dim over the data axis,
+        partial aggregates replicated) — each chip holds its share from
+        the start, and the first step's donation finds buffers it can
+        reuse. Single chip: unchanged."""
         if self.mesh is None:
             return buf
-        from ..dist.mesh import ring_sharding
+        from ..dist.mesh import replicated, ring_sharding
 
-        sh = ring_sharding(self.mesh)
-        return WindowBuffers(
-            {c: jax.device_put(a, sh) for c, a in buf.cols.items()},
-            jax.device_put(buf.valid, sh),
+        sh = ring_sharding(self.mesh) if isinstance(buf, WindowBuffers) \
+            else replicated(self.mesh)
+        return jax.tree_util.tree_map(lambda a: jax.device_put(a, sh), buf)
+
+    def window_state_bytes(self) -> int:
+        """Device bytes of window state (rings and partial aggregates):
+        static, from the shapes."""
+        return sum(
+            int(a.size) * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(self.window_buffers)
         )
 
     def _put_rows(self, a) -> jnp.ndarray:
@@ -1164,14 +1293,23 @@ class FlowProcessor:
         self._init_device_state()
 
     # -- window-state checkpoint ------------------------------------------
-    def snapshot_window_state(self) -> Dict[str, object]:
+    def snapshot_window_state(
+        self, since: Optional[int] = None
+    ) -> Dict[str, object]:
         """Host copy of everything a restart would otherwise lose: the
-        window ring buffers, the slot counter, the time base the ring
-        timestamps are relative to, AND the string dictionary — ring
-        columns hold dictionary ids, which only mean anything against
-        the dictionary that encoded them. Numpy-only; feed to
-        ``WindowStateCheckpointer.save`` (reference restores window state
-        via the StreamingContext checkpoint, StreamingHost.scala:83-89)."""
+        window ring buffers, the per-slot partial aggregates, the slot
+        counter, the time base the slot timestamps are relative to, AND
+        the string dictionary — ring columns hold dictionary ids, which
+        only mean anything against the dictionary that encoded them.
+        Numpy-only; feed to ``WindowStateCheckpointer.save`` (reference
+        restores window state via the StreamingContext checkpoint,
+        StreamingHost.scala:83-89).
+
+        Partial aggregates are snapshotted a slot at a time: ``since``
+        is the slot counter of the checkpoint the caller already holds
+        (``WindowStateCheckpointer.landed_counter``), and only the slots
+        written since cross to the host, with the small per-view head
+        (key directory, slot times). ``None`` takes every slot."""
         # under the device-state lock: the checkpoint may run on the
         # background landing thread while the dispatch thread is about
         # to donate these very ring buffers into the next step. The
@@ -1181,21 +1319,28 @@ class FlowProcessor:
         # donates the ring (reads after that are use-after-free: heap
         # corruption, not just stale data)
         with self._device_state_lock:
-            rings = {}
-            for table, buf in self.window_buffers.items():
-                rings[table] = {
+            rings, partials = {}, {}
+            cur = self._slot_counter
+            for name, buf in self.window_buffers.items():
+                if isinstance(buf, WindowPartials):
+                    partials[name] = _snapshot_partials(buf, cur, since)
+                    continue
+                rings[name] = {
                     "cols": {
                         c: np.array(a, copy=True)
                         for c, a in buf.cols.items()
                     },
                     "valid": np.array(buf.valid, copy=True),
                 }
-            return {
+            snap = {
                 "rings": rings,
-                "slot_counter": self._slot_counter,
+                "slot_counter": cur,
                 "base_ms": self._base_ms,
                 "dictionary": self.dictionary.entries(),
             }
+            if partials:
+                snap["partials"] = partials
+            return snap
 
     def restore_window_state(self, snap: Dict[str, object]) -> bool:
         """Restore a ``snapshot_window_state`` result. Shape-checked: a
@@ -1211,8 +1356,15 @@ class FlowProcessor:
             if not self.dictionary.restore_entries(saved_dict):
                 return False
         rings = snap.get("rings", {})
-        restored: Dict[str, WindowBuffers] = {}
+        partials = snap.get("partials", {})
+        restored: Dict[str, object] = {}
         for table, buf in self.window_buffers.items():
+            if isinstance(buf, WindowPartials):
+                state = _restore_partials(buf, partials.get(table))
+                if state is None:
+                    return False
+                restored[table] = state
+                continue
             saved = rings.get(table)
             if saved is None:
                 return False
@@ -1379,6 +1531,7 @@ class FlowProcessor:
             source_targets=[(s.name, s.target) for s in self.specs.values()],
             proj_views=dict(self.projection_views),
             primary_target=self.specs[self.primary].target,
+            window_states=dict(self.window_states),
         )
         self._step_fn = step
         # donate the rings: the old buffers are dead after the step, so
@@ -1389,7 +1542,10 @@ class FlowProcessor:
         if self.mesh is not None:
             from ..dist.mesh import step_shardings
 
-            in_shardings, out_shardings = step_shardings(self.mesh)
+            in_shardings, out_shardings = step_shardings(
+                self.mesh, rings=tuple(self.ring_slots),
+                partials=tuple(self.window_states),
+            )
             self._step = jax.jit(
                 step,
                 in_shardings=in_shardings,
@@ -1916,14 +2072,9 @@ class FlowProcessor:
             # the int32 rebase would overflow — start from clean rings.
             # Published under the device-state lock so a checkpoint on
             # the landing thread never snapshots mid-swap rings.
-            target_caps = {s.target: s.capacity for s in self.specs.values()}
+            fresh = self._fresh_window_state()
             with self._device_state_lock:
-                self.window_buffers = {
-                    table: make_buffers(
-                        self.target_schemas[table], target_caps[table], slots
-                    )
-                    for table, slots in self.ring_slots.items()
-                }
+                self.window_buffers = fresh
             delta_ms = 0
         # the landing thread's checkpoint reads base/counter under this
         # lock; writes pair with it so a snapshot is never torn
@@ -2163,14 +2314,20 @@ class FlowProcessor:
 
     def placement(self) -> Dict[str, object]:
         """Where this processor's device data lives, as jax reports it:
-        how many devices hold each window ring and each source's last
-        raw batch (``sharding.device_set``; 0 = a host array the step
+        how many devices hold each windowed table's state (ring or
+        partial aggregates) and each source's last raw batch (``sharding.device_set``; 0 = a host array the step
         call transfers itself), and the allocator's bytes in use on
         every device the step runs on."""
         with self._device_state_lock:
-            rings = {
-                t: _device_count(b) for t, b in self.window_buffers.items()
-            }
+            # by windowed table: its raw-row ring, or the partial
+            # aggregates of the views over its windows (the fewest)
+            rings: Dict[str, int] = {}
+            for name, b in self.window_buffers.items():
+                ws = self.window_states.get(name)
+                table = ws.table if ws is not None else name
+                rings[table] = min(
+                    rings.get(table, _device_count(b)), _device_count(b)
+                )
         devices = self.step_devices()
         return {
             "stepDevices": len(devices),
@@ -2201,6 +2358,77 @@ class FlowProcessor:
                 for s in per_device
             ),
         }
+
+
+@jax.jit
+def _slot_row(part: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
+    """One slot's row of a [slots, groups] partial: one program a shape,
+    whichever slot (a static index would compile one a slot)."""
+    return jax.lax.dynamic_index_in_dim(part, slot, 0, keepdims=False)
+
+
+def _snapshot_partials(
+    buf: WindowPartials, counter: int, since: Optional[int]
+) -> Dict[str, object]:
+    """Host copy of one view's partial aggregates: its head, and the rows
+    of the slots written by batches ``since`` .. ``counter`` - 1 (a
+    slot's generation is the counter of the batch that wrote it; all the
+    state still holds when ``since`` is None or further back than that)."""
+    k = buf.slots
+    first = max(0, counter - k)
+    if since is not None and first <= since <= counter:
+        first = since
+    gens = range(first, counter)
+    if len(gens) == k:
+        whole = {n: np.array(a, copy=True) for n, a in buf.parts.items()}
+        parts = {n: a[[g % k for g in gens]] for n, a in whole.items()}
+    else:
+        parts = {
+            n: np.stack([
+                np.array(_slot_row(a, jnp.asarray(g % k, jnp.int32)),
+                         copy=True)
+                for g in gens
+            ]) if len(gens) else np.zeros((0, buf.groups), a.dtype)
+            for n, a in buf.parts.items()
+        }
+    return {
+        "slots": k, "groups": buf.groups,
+        "keys": [np.array(a, copy=True) for a in buf.keys],
+        "used": np.array(buf.used, copy=True),
+        "slot_ts": np.array(buf.slot_ts, copy=True),
+        "slot_live": np.array(buf.slot_live, copy=True),
+        "first_gen": first, "parts": parts,
+    }
+
+
+def _restore_partials(
+    like: WindowPartials, saved: Optional[Dict[str, object]]
+) -> Optional[WindowPartials]:
+    """A loaded checkpoint's partial aggregates (``parts`` whole, [slots,
+    groups]) as device state shaped like ``like``; None when the flow's
+    shapes have changed since. Copies: the state is the step's donated
+    argument (see ``restore_window_state``)."""
+    if saved is None:
+        return None
+    fields = [
+        *zip(like.keys, saved.get("keys", ())),
+        (like.used, saved.get("used")),
+        (like.slot_ts, saved.get("slot_ts")),
+        (like.slot_live, saved.get("slot_live")),
+        *((a, saved.get("parts", {}).get(n)) for n, a in like.parts.items()),
+    ]
+    if len(saved.get("keys", ())) != len(like.keys) \
+            or set(saved.get("parts", {})) != set(like.parts) or any(
+        got is None or got.shape != a.shape or got.dtype != a.dtype
+        for a, got in fields
+    ):
+        return None
+    put = lambda a: jnp.array(a, copy=True)  # noqa: E731
+    return WindowPartials.of(
+        tuple(put(a) for a in saved["keys"]), put(saved["used"]),
+        {n: put(a) for n, a in saved["parts"].items()},
+        put(saved["slot_ts"]), put(saved["slot_live"]),
+    )
 
 
 def _device_count(tree) -> int:
@@ -2504,6 +2732,17 @@ class PendingBatch:
         retraces = proc.drain_retraces()
         if retraces:
             metrics["Retrace_Count"] = float(retraces)
+        if proc.window_buffers:
+            # window state on the device (rings and per-slot partial
+            # aggregates, from the shapes) and, where a view keeps
+            # partials, the slots inside its window this batch (the
+            # counts vector's tail: one a view, the widest reported)
+            metrics["Window_State_Bytes"] = float(proc.window_state_bytes())
+            n_live = len(proc.window_states)
+            if n_live:
+                metrics["Window_Slots_Live"] = float(
+                    max(bc.counts[len(bc.counts) - n_live:])
+                )
         if proc.mesh is not None:
             # the chips the step's own output lies on: a mesh conf that
             # silently stepped on one chip reads 1

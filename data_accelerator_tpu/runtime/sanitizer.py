@@ -110,40 +110,52 @@ class BufferSanitizer:
         sentinel-free. Returns the number of new hits."""
         before = self.poison_hits
         rings = snap.get("rings", {}) if isinstance(snap, dict) else {}
+        partials = snap.get("partials", {}) if isinstance(snap, dict) else {}
+        # every saved array beside the live one it was copied from (None:
+        # a row of a [slots, groups] partial, copied out by the device)
+        pairs = []
         for table, saved in rings.items():
             live = window_buffers.get(table)
-            arrays = dict(saved.get("cols", {}))
-            arrays["__valid__"] = saved.get("valid")
-            for cname, a in arrays.items():
-                if a is None:
-                    continue
-                with self._lock:
-                    self.guarded_views += 1
-                run = _longest_sentinel_run(a)
-                if run >= MIN_RUN:
-                    self._record(
-                        kind="sentinel-run", where="checkpoint",
-                        table=table, column=cname, run=run,
-                    )
-                if live is None:
-                    continue
-                live_arr = (
-                    live.valid if cname == "__valid__"
-                    else live.cols.get(cname)
+            for cname, a in saved.get("cols", {}).items():
+                pairs.append((table, cname, a,
+                              live.cols.get(cname) if live else None))
+            pairs.append((table, "__valid__", saved.get("valid"),
+                          live.valid if live else None))
+        for view, saved in partials.items():
+            live = window_buffers.get(view)
+            for i, a in enumerate(saved.get("keys", ())):
+                pairs.append((view, f"key{i}", a,
+                              live.keys[i] if live else None))
+            for field_ in ("used", "slot_ts", "slot_live"):
+                pairs.append((view, field_, saved.get(field_),
+                              getattr(live, field_, None)))
+            for pname, a in saved.get("parts", {}).items():
+                pairs.append((view, pname, a,
+                              live.parts.get(pname) if live else None))
+        for table, cname, a, live_arr in pairs:
+            if a is None:
+                continue
+            with self._lock:
+                self.guarded_views += 1
+            run = _longest_sentinel_run(a)
+            if run >= MIN_RUN:
+                self._record(
+                    kind="sentinel-run", where="checkpoint",
+                    table=table, column=cname, run=run,
                 )
-                if live_arr is None:
-                    continue
-                try:
-                    # dx-race: allow-zero-copy read-only identity probe —
-                    # the view dies inside this call, nothing escapes
-                    aliased = np.shares_memory(a, np.asarray(live_arr))
-                except Exception:  # noqa: BLE001 — non-CPU backends copy
-                    aliased = False
-                if aliased:
-                    self._record(
-                        kind="snapshot-alias", where="checkpoint",
-                        table=table, column=cname, run=0,
-                    )
+            if live_arr is None:
+                continue
+            try:
+                # dx-race: allow-zero-copy read-only identity probe —
+                # the view dies inside this call, nothing escapes
+                aliased = np.shares_memory(a, np.asarray(live_arr))
+            except Exception:  # noqa: BLE001 — non-CPU backends copy
+                aliased = False
+            if aliased:
+                self._record(
+                    kind="snapshot-alias", where="checkpoint",
+                    table=table, column=cname, run=0,
+                )
         return self.poison_hits - before
 
     def scan_table(self, name: str, table) -> int:

@@ -40,9 +40,19 @@ TRANSFORM = (
 )
 
 
-def make_conf(tmp_path):
+# a statement that reads the window's rows: the planner then keeps the
+# raw-row ring (a decomposable GROUP BY alone is held as per-slot partial
+# aggregates, tests/test_window_partials.py)
+ROW_READER = (
+    "--DataXQuery--\n"
+    "Recent = SELECT deviceId, temperature FROM DataXProcessedInput_2seconds "
+    "WHERE temperature > 99\n"
+)
+
+
+def make_conf(tmp_path, transform_text=TRANSFORM):
     transform = tmp_path / "t.transform"
-    transform.write_text(TRANSFORM)
+    transform.write_text(transform_text)
     return SettingDictionary({
         "datax.job.name": "DistTest",
         "datax.job.input.default.inputtype": "local",
@@ -124,7 +134,7 @@ def test_sharded_matches_single_device(tmp_path):
 def test_sharded_input_placement(tmp_path):
     """Raw columns pre-placed with the row sharding are consumed without
     resharding; the ring state stays sharded across steps."""
-    d = make_conf(tmp_path)
+    d = make_conf(tmp_path, TRANSFORM + ROW_READER)
     mesh = make_mesh(8)
     proc = FlowProcessor(d, batch_capacity=256, mesh=mesh,
                          output_datasets=["PerDevice"])
@@ -145,7 +155,7 @@ def test_numchips_builds_the_mesh_and_places_state_and_ingest_on_it(tmp_path):
     sharded over it (not whole on device 0 until the first step), the
     encoders hand each chip its row shard, and placement() reports
     both."""
-    conf = dict(make_conf(tmp_path).dict)
+    conf = dict(make_conf(tmp_path, TRANSFORM + ROW_READER).dict)
     conf["datax.job.process.numchips"] = "4"
     proc = FlowProcessor(SettingDictionary(conf), batch_capacity=256,
                          output_datasets=["PerDevice"])

@@ -583,9 +583,14 @@ def test_every_operation_of_the_step_lies_under_a_stage_scope(home_batches):
             if "dx." not in resolve(ln[ln.rfind("loc(") + 4:-1])]
     assert not bare, bare[:5]
     scopes = set(re.findall(r"dx\.[A-Za-z]+(?:\.[A-Za-z0-9_]+)?", text))
-    assert {"dx.project.default", "dx.ring", "dx.window", "dx.view.HeatAvg",
-            "dx.view.OpenDoors", "dx.compact.OpenDoors", "dx.compact.HeatAvg",
-            "dx.counts"} <= scopes
+    # the sample's window is held as per-slot partial aggregates (its
+    # one reader is a decomposable GROUP BY): no ring, the fold and the
+    # combine under their own scopes
+    scopes |= set(re.findall(r"dx\.window\.[a-z]+", text))
+    assert {"dx.project.default", "dx.window.partial", "dx.window.combine",
+            "dx.view.HeatAvg", "dx.view.OpenDoors", "dx.compact.OpenDoors",
+            "dx.compact.HeatAvg", "dx.counts"} <= scopes
+    assert "dx.ring" not in scopes
 
 
 @pytest.fixture(scope="module")
@@ -604,14 +609,9 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def v5e_step_text(home_batches, v5e_chip):
-    """The HomeAutomation step (2,048-row width: a 12,288-row ring, 4,096
-    group slots) compiled for the v5e under the settings the host runs
-    with; the optimized program's text."""
+def _compiled_for(proc, v5e_chip) -> str:
     import jax
 
-    proc = home_batches["host"].processor
     assert jax.config.jax_traceback_in_locations_limit == 1
     assert jax.config.jax_compilation_cache_include_metadata_in_key
     avals = jax.tree_util.tree_map(
@@ -623,6 +623,43 @@ def v5e_step_text(home_batches, v5e_chip):
         return jax.jit(proc._step_fn).lower(*avals).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+@pytest.fixture(scope="module")
+def v5e_step_text(home_batches, v5e_chip):
+    """The HomeAutomation step (2,048-row width, 4,096 group slots; the
+    window held as 6 slots of partial aggregates) compiled for the v5e
+    under the settings the host runs with; the optimized program's
+    text."""
+    return _compiled_for(home_batches["host"].processor, v5e_chip)
+
+
+@pytest.fixture(scope="module")
+def raw_ring_processor(home_batches, tmp_path_factory):
+    """The same deployment with one more statement that reads the
+    window's rows: the planner then keeps the raw-row ring (a 12,288-row
+    ring at this width) and ``HeatAvg`` is the sort-based GROUP BY over
+    it."""
+    from data_accelerator_tpu.core.config import SettingDictionary
+    from data_accelerator_tpu.runtime.processor import FlowProcessor
+
+    conf = dict(home_batches["host"].processor.dict.dict)
+    with open(conf["datax.job.process.transform"], encoding="utf-8") as f:
+        text = f.read()
+    path = tmp_path_factory.mktemp("rawring") / "flow.transform"
+    path.write_text(
+        text + "--DataXQuery--\nWarmRows = SELECT deviceId, temperature "
+        "FROM DataXProcessedInput_5seconds WHERE temperature > 99\n")
+    conf["datax.job.process.transform"] = str(path)
+    proc = FlowProcessor(SettingDictionary(conf))
+    assert "DataXProcessedInput" in proc.ring_slots
+    assert not proc.window_states
+    return proc
+
+
+@pytest.fixture(scope="module")
+def v5e_raw_ring_step_text(raw_ring_processor, v5e_chip):
+    return _compiled_for(raw_ring_processor, v5e_chip)
 
 
 def test_the_tpu_compiler_keeps_the_stage_in_op_name(v5e_step_text):
@@ -640,18 +677,49 @@ def test_the_tpu_compiler_keeps_the_stage_in_op_name(v5e_step_text):
     custom = [n for n in re.findall(
         r'fusion\([^\n]*kind=kCustom[^\n]*op_name="([^"]*)"', text)]
     assert custom and all("/dx." in n for n in custom), custom[:3]
-    assert any("dx.view.HeatAvg" in n for n in custom)
+    assert any("dx.window.partial" in n for n in custom)
+
+
+def test_the_window_partials_sort_a_batch_not_the_window(
+        home_batches, v5e_step_text):
+    """The sample's window held as per-slot partial aggregates: in the
+    program the v5e runs, the largest sort is the one over the batch's
+    rows (its width, once to group them and once to pack their groups to
+    the front); the merge with the key directory and the view's key order
+    sort groups, not rows. Nothing sorts the window (slots x width) and
+    nothing under the view's own scope sorts at all."""
+    text = v5e_step_text
+    proc = home_batches["host"].processor
+    state = proc.window_states["HeatAvg"]
+    width, slots, groups = 2048, 6, 4096
+    assert (state.slots, state.groups) == (slots, groups)
+    sorts = [(int(n), ln) for ln in text.splitlines()
+             for n in re.findall(r"= \(?\w+\[(\d+)\][^=]* sort\(%", ln)[:1]]
+    in_window = [(n, ln) for n, ln in sorts
+                 if "dx.window.partial" in ln or "dx.window.combine" in ln]
+    assert len(in_window) >= 4, sorts
+    by_rows = {n for n, _ln in in_window}
+    # the batch (2,048), the directory (4,096), directory + batch groups
+    assert width in by_rows
+    assert by_rows <= {width, groups, groups + min(width, groups)}, by_rows
+    assert max(n for n, _ln in sorts) < slots * width
+    assert not [ln for _n, ln in sorts if "dx.view.HeatAvg" in ln]
+    # at the deployment's shapes (groups <= width / 2) every one of them
+    # is at most the batch's width: the 262,144 rows of a batch against
+    # 131,072 + 131,072
+    assert 131_072 + min(262_144, 131_072) <= 262_144
 
 
 def test_the_group_by_gathers_and_scatters_by_group_not_by_row(
-        home_batches, v5e_step_text):
-    """``dx.view.HeatAvg`` groups the whole ring (6 slots x the batch
-    width) into 4,096 slots. In the program the v5e runs, the ring's rows
-    go through the view's sort, and no gather or scatter under the view's
-    scope takes an index a row: the sort carries the columns, segments
-    are reduced in place and read at 4,096 (+ 1) positions."""
-    text = v5e_step_text
-    proc = home_batches["host"].processor
+        raw_ring_processor, v5e_raw_ring_step_text):
+    """Over a raw-row ring ``dx.view.HeatAvg`` groups the whole ring (6
+    slots x the batch width) into 4,096 slots. In the program the v5e
+    runs, the ring's rows go through the view's sort, and no gather or
+    scatter under the view's scope takes an index a row: the sort carries
+    the columns, segments are reduced in place and read at 4,096 (+ 1)
+    positions."""
+    text = v5e_raw_ring_step_text
+    proc = raw_ring_processor
     rows = 6 * 2048
     slots = 4096
     view = {v.name: v for v in proc.pipeline.views}["HeatAvg"]

@@ -274,7 +274,13 @@ def test_seeded_pr13_bug_caught_dynamically(tmp_path):
         if isinstance(n, ast.FunctionDef)
         and n.name == "snapshot_window_state"
     )
-    ns = {"np": np, "Dict": dict}
+    from typing import Optional
+
+    from data_accelerator_tpu.runtime import processor as processor_mod
+
+    ns = {"np": np, "Dict": dict, "Optional": Optional,
+          "WindowPartials": processor_mod.WindowPartials,
+          "_snapshot_partials": processor_mod._snapshot_partials}
     exec(  # noqa: S102 — sandboxed regression seed, sources from this repo
         compile(ast.Module(body=[fn], type_ignores=[]), "<seed>", "exec"),
         ns,
