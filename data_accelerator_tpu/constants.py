@@ -198,6 +198,12 @@ class MetricName:
         # flag, deferred template, host-side ORDER BY) took the per-row
         # fallback — both on every batch, zero included
         r"Egress_(Columnar|Fallback)_Rows",
+        # rows of the batch the native NDJSON encoder wrote for its
+        # sinks (runtime/sinks.py OutputDispatcher, from the batches'
+        # ``encoded_rows``): a columnar output's rows once for each of
+        # its file / stream sinks, 0 for an output that fell back to
+        # rows or whose sinks ask for rows; on every batch
+        r"Sink_NativeEncoded_Rows",
         # jit re-traces observed since the last collect (UDF refresh
         # rebuilds + shape/dictionary-growth cache misses); the
         # conformance monitor's DX503 input

@@ -1,8 +1,15 @@
+"""The native codec (``native/decoder.cpp``, one library): JSON lines and
+Kafka record batches into columns at ingest, a result batch's columns
+into the sinks' NDJSON at egress. ``decoder.py`` builds, loads and binds
+it."""
+
 from .decoder import (
     KAFKA_CODEC_NAMES,
     NativeBuildError,
     NativeDecoder,
+    NdjsonBuffer,
     PackedBufferPool,
+    encode_ndjson,
     load_library,
     native_available,
     native_crc32c,
@@ -13,7 +20,9 @@ __all__ = [
     "KAFKA_CODEC_NAMES",
     "NativeBuildError",
     "NativeDecoder",
+    "NdjsonBuffer",
     "PackedBufferPool",
+    "encode_ndjson",
     "load_library",
     "native_available",
     "native_crc32c",

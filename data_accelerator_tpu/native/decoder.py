@@ -1,15 +1,20 @@
-"""ctypes binding for the native JSON->columnar ingest decoder.
+"""ctypes binding for the native codec: JSON -> columns at ingest,
+columns -> NDJSON at the sinks.
 
 The C++ library (``native/decoder.cpp``) replaces the role Spark's
 executor-side ``from_json`` plays in the reference
 (CommonProcessorFactory.scala:90-103): every event's JSON parse happens
-in native code straight into numpy buffers. The shared library builds
+in native code straight into numpy buffers; and the role its
+``to_json(struct(cols))`` plays at the sinks (OutputManager.scala:103-126):
+a result batch's payload is written from its columns by the same
+library. The shared library builds
 with g++ on first use into ``native/.build/`` under a name that is a
 hash of ``decoder.cpp`` and the compiler flags, so the library loaded
 is always the one built from the source in this checkout — a stale or
 foreign ``.so`` has a different name and is never looked at. A build
 that fails raises :class:`NativeBuildError` with the compiler's stderr;
-nothing on the served path decodes in Python instead.
+nothing on the served path decodes, or encodes a columnar batch, in
+Python instead.
 
 Three decode surfaces:
 
@@ -29,6 +34,16 @@ Three decode surfaces:
   the same JSON column decoder in the same call — the production wire
   format never touches a Python object per record.
 
+One encode surface:
+
+- ``encode_ndjson``: a result batch's rendered columns (int64, float64,
+  bool, or a string column as its distinct strings plus an index a row)
+  -> the sinks' newline-JSON payload, byte for byte
+  ``json.dumps(row) + "\\n"`` a row, into an :class:`NdjsonBuffer` its
+  caller keeps from one write to the next. No Python object a row or a
+  value, and the interpreter lock is released for the call, so the
+  dispatcher's per-output threads overlap.
+
 The decoder owns a string dictionary (string -> int32) kept consistent
 with the Python ``StringDictionary`` by push-before/pull-after syncs
 around each decode call; both sides assign ids sequentially so ids
@@ -44,6 +59,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import json
 import logging
 import os
 import subprocess
@@ -188,6 +204,14 @@ def load_library():
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
         ]
+        lib.dx_encode_ndjson.restype = ctypes.c_int64
+        lib.dx_encode_ndjson.argtypes = [
+            ctypes.c_int64, ctypes.c_int32, ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_void_p), ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_char_p),
+            ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_void_p, ctypes.c_int64,
+        ]
         lib.dx_bad_timestamps.restype = ctypes.c_int64
         lib.dx_bad_timestamps.argtypes = [ctypes.c_void_p]
         lib.dx_dict_size.restype = ctypes.c_int64
@@ -245,6 +269,123 @@ def scan_lines(
         ctypes.byref(cut), ctypes.byref(blank),
     )
     return int(lines), start + int(cut.value), int(blank.value)
+
+
+class NdjsonBuffer:
+    """The bytes ``encode_ndjson`` writes a payload into, kept by
+    whoever writes payloads one after another (a sink) so that a batch
+    neither allocates nor first-touches megabytes. Grown when a payload
+    can need more, with a quarter's headroom so that a batch a few rows
+    larger than the last does not grow it again. Not locked: its holder
+    lets one thread at a time encode into it and finishes with the
+    payload before the next encode."""
+
+    def __init__(self):
+        self._bytes = np.empty(0, dtype=np.uint8)
+        self.grow_count = 0
+
+    def reserve(self, n: int) -> np.ndarray:
+        if n > len(self._bytes):
+            self._bytes = np.empty(n + n // 4, dtype=np.uint8)
+            self.grow_count += 1
+        return self._bytes
+
+
+# dx_encode_ndjson's column kinds (decoder.cpp EncKind), by the dtype
+# a rendered column has
+_ENC_KIND = {np.dtype(np.int64): 0, np.dtype(np.float64): 1,
+             np.dtype(np.bool_): 2}
+_ENC_STRING = 3
+
+
+def _offsets(parts: Sequence[bytes]):
+    """``parts`` back to back, and the len(parts) + 1 offsets."""
+    off = np.zeros(len(parts) + 1, dtype=np.int64)
+    np.cumsum([len(p) for p in parts], out=off[1:])
+    return b"".join(parts), off
+
+
+def encode_ndjson(
+    n_rows: int,
+    fields: Sequence[Tuple[str, object]],
+    out: Optional[NdjsonBuffer] = None,
+) -> memoryview:
+    """The NDJSON payload of ``n_rows`` rows from their columns: byte
+    for byte ``json.dumps(row) + "\\n"`` a row, where a row is the dict of
+    ``fields`` in their order.
+
+    ``fields``: (name, column) pairs; a column is an int64, float64 or
+    bool array of ``n_rows``, or for strings a pair (the distinct
+    values, each a ``str`` or None; an int64 array of ``n_rows``
+    indices into them). Names and distinct strings are spelled by
+    ``json.dumps`` here, so escaping stays Python's; digits are the
+    library's (``float.__repr__``'s for a double, ``NaN`` / ``Infinity``
+    as ``json.dumps`` writes them).
+
+    The result is a view of ``out``'s bytes (a buffer of its own when
+    none is given), valid until the next encode into ``out``."""
+    if n_rows <= 0 or not fields:
+        raise ValueError(f"encode_ndjson of {n_rows} rows x {len(fields)}")
+    lib = load_library()
+    n_cols = len(fields)
+    kinds = (ctypes.c_int32 * n_cols)()
+    cols = (ctypes.c_void_p * n_cols)()
+    str_bytes = (ctypes.c_char_p * n_cols)()
+    str_offsets = (ctypes.c_void_p * n_cols)()
+    str_counts = (ctypes.c_int64 * n_cols)()
+    prefixes = []
+    keep = []  # every buffer the call reads, alive until it returns
+    for c, (name, col) in enumerate(fields):
+        prefixes.append(
+            (("{" if c == 0 else ", ") + json.dumps(name) + ": ").encode()
+        )
+        strings = None
+        if isinstance(col, tuple):
+            strings, col = col
+        if col.shape != (n_rows,):
+            raise ValueError(f"column {name!r}: shape {col.shape}")
+        if strings is None:
+            kind = _ENC_KIND.get(col.dtype)
+            if kind is None:
+                raise ValueError(f"column {name!r}: dtype {col.dtype}")
+            kinds[c] = kind
+        else:
+            if col.dtype != np.int64 or not (
+                0 <= int(col.min()) and int(col.max()) < len(strings)
+            ):
+                raise ValueError(
+                    f"column {name!r}: a string index of {col.dtype} "
+                    f"into {len(strings)} strings, or out of their range"
+                )
+            blob, off = _offsets([json.dumps(s).encode() for s in strings])
+            keep.append(off)
+            str_bytes[c] = blob
+            str_offsets[c] = off.ctypes.data
+            str_counts[c] = len(strings)
+            kinds[c] = _ENC_STRING
+        col = np.ascontiguousarray(col)
+        keep.append(col)
+        cols[c] = col.ctypes.data
+    prefix_blob, prefix_off = _offsets(prefixes)
+    prefix_off_p = prefix_off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
+
+    def encode_into(target: np.ndarray) -> int:
+        return lib.dx_encode_ndjson(
+            n_rows, n_cols, kinds, cols, prefix_blob, prefix_off_p,
+            str_bytes, str_offsets, str_counts,
+            target.ctypes.data, len(target),
+        )
+
+    buffer = out if out is not None else NdjsonBuffer()
+    target = buffer.reserve(0)
+    wrote = encode_into(target)
+    if wrote < 0:
+        # minus the most these rows can take, by the library's own bound
+        target = buffer.reserve(-wrote)
+        wrote = encode_into(target)
+    if wrote <= 0:
+        raise RuntimeError(f"dx_encode_ndjson returned {wrote}")
+    return memoryview(target)[:wrote]
 
 
 def _decode_threads(conf_threads: Optional[int] = None) -> int:

@@ -141,6 +141,18 @@ def span(name: str, **props) -> Iterator[None]:
         yield
 
 
+def add(**props) -> None:
+    """Attach properties to the innermost span open on THIS thread (a
+    callee that learns a number its caller's span should carry: the
+    bytes of a sink write); no-op outside any span."""
+    stack = getattr(_local, "stack", None)
+    if stack:
+        ctx, span_id = stack[-1]
+        open_props = ctx._open.get(span_id)
+        if open_props is not None:
+            open_props.update(props)
+
+
 class TraceContext:
     """One batch's trace: a root span plus explicitly-parented children.
 
@@ -185,6 +197,8 @@ class TraceContext:
         # event (the poll's Source_Backlog_Rows): the trace is what
         # travels with a batch from its poll to its tail
         self.counters: Dict[str, float] = {}
+        # properties of the child spans still open, by span id (``add``)
+        self._open: Dict[str, Dict] = {}
 
     # -- root ------------------------------------------------------------
     def add(self, **props) -> None:
@@ -264,6 +278,7 @@ class TraceContext:
             # nest further children under this span on the same thread
             stack.append((self, span_id))
             pushed = True
+        self._open[span_id] = props
         try:
             with annotation(
                 name, batch=self._props.get("batchTime", self.trace_id)
@@ -272,6 +287,7 @@ class TraceContext:
         finally:
             if pushed:
                 stack.pop()
+            del self._open[span_id]
             self.tracer._emit_span(
                 self, name, span_id, parent_id, start_ts,
                 (time.perf_counter() - t0) * 1000.0, props,

@@ -8,12 +8,12 @@ producing the same row JSON the reference's sinks serialize
 
 What crosses the boundary is a ``ColumnBatch``: one output's valid rows
 of one batch, kept as columns. A schema of flat scalar columns is
-rendered column by column (numpy), and encodes itself to the sinks'
-NDJSON without ever building a dict; any other schema (a dotted name, a
-``.__valid`` flag, a deferred template, a host-side ORDER BY) goes
-through ``materialize_rows`` row by row, behind the same type. Either
-way the batch is a read-only ``Sequence[dict]`` for the sinks that want
-rows.
+rendered column by column (numpy), and the native encoder writes the
+sinks' NDJSON from those columns without a Python object a row; any
+other schema (a dotted name, a ``.__valid`` flag, a deferred template,
+a host-side ORDER BY) goes through ``materialize_rows`` row by row,
+behind the same type. Either way the batch is a read-only
+``Sequence[dict]`` for the sinks that want rows.
 """
 
 from __future__ import annotations
@@ -27,6 +27,7 @@ import numpy as np
 from ..compile.exprs import WS_MARKER
 from ..compile.planner import TableData, ViewSchema
 from ..core.schema import StringDictionary
+from ..native import NdjsonBuffer, encode_ndjson
 
 
 def _render_value(v, t: str, dictionary: StringDictionary, base_ms: int):
@@ -160,17 +161,6 @@ def _is_flat(schema: ViewSchema) -> bool:
     )
 
 
-def _json_float_strings(col: np.ndarray) -> List[str]:
-    """``json.dumps`` spellings of a float64 column that holds a
-    non-finite value: ``float.__repr__`` digits, and ``NaN`` /
-    ``Infinity`` / ``-Infinity`` where repr says nan / inf / -inf."""
-    out = list(map(float.__repr__, col.tolist()))
-    for i in np.nonzero(~np.isfinite(col))[0].tolist():
-        v = col[i]
-        out[i] = "NaN" if v != v else ("Infinity" if v > 0 else "-Infinity")
-    return out
-
-
 class ColumnBatch(Sequence):
     """One output's valid rows of one batch: what ``collect_tables``
     hands to ``Sink.write``.
@@ -179,10 +169,11 @@ class ColumnBatch(Sequence):
     rows' columns are rendered once, as numpy columns (validity mask
     once; ``timestamp`` / ``tssec`` widened to int64 before the batch
     base is added; float32 -> float64; string ids decoded once per
-    distinct id); ``ndjson()`` formats the sinks' payload straight from
-    them, and the row dicts exist only if someone asks. Otherwise the
-    rows are built by ``materialize_rows`` at construction (then sorted
-    and cut by ``finish``, the view's host-side ORDER BY / LIMIT).
+    distinct id); ``ndjson()`` has the native encoder write the sinks'
+    payload straight from them, and the row dicts exist only if someone
+    asks. Otherwise the rows are built by ``materialize_rows`` at
+    construction (then sorted and cut by ``finish``, the view's
+    host-side ORDER BY / LIMIT).
 
     As a sequence (``len``, iteration, indexing, slicing, ``==`` with a
     list) it is the very ``List[dict]`` ``materialize_rows`` gives,
@@ -200,6 +191,9 @@ class ColumnBatch(Sequence):
     ):
         self.schema = schema
         self._rows: Optional[List[dict]] = None
+        # rows the native encoder has written from this batch, over all
+        # its sinks (Sink_NativeEncoded_Rows)
+        self.encoded_rows = 0
         # (name, type, rendered column); a string column is
         # (distinct strings, index of each row's string among them)
         self._columns: List[Tuple[str, str, object]] = []
@@ -232,7 +226,7 @@ class ColumnBatch(Sequence):
                 ids, where = np.unique(col, return_inverse=True)
                 col = (
                     [dictionary.decode(i) for i in ids.tolist()],
-                    where.reshape(-1),
+                    where.reshape(-1).astype(np.int64, copy=False),
                 )
             elif t == "timestamp":
                 col = col.astype(np.int64) + base_ms
@@ -287,40 +281,27 @@ class ColumnBatch(Sequence):
             self._rows = [dict(zip(names, r)) for r in zip(*values)]
         return self._rows
 
-    def ndjson(self) -> str:
+    def ndjson(self, out: Optional[NdjsonBuffer] = None):
         """One JSON object a row, one row a line: byte for byte
-        ``json.dumps(row, default=str) + "\n"`` over ``rows()``."""
+        ``json.dumps(row, default=str) + "\n"`` over ``rows()``, as
+        bytes. A columnar batch's are written by the native encoder
+        straight from the columns (``encoded_rows`` counts them), into
+        ``out`` when its caller keeps one: the payload is then a view
+        of it, valid until that buffer's next use."""
         if not self._len:
-            return ""
+            return b""
         if not self.columnar:
             return ndjson(self._rows)
-        fields, values = [], []
-        for name, t, col in self._columns:
-            spec = "%d"
-            if t == "string":
-                strings, where = col
-                spec = "%s"
-                col = np.array(
-                    [json.dumps(s) for s in strings], dtype=object
-                )[where]
-            elif t == "boolean":
-                spec = "%s"
-                col = np.where(col, "true", "false")
-            elif t == "double":
-                if np.isfinite(col).all():
-                    spec = "%r"
-                else:
-                    spec = "%s"
-                    col = _json_float_strings(col)
-            fields.append(json.dumps(name).replace("%", "%%") + ": " + spec)
-            values.append(col if isinstance(col, list) else col.tolist())
-        line = "{" + ", ".join(fields) + "}\n"
-        return "".join(map(line.__mod__, zip(*values)))
+        self.encoded_rows += self._len
+        return encode_ndjson(
+            self._len, [(name, col) for name, _t, col in self._columns], out
+        )
 
 
-def ndjson(rows: Union[ColumnBatch, Sequence]) -> str:
-    """The NDJSON payload of a sink write: from the columns when
-    ``rows`` is a batch, row by row for a plain list of dicts."""
+def ndjson(rows: Union[ColumnBatch, Sequence], out=None):
+    """The NDJSON payload of a sink write, as bytes: from the columns
+    when ``rows`` is a batch (into ``out``, see ``ColumnBatch.ndjson``),
+    row by row for a plain list of dicts."""
     if isinstance(rows, ColumnBatch):
-        return rows.ndjson()
-    return "".join(json.dumps(r, default=str) + "\n" for r in rows)
+        return rows.ndjson(out)
+    return "".join(json.dumps(r, default=str) + "\n" for r in rows).encode()

@@ -16,10 +16,11 @@ Sinks receive each dataset's rows as a ``ColumnBatch``
 processor landed once per batch, off the jitted path, and that keeps
 flat outputs as columns. A sink that wants rows iterates, indexes or
 slices it like the ``List[dict]`` it used to get (a plain list is still
-accepted); a sink that writes NDJSON asks ``ndjson(rows)`` and gets the
-payload straight from the columns, with no dict and no ``json.dumps`` a
-row; a sink that hands the whole batch to a serializer takes
-``list(rows)`` first.
+accepted); a sink that writes NDJSON asks ``ndjson(rows, buffer)`` and
+gets the payload as bytes, written from the columns by the native
+encoder into the buffer the sink keeps, with no dict, no ``json.dumps``
+and no ``str`` a row; a sink that hands the whole batch to a serializer
+takes ``list(rows)`` first.
 """
 
 from __future__ import annotations
@@ -37,8 +38,9 @@ from ..core.config import SettingDictionary
 from ..obs import tracing
 from ..obs.metrics import MetricLogger
 from ..constants import MetricName
+from ..native import NdjsonBuffer, load_library
 from ..utils import fs
-from .materialize import ndjson
+from .materialize import ColumnBatch, ndjson
 
 logger = logging.getLogger(__name__)
 
@@ -50,6 +52,27 @@ class Sink:
         self, dataset: str, rows: Sequence[dict], batch_time_ms: int
     ) -> int:
         raise NotImplementedError
+
+
+class _NdjsonWriter:
+    """What the sinks that write NDJSON share: the buffer their
+    payloads are encoded into, one write after another, and the lock
+    that keeps two outputs routed to one sink out of it. The native
+    library is loaded here, when the sink is built, so that no batch's
+    ``sinks`` span holds a compiler run."""
+
+    def __init__(self):
+        load_library()
+        self._buffer = NdjsonBuffer()
+        self._lock = threading.Lock()
+
+    def _encode(self, rows):
+        """The payload (hold ``_lock`` until done with it), under a
+        ``sink/encode`` span; its size goes on the ``sink/<kind>`` span."""
+        with tracing.span("sink/encode"):
+            payload = ndjson(rows, self._buffer)
+        tracing.add(bytes=len(payload))
+        return payload
 
 
 class ConsoleSink(Sink):
@@ -78,7 +101,7 @@ def partition_folder(base: str, batch_time_ms: int) -> str:
     )
 
 
-class FileSink(Sink):
+class FileSink(_NdjsonWriter, Sink):
     """JSON(.gz) writer into time-partitioned folders (blob sink analog).
 
     Writes temp + rename for atomicity (HadoopClient.scala:391-441)."""
@@ -86,6 +109,7 @@ class FileSink(Sink):
     kind = "file"
 
     def __init__(self, folder: str, compression: str = "none"):
+        super().__init__()
         self.folder = folder
         self.compression = compression
         self._counter = 0
@@ -94,11 +118,11 @@ class FileSink(Sink):
         if not rows:
             return 0
         out_dir = partition_folder(self.folder, batch_time_ms)
-        self._counter += 1
         ext = ".json.gz" if self.compression == "gzip" else ".json"
-        name = f"{dataset}_{batch_time_ms}_{self._counter}{ext}"
-        path = os.path.join(out_dir, name)
-        fs.write_text(path, ndjson(rows))
+        with self._lock:
+            self._counter += 1
+            name = f"{dataset}_{batch_time_ms}_{self._counter}{ext}"
+            fs.write_bytes(os.path.join(out_dir, name), self._encode(rows))
         return len(rows)
 
 
@@ -312,7 +336,7 @@ class DocumentSink(Sink):
         return len(rows)
 
 
-class StreamSink(Sink):
+class StreamSink(_NdjsonWriter, Sink):
     """Event-stream sink: newline-delimited JSON over TCP.
 
     reference: sink/EventHubStreamPoster.scala:15-81 — per-row JSON
@@ -326,9 +350,9 @@ class StreamSink(Sink):
     kind = "eventhub"
 
     def __init__(self, host: str, port: int):
+        super().__init__()
         self.addr = (host, port)
         self._sock = None
-        self._lock = threading.Lock()
 
     def _connect(self):
         import socket as _socket
@@ -339,8 +363,8 @@ class StreamSink(Sink):
     def write(self, dataset, rows, batch_time_ms) -> int:
         if not rows:
             return 0
-        payload = ndjson(rows).encode()
         with self._lock:
+            payload = self._encode(rows)
             try:
                 if self._sock is None:
                     self._sock = self._connect()
@@ -609,6 +633,16 @@ class OutputDispatcher:
             raise errors[0]
         for metric, count in results.items():
             self.metric_logger.send_metric(metric, count, batch_time_ms)
+        # rows the native encoder wrote for this batch's sinks, on the
+        # batch's end event beside Egress_Columnar_Rows (which counts
+        # rows handed over as columns, encoded or not: a sink that asks
+        # for rows encodes none)
+        trace = tracing.current_trace()
+        if trace is not None:
+            trace.counters["Sink_NativeEncoded_Rows"] = float(sum(
+                rows.encoded_rows for rows in datasets.values()
+                if isinstance(rows, ColumnBatch)
+            ))
         return results
 
     def close(self) -> None:

@@ -62,14 +62,15 @@ def read_lines(path: str) -> List[str]:
     return read_text(path).splitlines()
 
 
-def write_text(
+def write_bytes(
     path: str,
-    content: str,
+    content,
     atomic: bool = True,
     abort: Optional[threading.Event] = None,
 ) -> None:
-    """Write text, gzip-aware; atomic temp+rename by default
-    (HadoopClient.scala:391-441 writeFile via temp + rename).
+    """Write bytes (anything with the buffer protocol), gzip-aware;
+    atomic temp+rename by default (HadoopClient.scala:391-441 writeFile
+    via temp + rename).
 
     The temp name is unique per call so concurrent writers (e.g. a
     timed-out attempt still running alongside its retry) never share a
@@ -82,12 +83,8 @@ def write_text(
         f"{path}.tmp.{os.getpid()}.{next(_TMP_COUNTER)}" if atomic else path
     )
     try:
-        if is_gzip(path):
-            with gzip.open(target, "wt", encoding="utf-8") as f:
-                f.write(content)
-        else:
-            with open(target, "w", encoding="utf-8") as f:
-                f.write(content)
+        with (gzip.open if is_gzip(path) else open)(target, "wb") as f:
+            f.write(content)
         if atomic:
             if abort is not None and abort.is_set():
                 raise InterruptedError(f"write of {path} superseded")
@@ -98,6 +95,16 @@ def write_text(
                 os.remove(target)
             except OSError:
                 pass
+
+
+def write_text(
+    path: str,
+    content: str,
+    atomic: bool = True,
+    abort: Optional[threading.Event] = None,
+) -> None:
+    """``write_bytes`` of the text's UTF-8."""
+    write_bytes(path, content.encode("utf-8"), atomic=atomic, abort=abort)
 
 
 def write_with_timeout_and_retries(

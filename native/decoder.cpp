@@ -1,6 +1,7 @@
-// High-throughput event decoder: newline-delimited JSON (and native
-// Kafka v2 record batches) -> typed columnar buffers, the TPU
-// framework's ingest hot path.
+// The native codec. High-throughput event decoder: newline-delimited
+// JSON (and native Kafka v2 record batches) -> typed columnar buffers,
+// the TPU framework's ingest hot path; and the encoder of the way back
+// out: a result batch's columns -> the sinks' newline-delimited JSON.
 //
 // Role in the reference: the EventHub/Kafka receivers deserialize AMQP
 // payloads and Spark's from_json does the per-event parse on executors
@@ -34,6 +35,11 @@
 //    string misses intern thread-locally against the frozen shared
 //    dictionary, and a serial merge assigns global ids (the
 //    single-writer step is O(new distinct strings), not O(rows));
+//  - the way back out (dx_encode_ndjson): the sinks' newline-JSON
+//    payload written from a result batch's columns into the caller's
+//    reused byte buffer, byte for byte what Python's json.dumps writes
+//    a row (digits by std::to_chars, doubles laid out by
+//    float.__repr__'s rule); no Python object per row or value;
 //  - Kafka fast path (dx_decode_kafka_packed): walks message-format-v2
 //    record batches directly — varint record framing, per-batch
 //    CRC-32C verification (corrupt batches skip + count instead of
@@ -44,6 +50,7 @@
 //
 // C ABI for ctypes; no external dependencies.
 
+#include <charconv>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -1009,6 +1016,122 @@ int64_t decode_values_range(Decoder* d, OutBufs* out, DictSink* sink,
   return rows;
 }
 
+// ---- NDJSON encoder --------------------------------------------------
+// One result column as the encoder is handed it (runtime/materialize.py
+// ColumnBatch, rendered: the batch base added, float32 widened).
+enum EncKind : int32_t { E_INT = 0, E_DOUBLE = 1, E_BOOL = 2, E_STR = 3 };
+
+struct EncCol {
+  int32_t kind;
+  const void* data;        // int64 / double / uint8 a row; E_STR: int64
+                           // index a row into the distinct strings
+  const char* prefix;      // `{"name": ` or `, "name": `
+  int64_t prefix_len;
+  const char* strs;        // E_STR: the distinct strings as json.dumps
+  const int64_t* str_off;  // spells them, back to back; n + 1 offsets
+  int64_t width;           // the widest spelling of a value
+};
+
+constexpr int64_t kIntWidth = 20;     // -9223372036854775808
+constexpr int64_t kDoubleWidth = 24;  // -2.2250738585072014e-308
+constexpr int64_t kBoolWidth = 5;     // false
+
+// json.dumps of a double: float.__repr__ (the shortest digits that
+// round-trip; exponent form `d.ddde-XX` when the decimal exponent is
+// under -4 or over 15, else positional, `.0` after an integer value),
+// and NaN / Infinity / -Infinity.
+inline char* put_double(char* p, double v) {
+  if (v != v) {
+    memcpy(p, "NaN", 3);
+    return p + 3;
+  }
+  if (v - v != 0.0) {  // an infinity
+    if (v < 0) *p++ = '-';
+    memcpy(p, "Infinity", 8);
+    return p + 8;
+  }
+  // [-]d[.ddd]e[+-]XX[X]: to_chars' scientific form is repr's own
+  char tmp[32];
+  const char* s = tmp;
+  const char* end =
+      std::to_chars(tmp, tmp + sizeof tmp, v, std::chars_format::scientific)
+          .ptr;
+  if (*s == '-') *p++ = *s++;
+  const char* e = end - 3;  // the exponent has two or three digits
+  while (*e != 'e') --e;
+  int exp10 = 0;
+  for (const char* q = e + 2; q < end; ++q) exp10 = exp10 * 10 + (*q - '0');
+  if (e[1] == '-') exp10 = -exp10;
+  if (exp10 < -4 || exp10 > 15) {
+    memcpy(p, s, (size_t)(end - s));
+    return p + (end - s);
+  }
+  const char* rest = s + 2;  // the digits after the first
+  int n_rest = e - s > 1 ? (int)(e - rest) : 0;
+  if (exp10 < 0) {  // 0.000ddd
+    *p++ = '0';
+    *p++ = '.';
+    for (int z = -exp10 - 1; z > 0; --z) *p++ = '0';
+    *p++ = *s;
+    memcpy(p, rest, (size_t)n_rest);
+    return p + n_rest;
+  }
+  *p++ = *s;
+  if (exp10 >= n_rest) {  // ddd000.0
+    memcpy(p, rest, (size_t)n_rest);
+    p += n_rest;
+    for (int z = exp10 - n_rest; z > 0; --z) *p++ = '0';
+    *p++ = '.';
+    *p++ = '0';
+    return p;
+  }
+  memcpy(p, rest, (size_t)exp10);  // ddd.ddd
+  p += exp10;
+  *p++ = '.';
+  memcpy(p, rest + exp10, (size_t)(n_rest - exp10));
+  return p + (n_rest - exp10);
+}
+
+// The columns' n_rows rows as one JSON object a line at p; returns the
+// end. A row takes at most sum(prefix_len + width) + 2 bytes.
+char* encode_rows(const EncCol* cols, int32_t n_cols, int64_t n_rows,
+                  char* p) {
+  for (int64_t i = 0; i < n_rows; ++i) {
+    for (int32_t c = 0; c < n_cols; ++c) {
+      const EncCol& col = cols[c];
+      memcpy(p, col.prefix, (size_t)col.prefix_len);
+      p += col.prefix_len;
+      switch (col.kind) {
+        case E_INT:
+          p = std::to_chars(p, p + kIntWidth,
+                            static_cast<const int64_t*>(col.data)[i]).ptr;
+          break;
+        case E_DOUBLE:
+          p = put_double(p, static_cast<const double*>(col.data)[i]);
+          break;
+        case E_BOOL:
+          if (static_cast<const uint8_t*>(col.data)[i]) {
+            memcpy(p, "true", 4);
+            p += 4;
+          } else {
+            memcpy(p, "false", 5);
+            p += 5;
+          }
+          break;
+        default: {
+          int64_t k = static_cast<const int64_t*>(col.data)[i];
+          int64_t n = col.str_off[k + 1] - col.str_off[k];
+          memcpy(p, col.strs + col.str_off[k], (size_t)n);
+          p += n;
+        }
+      }
+    }
+    *p++ = '}';
+    *p++ = '\n';
+  }
+  return p;
+}
+
 }  // namespace
 
 extern "C" {
@@ -1243,6 +1366,56 @@ int64_t dx_scan_lines(const char* buf, int64_t len, int64_t max_lines,
   *cut = p - buf;
   *blank = blanks;
   return lines;
+}
+
+// The sinks' NDJSON payload of n_rows rows, straight from the columns
+// (kinds[c]: EncKind; cols[c]: the column's buffer; prefixes with
+// n_cols + 1 offsets: `{"name": ` / `, "name": ` as json.dumps spells
+// the names; a string column's distinct strings, spelled by json.dumps,
+// in str_bytes[c] with str_counts[c] + 1 offsets in str_offsets[c] and
+// its rows' indices into them in cols[c]). Byte for byte
+// json.dumps(row) + "\n" a row. Returns the bytes written into out; or,
+// when out_cap is under the most these rows can take, minus that many
+// bytes, with nothing written (the caller grows its buffer and asks
+// again). Holds no lock and no state: concurrent calls need only
+// buffers of their own.
+int64_t dx_encode_ndjson(int64_t n_rows, int32_t n_cols, const int32_t* kinds,
+                         const void* const* cols, const char* prefixes,
+                         const int64_t* prefix_offsets,
+                         const char* const* str_bytes,
+                         const int64_t* const* str_offsets,
+                         const int64_t* str_counts, char* out,
+                         int64_t out_cap) {
+  std::vector<EncCol> enc((size_t)n_cols);
+  int64_t row_bound = 2;  // "}\n"
+  for (int32_t c = 0; c < n_cols; ++c) {
+    EncCol& col = enc[(size_t)c];
+    col.kind = kinds[c];
+    col.data = cols[c];
+    col.prefix = prefixes + prefix_offsets[c];
+    col.prefix_len = prefix_offsets[c + 1] - prefix_offsets[c];
+    col.strs = nullptr;
+    col.str_off = nullptr;
+    switch (col.kind) {
+      case E_INT: col.width = kIntWidth; break;
+      case E_DOUBLE: col.width = kDoubleWidth; break;
+      case E_BOOL: col.width = kBoolWidth; break;
+      case E_STR:
+        col.strs = str_bytes[c];
+        col.str_off = str_offsets[c];
+        col.width = 0;
+        for (int64_t k = 0; k < str_counts[c]; ++k) {
+          int64_t n = col.str_off[k + 1] - col.str_off[k];
+          if (n > col.width) col.width = n;
+        }
+        break;
+      default:
+        return 0;
+    }
+    row_bound += col.prefix_len + col.width;
+  }
+  if (n_rows * row_bound > out_cap) return -(n_rows * row_bound);
+  return encode_rows(enc.data(), n_cols, n_rows, out) - out;
 }
 
 // Rows dropped by the last decode because a string timestamp was

@@ -1,7 +1,8 @@
 """Columnar egress: ``collect_tables()`` hands each output to the sinks
 as a ``ColumnBatch`` (runtime/materialize.py).
 
-- a flat schema stays columns and its NDJSON is byte for byte what
+- a flat schema stays columns and its NDJSON, written by the native
+  encoder from those columns, is byte for byte what
   ``json.dumps(row, default=str)`` a row gives over ``materialize_rows``
   (the per-row code, unchanged, is the reference in every case);
 - any other schema (nested struct, array ``.__valid``, CONCAT,
@@ -27,6 +28,7 @@ from data_accelerator_tpu.core.config import SettingDictionary
 from data_accelerator_tpu.core.schema import StringDictionary
 from data_accelerator_tpu.obs.metrics import MetricLogger
 from data_accelerator_tpu.obs.store import MetricStore
+from data_accelerator_tpu.obs.tracing import Tracer
 from data_accelerator_tpu.runtime.materialize import (
     ColumnBatch,
     materialize_rows,
@@ -41,6 +43,7 @@ from data_accelerator_tpu.runtime.sinks import (
     HttpPostSink,
     KafkaSink,
     MetricSink,
+    OutputDispatcher,
     OutputOperator,
     SqlSink,
     StreamSink,
@@ -52,7 +55,9 @@ BASE_MS = 1_790_000_000_123  # far above 2**31: int32 + base must widen
 
 def per_row_payload(rows):
     """The file sink's payload before this batch type existed."""
-    return "\n".join(json.dumps(r, default=str) for r in rows) + "\n"
+    return (
+        "\n".join(json.dumps(r, default=str) for r in rows) + "\n"
+    ).encode()
 
 
 def _dictionary(strings):
@@ -162,7 +167,7 @@ def test_flat_schema_ndjson_is_the_per_row_payload(name):
     assert batch._rows is None
     payload = batch.ndjson()
     assert batch._rows is None, "the encoder built a dict"
-    assert payload == (per_row_payload(reference) if reference else "")
+    assert payload == (per_row_payload(reference) if reference else b"")
     assert ndjson(batch) == payload
     # ... and the other view of the same batch: the very rows (compared
     # as text, since a NaN is not equal to itself)
@@ -182,7 +187,7 @@ def test_max_rows_cuts_like_the_per_row_code(max_rows):
     batch = ColumnBatch(table, schema, dictionary, BASE_MS, max_rows=max_rows)
     assert batch.columnar and len(batch) == len(reference)
     assert json.dumps(batch.rows()) == json.dumps(reference)
-    assert batch.ndjson() == (per_row_payload(reference) if reference else "")
+    assert batch.ndjson() == (per_row_payload(reference) if reference else b"")
 
 
 def test_batch_is_a_read_only_sequence_of_the_rows():
@@ -201,7 +206,7 @@ def test_batch_is_a_read_only_sequence_of_the_rows():
         batch[0] = {}
     empty = ColumnBatch(*_flat_case("empty_table"))
     assert len(empty) == 0 and not empty and list(empty) == []
-    assert empty.ndjson() == "" and ndjson([]) == ""
+    assert empty.ndjson() == b"" and ndjson([]) == b""
 
 
 def test_ndjson_of_a_plain_list_is_the_per_row_payload():
@@ -364,10 +369,26 @@ def test_flow_rows_are_the_parents_and_the_counters_name_the_path(
     assert batch.columnar is columnar
     assert batch.rows() == golden
     assert [list(r) for r in batch] == [list(r) for r in golden]  # key order
-    assert batch.ndjson() == per_row_payload(golden)
     n = float(len(golden))
     assert metrics["Egress_Columnar_Rows"] == (n if columnar else 0.0)
     assert metrics["Egress_Fallback_Rows"] == (0.0 if columnar else n)
+    # ... and through a file sink under the batch's trace: the native
+    # encoder wrote the columnar rows and none of the fallback's
+    dispatcher = OutputDispatcher(
+        {"Out": OutputOperator("Out", [FileSink(str(tmp_path / "o"), "none")])},
+        MetricLogger("DATAX-Egress", store=MetricStore()),
+    )
+    trace = Tracer().begin()
+    try:
+        with trace.activate():
+            dispatcher.dispatch(datasets, 1000)
+    finally:
+        dispatcher.close()
+    assert trace.counters["Sink_NativeEncoded_Rows"] == \
+        metrics["Egress_Columnar_Rows"]
+    (path,) = [p for p in (tmp_path / "o").rglob("Out_*") if p.is_file()]
+    assert path.read_bytes() == per_row_payload(golden)
+    assert batch.ndjson() == per_row_payload(golden)
     # collect() / process_batch: the same batch as a plain list
     rows, m2 = proc.process_batch(proc.encode_rows(INPUT, 0), 2000)
     assert type(rows["Out"]) is list and rows["Out"] == golden
@@ -381,7 +402,8 @@ def test_counters_are_on_an_empty_batch_and_registered(tmp_path):
     ).collect_tables()
     assert metrics["Egress_Columnar_Rows"] == 0.0
     assert metrics["Egress_Fallback_Rows"] == 0.0
-    for name in ("Egress_Columnar_Rows", "Egress_Fallback_Rows"):
+    for name in ("Egress_Columnar_Rows", "Egress_Fallback_Rows",
+                 "Sink_NativeEncoded_Rows"):
         assert MetricName.is_runtime_metric(name)
     assert not MetricName.is_runtime_metric("Egress_Rows")
 
@@ -452,8 +474,7 @@ def test_benchmark_flow_sink_files_are_the_per_row_bytes(
                 h.base_ms,
             )
             (path,) = (tmp_path / "out" / o).rglob(f"{o}_{t_ms}_*.json")
-            assert path.read_bytes() == \
-                per_row_payload(reference).encode("utf-8")
+            assert path.read_bytes() == per_row_payload(reference)
             total += len(reference)
     assert total > 3 * 8  # HeatAvg's 8 groups a batch at the very least
 
@@ -477,7 +498,7 @@ def test_file_sink_bytes_are_the_per_row_payload(tmp_path, compression):
     if compression == "gzip":
         assert path.name.endswith(".json.gz")
         raw = gzip.decompress(raw)
-    assert raw == per_row_payload(reference).encode("utf-8")
+    assert raw == per_row_payload(reference)
     # a plain list still writes the same file
     other = FileSink(str(tmp_path / "list"), compression)
     other.write("Out", reference, 1_700_000_000_000)
@@ -528,7 +549,7 @@ def _console(tmp_path, batch, reference):
 def _file(tmp_path, batch, reference):
     assert FileSink(str(tmp_path), "none").write("Out", batch, 0) == 3
     (path,) = [p for p in tmp_path.rglob("Out_*") if p.is_file()]
-    assert path.read_text() == per_row_payload(reference)
+    assert path.read_bytes() == per_row_payload(reference)
 
 
 def _httppost(tmp_path, batch, reference):
