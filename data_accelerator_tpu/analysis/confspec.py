@@ -36,6 +36,7 @@ decision.
 from __future__ import annotations
 
 import json
+import os
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
@@ -315,9 +316,21 @@ CONF_REGISTRY: Tuple[ConfKey, ...] = (
     _K("transform", "path", None, "runtime", source="template",
        description="path to the flow's transform script (codegen input)"),
     _K("timestampcolumn", "string", None, "runtime", source="template",
-       description="event-time column driving windows and watermarks"),
+       description="the column TIMEWINDOW tables go by. The "
+                   "current_timestamp() projection: processing-time "
+                   "windows; a payload column (or one computed from "
+                   "it): event-time windows, rows on the batch "
+                   "interval's grid (see Time windows below)"),
     _K("watermark", "duration", None, "runtime", source="template", min=0,
-       description="allowed event-time lateness"),
+       description="W: how far an event-time window trails its batch "
+                   "(W and the one interval the batch's own rows came "
+                   "in over), and how late a row may be stamped and "
+                   "still count (older ones are dropped and counted in "
+                   "Window_TooLate_Rows_Dropped; under 0, the default, "
+                   "every on-time row counts); sizes the window state "
+                   "(window + watermark + 1 interval, 2 for an "
+                   "event-time window) for both kinds of window (see "
+                   "Time windows below)"),
     _K("projection", "list", None, "runtime", source="template",
        description="';'-separated projection column list"),
     _K("properties.enabled", "bool", "false", "runtime", source="manual",
@@ -669,8 +682,31 @@ def render_conf_md() -> str:
     ]
     for rule in CONSTRAINTS:
         lines.append(f"| `{rule.name}` | {cell(rule.description)} |")
-    lines.append("")
+    lines += [
+        "",
+        "## Time windows (`timestampcolumn`, `watermark`, "
+        "`timewindow.*.windowduration`)",
+        "",
+        "Quoted from the docstring of `runtime/timewindow.py`, the one "
+        "statement of the rule:",
+        "",
+        *_window_rule().splitlines(),
+        "",
+    ]
     return "\n".join(lines)
+
+
+def _window_rule() -> str:
+    """The paragraphs of ``runtime/timewindow.py``'s docstring that say
+    which rows a window holds (read from the source: this module stays
+    importable without jax)."""
+    import ast
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "runtime", "timewindow.py")
+    with open(path, encoding="utf-8") as f:
+        doc = ast.get_docstring(ast.parse(f.read()))
+    return doc[doc.index("Which rows a window holds"):].rstrip()
 
 
 if __name__ == "__main__":  # pragma: no cover — doc generator

@@ -48,6 +48,7 @@ from ..core.config import EngineException, parse_duration_seconds
 from ..core.schema import Schema, StringDictionary
 from ..runtime.processor import (
     default_projection,
+    event_time_table,
     projection_select,
     schema_to_view,
     window_inputs,
@@ -548,6 +549,12 @@ def _plan_from_gui(
             target_caps[target] = cap
 
         # windows over projected tables (ring retention model)
+        projections: Dict[str, List[List[str]]] = {}
+        for sname, _sprops, target in sources:
+            projections.setdefault(target, []).append(
+                [snippets[sname]] if snippets[sname]
+                else [default_projection(schemas[sname], ts_col)]
+            )
         windows: Dict[str, Tuple[str, float]] = {}
         table_slots: Dict[str, int] = {}
         for wname, duration in rc.time_windows.items():
@@ -563,7 +570,10 @@ def _plan_from_gui(
                     f"{ts_col!r} in table {table}"
                 )
             windows[wname] = (table, dur_s)
-            slots = num_slots(dur_s, watermark_s, interval_s)
+            slots = num_slots(
+                dur_s, watermark_s, interval_s,
+                event_time_table(projections, table, ts_col),
+            )
             table_slots[table] = max(table_slots.get(table, 1), slots)
 
         # accumulation tables
@@ -581,15 +591,12 @@ def _plan_from_gui(
                 target_schemas[table],
                 table_slots[table] * target_caps[table],
             )
-        projections: Dict[str, List[List[str]]] = {}
-        for sname, _sprops, target in sources:
-            projections.setdefault(target, []).append(
-                [snippets[sname]] if snippets[sname]
-                else [default_projection(schemas[sname], ts_col)]
-            )
         pipeline = pc.compile_transform(
             rc.code, inputs, state,
-            windows=window_inputs(windows, table_slots, projections, ts_col),
+            windows=window_inputs(
+                windows, table_slots, projections, ts_col,
+                interval_s=interval_s, watermark_s=watermark_s,
+            ),
         )
         # a window the planner holds as partial aggregates keeps no ring
         ring_slots = {
@@ -810,7 +817,9 @@ def _stage_walk(
 
         def combined(_env, _base, now, ws=ws):
             state = ws.init()
-            return ws.combine(state, state.parts["n"][0], now)
+            return ws.combine(
+                state, state.slot_live, state.parts["n"][0], now
+            )
 
         env[WINDOW_PARTIALS_PREFIX + vname] = eval_view(
             SimpleNamespace(fn=combined), env

@@ -545,11 +545,12 @@ def _cross_check(
                 # an empty state and combined is its stage body
                 from ..compile.planner import WINDOW_PARTIALS_PREFIX
 
-                st, rows, dropped = ws.fold(
-                    t[ws.table], ws.init(), zero, zero, zero, zero, _aux
+                st, window, rows, dropped, _wrote = ws.fold(
+                    t[ws.table], ws.init(), zero, zero, zero, zero, _aux,
+                    ws.event_rows(t[ws.table], zero, zero),
                 )
                 t[WINDOW_PARTIALS_PREFIX + _view.name] = ws.combine(
-                    st, rows, dropped
+                    st, window, rows, dropped
                 )
             return _view.fn(t, zero, zero)
 

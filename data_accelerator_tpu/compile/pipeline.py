@@ -25,6 +25,7 @@ from ..core.config import EngineException
 from ..core.schema import StringDictionary
 from .planner import (
     CompiledView,
+    EventClock,
     PlannerConfig,
     RawWindowNeeded,
     SelectCompiler,
@@ -52,6 +53,17 @@ class Pipeline:
     # rows: every statement that reads one is a GROUP BY the planner
     # could decompose (compile/planner.py _compile_window_partials)
     partial_windows: Tuple[str, ...] = ()
+    # every TIMEWINDOW table as the runtime declared it
+    windows: Dict[str, WindowInput] = field(default_factory=dict)
+
+    @property
+    def event_tables(self) -> Dict[str, EventClock]:
+        """Projected table -> the clock of the event-time windows over it
+        (its timestamp column comes from the payload)."""
+        return {
+            w.table: w.clock for w in self.windows.values()
+            if w.clock is not None
+        }
 
     @property
     def window_states(self) -> Dict[str, WindowPartialsPlan]:
@@ -177,12 +189,12 @@ class PipelineCompiler:
         (DataXProcessedInput, its TIMEWINDOW variants, reference data).
         state_tables: accumulation tables (previous-state inputs).
         windows: which of the inputs are TIMEWINDOW tables. A window
-        whose rows share their batch's time, whose state is not handed
-        off by key partition and whose every reader is a decomposable
-        GROUP BY is held as per-slot partial aggregates;
-        the planner decides from the statements alone (no conf key): it
-        starts from every such window and gives one up when a statement
-        turns out to need its rows.
+        (processing-time or event-time: ``runtime/timewindow.py``) whose
+        state is not handed off by key partition and whose every reader
+        is a decomposable GROUP BY is held as per-slot partial
+        aggregates; the planner decides from the statements alone (no
+        conf key): it starts from every such window and gives one up
+        when a statement turns out to need its rows.
         """
         parsed = (
             transform
@@ -197,8 +209,7 @@ class PipelineCompiler:
         read = {t for sel in selects for t in _referenced_tables(sel)}
         partial = {
             w for w, info in windows.items()
-            if info.slot_uniform_time and not info.handoff_by_key
-            and w in read
+            if not info.handoff_by_key and w in read
         }
         while True:
             try:
@@ -265,4 +276,5 @@ class PipelineCompiler:
             state_tables=state_names,
             aux_registry=self.aux,
             partial_windows=tuple(sorted(partial_windows)),
+            windows=dict(windows),
         )

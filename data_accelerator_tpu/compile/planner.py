@@ -199,21 +199,56 @@ class StagePlan:
 # what it decides for a GROUP BY over one
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
+class EventClock:
+    """The grid an event-time window lives on (``runtime/timewindow.py``
+    has the rule): the batch interval I and ``process.watermark`` W, in
+    ms. A row's bucket is floor(ts / I); a batch accepts the buckets of
+    its own interval and the ``lag`` before it."""
+
+    interval_ms: int
+    watermark_ms: int
+
+    def __post_init__(self):
+        # int32 on the device: (base_s mod I) * 1000 has to fit
+        if not 0 < self.interval_ms < 2_000_000:
+            raise EngineException(
+                "an event-time window needs a batch interval under "
+                f"2,000 s, got {self.interval_ms} ms"
+            )
+
+    @property
+    def lag(self) -> int:
+        """w + 1: whole intervals the window trails the batch by, and a
+        row may lie behind it and still count. The watermark's w, and
+        one for the batch itself: its time t is when its rows were
+        polled, and they came in over the interval before t, which is
+        not on the grid, so its on-time rows are stamped in n - 1 and n."""
+        return -(-self.watermark_ms // self.interval_ms) + 1
+
+    def span(self, duration_ms: int) -> int:
+        """d: whole intervals a window of ``duration_ms`` covers."""
+        return max(1, -(-duration_ms // self.interval_ms))
+
+
+@dataclass(frozen=True)
 class WindowInput:
     """One ``TIMEWINDOW`` table as the runtime declares it."""
 
     table: str  # the projected table whose batches the window retains
-    slots: int  # batches retained (runtime/timewindow.py num_slots)
+    slots: int  # intervals retained (runtime/timewindow.py num_slots)
     duration_ms: int
     ts_col: str  # the flow's timestamp column
-    # every row of a batch carries the batch's one time (the timestamp
-    # column is the ``current_timestamp()`` projection): a slot is then
-    # wholly inside or outside the window
+    # which of the two window kinds: every row of a batch carries the
+    # batch's one time (the timestamp column is the
+    # ``current_timestamp()`` projection: a processing-time window, a
+    # slot is a batch), or the rows bring their own (an event-time
+    # window, a slot is an interval of event time, on ``clock``'s grid)
     slot_uniform_time: bool
     # the runtime hands this window's state to rescale successors by key
     # partition (``process.state.snapshoturl``: rows re-packed a
     # partition, runtime/statepartition.py), which needs the rows
     handoff_by_key: bool = False
+    clock: Optional[EventClock] = None  # set iff not slot_uniform_time
 
 
 class RawWindowNeeded(Exception):
@@ -245,9 +280,26 @@ class WindowPartialsPlan:
     duration_ms: int
     key_dtypes: Tuple[object, ...]
     parts: Dict[str, Tuple[str, object]]  # partial name -> (op, dtype)
-    # fold(batch, state, slot, delta_ms, base_s, now_rel_ms, aux)
-    #   -> (new state, live rows a column, groups dropped)
+    # fold(batch, state, counter, delta_ms, base_s, now_rel_ms, aux, event)
+    #   -> (new state, [slots] the slots the window reads, rows a column
+    #       holds over them, groups dropped, slots written);
+    # ``event``: the batch's rows on the clock's grid
+    # (``timewindow.event_rows``), None for a processing-time window
     fold: Callable = None
+    clock: Optional[EventClock] = None  # an event-time window's grid
+    ts_col: Optional[str] = None
+
+    def event_rows(self, batch: "TableData", base_s, now_rel_ms):
+        """The batch on the clock's grid (what ``fold`` takes as
+        ``event``); None for a processing-time window."""
+        if self.clock is None:
+            return None
+        from ..runtime.timewindow import event_rows
+
+        return event_rows(
+            batch.cols[self.ts_col], batch.valid, base_s, now_rel_ms,
+            self.clock,
+        )
 
     @property
     def ops(self) -> Dict[str, str]:
@@ -257,19 +309,24 @@ class WindowPartialsPlan:
         from ..runtime.timewindow import make_partials
 
         return make_partials(
-            self.key_dtypes, self.parts, self.slots, self.groups
+            self.key_dtypes, self.parts, self.slots, self.groups,
+            event_time=self.clock is not None,
         )
 
-    def combine(self, state, rows, dropped) -> "TableData":
+    def combine(self, state, window, rows, dropped) -> "TableData":
         from ..runtime.timewindow import combine_partials
 
-        return combine_partials(state, self.ops, rows, dropped)
+        return combine_partials(state, self.ops, window, rows, dropped)
 
     @property
     def state_bytes(self) -> int:
         per_group = sum(jnp.dtype(dt).itemsize for dt in self.key_dtypes) + 1
         per_cell = sum(jnp.dtype(dt).itemsize for _op, dt in self.parts.values())
-        return self.groups * (per_group + self.slots * per_cell) + self.slots * 5
+        # a slot's time and liveness, 4 + 1 bytes; an event-time window's
+        # generation, 4 more
+        per_slot = 5 if self.clock is None else 9
+        return self.groups * (per_group + self.slots * per_cell) \
+            + self.slots * per_slot
 
 
 @dataclass
@@ -1452,26 +1509,28 @@ class SelectCompiler:
         binding = sel.from_table.binding
         ops = {n: op for n, (op, _dt) in parts.items()}
 
-        def fold(batch, state, slot, delta_ms, base_s, now_rel_ms, aux):
+        def fold(batch, state, counter, delta_ms, base_s, now_rel_ms, aux,
+                 event=None):
             env = EvalEnv(
                 {binding: batch.cols, "__aux": aux}, base_s, now_rel_ms,
                 batch.valid.shape,
             )
-            valid = batch.valid
+            # an event-time window folds the rows the watermark accepted
+            valid = batch.valid if event is None else event.accepted
             if where_fn is not None:
                 valid = valid & where_fn(env)
             return fold_partials(
                 state, ops, [k.fn(env) for k in key_compiled], valid,
                 {key: a.fn(env).astype(parts[key][1])
                  for key, a in agg_args.items()},
-                slot, delta_ms, now_rel_ms, win.duration_ms,
+                counter, delta_ms, now_rel_ms, win.duration_ms, event,
             )
 
         key_dtypes = tuple(_DTYPES[k.type] for k in key_compiled)
         state = WindowPartialsPlan(
             window=wname, table=win.table, slots=win.slots, groups=groups,
             duration_ms=win.duration_ms, key_dtypes=key_dtypes, parts=parts,
-            fold=fold,
+            fold=fold, clock=win.clock, ts_col=win.ts_col,
         )
         agg_nodes = dict(compiler.agg_nodes)
 

@@ -224,6 +224,14 @@ class MetricName:
         r"Window_State_Bytes",
         r"Window_Slots_Live",
         r"Checkpoint_Window_Bytes",
+        # event-time windows (runtime/timewindow.py): accepted rows
+        # stamped over an interval before their batch's time, rows the
+        # watermark refused, slots of window state the batch wrote, slot
+        # rows the last window checkpoint wrote
+        r"Window_Late_Rows",
+        r"Window_TooLate_Rows_Dropped",
+        r"Window_Slots_Touched",
+        r"Checkpoint_Window_Slots",
         # model-vs-observed conformance (obs/conformance.py): windowed
         # observed/predicted ratios against the cost-model report
         # embedded in the conf, plus the cumulative drift-event count

@@ -66,7 +66,7 @@ def snapshot_arrays(snap: Dict) -> Dict:
         # in a partition payload ("parts"), in slot files a checkpoint
         for i, a in enumerate(part["keys"]):
             arrays[f"partial/{view}/key/{i}"] = a
-        for field_ in ("used", "slot_ts", "slot_live"):
+        for field_ in ("used", "slot_ts", "slot_live", "slot_gen"):
             arrays[f"partial/{view}/{field_}"] = part[field_]
     arrays["slot_counter"] = np.asarray(int(snap.get("slot_counter", 0)),
                                         np.int64)
@@ -106,7 +106,7 @@ def arrays_to_snapshot(z) -> Dict:
         part = partials.setdefault(view, {"keys": {}})
         if kind.startswith("key/"):
             part["keys"][int(kind[4:])] = z[key]
-        elif kind in ("used", "slot_ts", "slot_live"):
+        elif kind in ("used", "slot_ts", "slot_live", "slot_gen"):
             part[kind] = z[key]
     for part in partials.values():
         part["keys"] = [part["keys"][i] for i in sorted(part["keys"])]
@@ -214,6 +214,21 @@ class OffsetCheckpointer:
             self.write_offsets(list(merged.values()))
 
 
+def _slot_entries(
+    files: List, slots: int, counter: int
+) -> List[Tuple[int, int, str, int]]:
+    """[slot, generation, file, row] a slot still held, from a head of
+    PR 32's form: [first generation, last generation, file] a file, the
+    batch of counter g in slot g mod ``slots`` and in row g - first of
+    its file (one batch wrote a slot, so its counter is the slot's
+    generation)."""
+    return [
+        [g % slots, g, name, g - first]
+        for first, last, name in files
+        for g in range(max(first, counter - slots), last + 1)
+    ]
+
+
 class WindowStateCheckpointer:
     """Persist/restore the device window state across restarts.
 
@@ -229,15 +244,21 @@ class WindowStateCheckpointer:
     counter, the time base, the dictionary, every raw-row ring whole
     (``ring/<table>/col/<name>`` + ``ring/<table>/valid``) and, for a
     view that keeps per-slot partial aggregates, its key directory, slot
-    times and the names of the slot files that hold its slots' rows
-    (``partial/<view>/...``). Those rows are written a slot at a time:
-    one file a checkpoint under ``window-slots/`` with the slots written
-    since the last head landed (a slot's generation is the counter of
-    the batch that wrote it), never the whole state. A slot file is
-    named once and never rewritten, and is deleted only when neither the
-    head nor ``.old`` names it: whichever of the two a restart reads
-    finds every file it names, so a kill at any point restores a state
-    some completed checkpoint described.
+    times, slot generations and, for every live slot, the name of the
+    slot file that holds its row (``partial/<view>/...``). Those rows are
+    written a slot at a time: one file a checkpoint under
+    ``window-slots/`` with the live slots changed since the last head
+    landed (a slot's generation is the counter of the last batch that
+    changed it), never the whole state. A slot of a processing-time
+    window is written by one batch, so it is in one file; a slot of an
+    event-time window changes with every batch that brings rows of its
+    interval, late ones included, so a later checkpoint writes it again
+    and the head names the newest file that holds it. A file is written
+    once, under a name of its own, and is deleted only when neither the
+    head nor ``.old`` names it for any slot: whichever of the two a
+    restart reads finds every file it names, so a kill at any point
+    restores a state some completed checkpoint described, late rows
+    included.
     """
 
     FILE = "window.npz"
@@ -251,11 +272,12 @@ class WindowStateCheckpointer:
         # what ``FlowProcessor.snapshot_window_state(since=...)`` may
         # leave out. None: nothing on disk this process may build on
         self.landed_counter: Optional[int] = None
-        # view -> [(first generation, last generation, file name)] as the
+        # view -> slot -> (generation, file name, row in the file) as the
         # landed head names them, and as the head now in ``.old`` does
-        self._slot_files: Dict[str, List[Tuple[int, int, str]]] = {}
-        self._old_slot_files: Dict[str, List[Tuple[int, int, str]]] = {}
+        self._slot_files: Dict[str, Dict[int, Tuple[int, str, int]]] = {}
+        self._old_slot_files: Dict[str, Dict[int, Tuple[int, str, int]]] = {}
         self.last_bytes = 0  # bytes the last save wrote (head + slots)
+        self.last_slots = 0  # slot rows the last save wrote
 
     @property
     def path(self) -> str:
@@ -287,13 +309,14 @@ class WindowStateCheckpointer:
 
         arrays = snapshot_arrays(snap)
         counter = int(snap.get("slot_counter", 0))
-        written = 0
-        files: Dict[str, List[Tuple[int, int, str]]] = {}
+        written = slots = 0
+        files: Dict[str, Dict[int, Tuple[int, str, int]]] = {}
         if snap.get("partials"):
             with _trace_span("checkpoint/window-slots"):
                 for view, part in snap["partials"].items():
                     files[view], n = self._save_slots(view, part, counter)
                     written += n
+                    slots += len(part["rows"])
             for view, part in snap["partials"].items():
                 arrays[f"partial/{view}/files_json"] = np.frombuffer(
                     _json.dumps({
@@ -301,7 +324,7 @@ class WindowStateCheckpointer:
                         "groups": int(part["groups"]),
                         "dtypes": {n: str(a.dtype)
                                    for n, a in part["parts"].items()},
-                        "files": files[view],
+                        "files": [[s, *e] for s, e in files[view].items()],
                     }).encode("utf-8"), dtype=np.uint8,
                 )
         had_head = os.path.exists(self.path)
@@ -318,39 +341,37 @@ class WindowStateCheckpointer:
         self._slot_files = files
         self.landed_counter = counter
         self.last_bytes = written
+        self.last_slots = slots
         self._drop_unnamed_slot_files()
 
     def _save_slots(
         self, view: str, part: Dict, counter: int
-    ) -> Tuple[List[Tuple[int, int, str]], int]:
-        """Write the rows of the slots a snapshot brought (generations
-        ``first_gen`` on) as one new file; returns the slot files the new
-        head names for this view (those of the landed head that still
-        hold a live generation, then the new one) and the bytes
-        written."""
+    ) -> Tuple[Dict[int, Tuple[int, str, int]], int]:
+        """Write the rows a snapshot brought (``part["rows"]``: the live
+        slots changed since generation ``first_gen``) as one new file;
+        returns, for every live slot, the file the new head names for it
+        (the new one, or the one the landed head names when the slot has
+        not changed since) and the bytes written."""
         import numpy as np
 
-        first = int(part["first_gen"])
-        n = len(next(iter(part["parts"].values())))
-        oldest = max(0, counter - int(part["slots"]))
-        named = [
-            (a, b, name) for a, b, name in self._slot_files.get(view, [])
-            if oldest <= b < first
-        ]
-        covered = named[0][0] if named else first
-        if (named and covered > oldest) or (not named and first > oldest) \
-                or first + n != counter:
-            raise ValueError(
-                f"window checkpoint of {view}: the snapshot brings "
-                f"generations {first}..{first + n - 1} of "
-                f"{oldest}..{counter - 1} and the landed head names "
-                f"{[(a, b) for a, b, _ in named]}"
-            )
-        if n == 0:
+        gens, brought = part["slot_gen"], [int(s) for s in part["rows"]]
+        landed = self._slot_files.get(view, {})
+        named: Dict[int, Tuple[int, str, int]] = {}
+        for s in set(np.flatnonzero(part["slot_live"])) - set(brought):
+            entry = landed.get(int(s))
+            if entry is None or entry[0] != gens[s]:
+                raise ValueError(
+                    f"window checkpoint of {view}: slot {s} (generation "
+                    f"{gens[s]}) is not in the snapshot, which brings the "
+                    f"slots changed since {part['first_gen']}, and the "
+                    f"landed head names {entry} for it"
+                )
+            named[int(s)] = entry
+        if not len(brought):
             return named, 0
         os.makedirs(self.slots_dir, exist_ok=True)
-        name = (f"{view.replace(os.sep, '_')}.{first}-{counter - 1}."
-                f"{time.time_ns():x}.npz")
+        name = (f"{view.replace(os.sep, '_')}.{int(part['first_gen'])}-"
+                f"{counter - 1}.{time.time_ns():x}.npz")
         path = os.path.join(self.slots_dir, name)
         with open(path + ".tmp", "wb") as f:
             np.savez(f, **part["parts"])
@@ -358,7 +379,9 @@ class WindowStateCheckpointer:
             os.fsync(f.fileno())
         size = os.path.getsize(path + ".tmp")
         _durable_replace(path + ".tmp", path)
-        return named + [(first, counter - 1, name)], size
+        for row, s in enumerate(brought):
+            named[s] = (int(gens[s]), name, row)
+        return named, size
 
     def _drop_unnamed_slot_files(self) -> None:
         """Delete the slot files (and torn temp files) that neither the
@@ -368,7 +391,8 @@ class WindowStateCheckpointer:
         keep = {
             name
             for files in (self._slot_files, self._old_slot_files)
-            for entries in files.values() for _a, _b, name in entries
+            for entries in files.values()
+            for _gen, name, _row in entries.values()
         }
         for name in os.listdir(self.slots_dir):
             if name not in keep:
@@ -400,37 +424,49 @@ class WindowStateCheckpointer:
             return snap
         return None
 
-    def _load_slots(self, z, snap: Dict) -> Dict[str, List]:
+    def _load_slots(self, z, snap: Dict) -> Dict[str, Dict]:
         """Fill every view's ``parts`` from the slot files its head
-        names; returns those names. Raises when one cannot be read."""
+        names; returns those names. Raises when one cannot be read. A
+        head written before slots had generations of their own (PR 32,
+        33: processing-time windows only) is read too."""
         import json as _json
 
         import numpy as np
 
-        files: Dict[str, List[Tuple[int, int, str]]] = {}
-        counter = int(snap["slot_counter"])
+        files: Dict[str, Dict[int, Tuple[int, str, int]]] = {}
         for view, part in snap.get("partials", {}).items():
             head = _json.loads(
                 z[f"partial/{view}/files_json"].tobytes().decode("utf-8")
             )
             k, d = head["slots"], head["groups"]
-            files[view] = [tuple(e) for e in head["files"]]
-            # a slot no file holds was never written: it is not live, and
-            # the batch that takes it overwrites its whole row
+            entries = head["files"]
+            if entries and len(entries[0]) == 3:
+                entries = _slot_entries(
+                    entries, k, int(snap["slot_counter"]))
+            files[view] = {
+                int(s): (int(gen), name, int(row))
+                for s, gen, name, row in entries
+            }
+            if "slot_gen" not in part:
+                part["slot_gen"] = np.full(k, -1, np.int32)
+                for s, (gen, _name, _row) in files[view].items():
+                    part["slot_gen"][s] = gen
+            # a slot no file holds is not live: the batch (the interval)
+            # that takes it over starts its row from the identities
             parts = {
                 n: np.full((k, d), 0, np.dtype(dt))
                 for n, dt in head["dtypes"].items()
             }
-            held = np.zeros(k, bool)
-            for first, last, name in files[view]:
+            by_file: Dict[str, List[Tuple[int, int]]] = {}
+            for s, (_gen, name, row) in files[view].items():
+                by_file.setdefault(name, []).append((s, row))
+            for name, held in by_file.items():
                 with np.load(os.path.join(self.slots_dir, name)) as zs:
-                    for g in range(max(first, counter - k), last + 1):
-                        for n in parts:
-                            parts[n][g % k] = zs[n][g - first]
-                        held[g % k] = True
-            want = np.zeros(k, bool)
-            want[[g % k for g in range(max(0, counter - k), counter)]] = True
-            if (want & ~held).any():
-                raise ValueError(f"{view}: slots named by no file")
+                    for n in parts:
+                        rows = zs[n]
+                        for s, row in held:
+                            parts[n][s] = rows[row]
+            if set(np.flatnonzero(part["slot_live"])) - set(files[view]):
+                raise ValueError(f"{view}: live slots named by no file")
             part["parts"] = parts
         return files

@@ -690,6 +690,13 @@ class StreamingHost:
                 metrics["Checkpoint_Window_Bytes"] = float(
                     self.window_checkpointer.last_bytes
                 )
+                if self.processor.window_states:
+                    # and the slot rows among them (a late row changes
+                    # a slot an earlier checkpoint wrote: it is written
+                    # again)
+                    metrics["Checkpoint_Window_Slots"] = float(
+                        self.window_checkpointer.last_slots
+                    )
             metrics.update(trace.counters)
             if backlog is not None:
                 # background landing accounting: landings still queued when

@@ -39,6 +39,7 @@ import numpy as np
 from ..compile.pipeline import Pipeline, PipelineCompiler, parse_state_table_schema
 from ..compile.planner import (
     WINDOW_PARTIALS_PREFIX,
+    EventClock,
     PlannerConfig,
     TableData,
     ViewSchema,
@@ -54,8 +55,10 @@ from ..core.schema import ColType, Schema, StringDictionary
 from .materialize import ColumnBatch
 from .statetable import StateTable
 from .timewindow import (
+    EventRows,
     WindowBuffers,
     WindowPartials,
+    event_rows,
     make_buffers,
     num_slots,
     update_buffers,
@@ -113,10 +116,10 @@ def projection_select(step_text: str, from_table: str):
 
 def batch_constant_time(steps: List[str], ts_col: Optional[str]) -> bool:
     """Is the projected timestamp column the ``current_timestamp()``
-    projection? Every row of a batch then carries the batch's one time,
-    so a window slot lies wholly inside or outside a window: what lets
-    the planner hold a windowed GROUP BY as per-slot partial aggregates.
-    A payload time column (or anything computed from one) is not."""
+    projection? Every row of a batch then carries the batch's one time:
+    the table's windows are processing-time windows. A payload time
+    column (or anything computed from one) makes them event-time windows
+    (``runtime/timewindow.py`` has both rules)."""
     from ..compile.sqlparser import Col, Func, Star
 
     col = ts_col
@@ -143,29 +146,51 @@ def batch_constant_time(steps: List[str], ts_col: Optional[str]) -> bool:
     return False
 
 
+def event_time_table(
+    projections: Dict[str, List[List[str]]], table: str,
+    ts_col: Optional[str],
+) -> bool:
+    """Do the windows over ``table`` go by a time its rows bring (some
+    source's projection of the timestamp column is not the
+    ``current_timestamp()`` one)? ``projections`` as ``window_inputs``
+    takes them."""
+    return not all(
+        batch_constant_time(steps, ts_col)
+        for steps in projections.get(table, [[]])
+    )
+
+
 def window_inputs(
     windows: Dict[str, Tuple[str, float]],
     table_slots: Dict[str, int],
     projections: Dict[str, List[List[str]]],
     ts_col: Optional[str],
     handoff_by_key: bool = False,
+    interval_s: float = 1.0,
+    watermark_s: float = 0.0,
 ) -> Dict[str, WindowInput]:
     """What the planner is told of every TIMEWINDOW table (window name ->
     (table, seconds)); ``projections``: per projected table, the
     projection steps of each source that feeds it; ``handoff_by_key``:
     the runtime ships window state to a snapshot mirror by key partition
-    (the rescale handoff re-packs rows). The ONE definition
-    the runtime and the device-plan analyzer share, so both see the
-    planner make the same choice of window state."""
+    (the rescale handoff re-packs rows); the batch interval and
+    ``process.watermark`` are the grid of an event-time window. The ONE
+    definition the runtime and the device-plan analyzer share, so both
+    see the planner make the same choice of window state."""
+    uniform = {
+        table: not event_time_table(projections, table, ts_col)
+        for table, _dur_s in windows.values()
+    }
+    clock = EventClock(
+        int(round(interval_s * 1000)), int(round(watermark_s * 1000))
+    ) if not all(uniform.values()) else None
     return {
         wname: WindowInput(
             table=table, slots=table_slots[table],
             duration_ms=int(dur_s * 1000), ts_col=ts_col,
-            slot_uniform_time=all(
-                batch_constant_time(steps, ts_col)
-                for steps in projections.get(table, [[]])
-            ),
+            slot_uniform_time=uniform[table],
             handoff_by_key=handoff_by_key,
+            clock=None if uniform[table] else clock,
         )
         for wname, (table, dur_s) in windows.items()
     }
@@ -413,7 +438,17 @@ def build_step_fn(
                 projected[target_] = env[target_]
 
         # 2. ring updates (one ring per windowed table; each ring's
-        # slot index derives from the shared batch counter)
+        # slot index derives from the shared batch counter). A table
+        # whose rows bring their own time is put on its clock's grid
+        # first: which rows the watermark accepts, and how many
+        # intervals behind the batch each lies (runtime/timewindow.py)
+        events: Dict[str, EventRows] = {}
+        with jax.named_scope("dx.window"):
+            for table, clock in pipeline.event_tables.items():
+                events[table] = event_rows(
+                    projected[table].cols[ts_col], projected[table].valid,
+                    base_s, now_rel_ms, clock,
+                )
         new_rings: Dict[str, WindowBuffers] = {}
         with jax.named_scope("dx.ring"):
             for table in ring_tables:
@@ -422,7 +457,8 @@ def build_step_fn(
                     counter, jnp.asarray(buf.valid.shape[0], jnp.int32)
                 )
                 new_rings[table] = update_buffers(
-                    buf, projected[table], slot, delta_ms, ts_col
+                    buf, projected[table], slot, delta_ms, ts_col,
+                    now_rel_ms, events.get(table),
                 )
 
         tables: Dict[str, TableData] = dict(projected)
@@ -431,7 +467,7 @@ def build_step_fn(
                 if table in new_rings:
                     tables[wname] = window_table(
                         new_rings[table], int(dur_s * 1000), now_rel_ms,
-                        ts_col,
+                        ts_col, events.get(table),
                     )
         # windowed GROUP BYs held as per-slot partial aggregates: the
         # batch's rows fold into its slot (``dx.window.partial``), the
@@ -439,20 +475,23 @@ def build_step_fn(
         # (``dx.window.combine``); outside the view's own scope so a
         # device trace tells the window's state from the view's select
         slots_live = []
+        # slots of window state written this batch, a table: one a ring,
+        # the most a view's fold wrote
+        touched = {table: jnp.asarray(1, jnp.int32) for table in new_rings}
         for vname, ws in (window_states or {}).items():
             with jax.named_scope("dx.window.partial"):
-                slot = jax.lax.rem(counter, jnp.asarray(ws.slots, jnp.int32))
-                new_rings[vname], live_rows, dropped = ws.fold(
-                    projected[ws.table], rings[vname], slot, delta_ms,
-                    base_s, now_rel_ms, aux,
-                )
+                new_rings[vname], in_window, live_rows, dropped, wrote = \
+                    ws.fold(
+                        projected[ws.table], rings[vname], counter, delta_ms,
+                        base_s, now_rel_ms, aux, events.get(ws.table),
+                    )
+                touched[ws.table] = jnp.maximum(
+                    touched.get(ws.table, 0), wrote)
             with jax.named_scope("dx.window.combine"):
                 tables[WINDOW_PARTIALS_PREFIX + vname] = ws.combine(
-                    new_rings[vname], live_rows, dropped
+                    new_rings[vname], in_window, live_rows, dropped
                 )
-                slots_live.append(
-                    new_rings[vname].slot_live.astype(jnp.int32).sum()
-                )
+                slots_live.append(in_window.astype(jnp.int32).sum())
         for rname in refdata_names:
             tables[rname] = refdata[rname]
         for sname in state_names:
@@ -499,6 +538,13 @@ def build_step_fn(
                 counts.append(projected[target_].count())
             # live slots of every partial-aggregate window state
             counts.extend(slots_live)
+            # event-time windows: accepted rows stamped over an interval
+            # before the batch's time, rows the watermark refused, slots
+            # written (summed over the tables)
+            if events:
+                counts.append(sum(e.late for e in events.values()))
+                counts.append(sum(e.too_late for e in events.values()))
+                counts.append(sum(touched.get(t, 0) for t in events))
             counts_vec = jnp.stack(
                 [jnp.asarray(c, jnp.int32) for c in counts]
             )
@@ -1109,6 +1155,9 @@ class FlowProcessor:
         ]
 
         # 2. window slots per windowed target table
+        projections: Dict[str, List[List[str]]] = {}
+        for s in self.specs.values():
+            projections.setdefault(s.target, []).append(s.projection_steps)
         table_slots: Dict[str, int] = {}
         for wname, (table, dur_s) in self.windows.items():
             if self.timestamp_column not in self.target_schemas[table].types:
@@ -1116,7 +1165,10 @@ class FlowProcessor:
                     f"timewindow {wname} requires timestamp column "
                     f"{self.timestamp_column!r} in table {table}"
                 )
-            slots = num_slots(dur_s, self.watermark_s, self.interval_s)
+            slots = num_slots(
+                dur_s, self.watermark_s, self.interval_s,
+                event_time_table(projections, table, self.timestamp_column),
+            )
             table_slots[table] = max(table_slots.get(table, 1), slots)
 
         # 3. main pipeline inputs
@@ -1129,9 +1181,6 @@ class FlowProcessor:
                 self.target_schemas[table],
                 table_slots[table] * target_caps[table],
             )
-        projections: Dict[str, List[List[str]]] = {}
-        for s in self.specs.values():
-            projections.setdefault(s.target, []).append(s.projection_steps)
         for rname, (rschema, rtable) in self.refdata.items():
             inputs[rname] = (rschema, rtable.capacity)
         state_inputs = {
@@ -1144,6 +1193,7 @@ class FlowProcessor:
                 self.windows, table_slots, projections,
                 self.timestamp_column,
                 handoff_by_key=self.state_mirror is not None,
+                interval_s=self.interval_s, watermark_s=self.watermark_s,
             ),
         )
         # the planner's choice, from the statements alone: a window every
@@ -1307,9 +1357,11 @@ class FlowProcessor:
 
         Partial aggregates are snapshotted a slot at a time: ``since``
         is the slot counter of the checkpoint the caller already holds
-        (``WindowStateCheckpointer.landed_counter``), and only the slots
-        written since cross to the host, with the small per-view head
-        (key directory, slot times). ``None`` takes every slot."""
+        (``WindowStateCheckpointer.landed_counter``), and only the live
+        slots a batch has changed since cross to the host (a late row
+        changes a slot an earlier checkpoint wrote: it crosses again),
+        with the small per-view head (key directory, slot times, slot
+        generations). ``None`` takes every live slot."""
         # under the device-state lock: the checkpoint may run on the
         # background landing thread while the dispatch thread is about
         # to donate these very ring buffers into the next step. The
@@ -2371,24 +2423,32 @@ def _snapshot_partials(
     buf: WindowPartials, counter: int, since: Optional[int]
 ) -> Dict[str, object]:
     """Host copy of one view's partial aggregates: its head, and the rows
-    of the slots written by batches ``since`` .. ``counter`` - 1 (a
-    slot's generation is the counter of the batch that wrote it; all the
-    state still holds when ``since`` is None or further back than that)."""
+    of the live slots that batches ``since`` .. ``counter`` - 1 changed
+    (a slot's generation is the counter of the last batch that changed
+    it: an event-time state's own ``slot_gen``; of a processing-time
+    window the batch of counter g wrote slot g mod K, once. Every live
+    slot when ``since`` is None or not a counter this state has passed).
+    ``rows`` names the slots brought, oldest change first."""
     k = buf.slots
-    first = max(0, counter - k)
-    if since is not None and first <= since <= counter:
-        first = since
-    gens = range(first, counter)
-    if len(gens) == k:
+    live = np.array(buf.slot_live, copy=True)
+    if buf.slot_gen is None:
+        gens = np.full(k, -1, np.int32)
+        written = np.arange(max(0, counter - k), counter)
+        gens[written % k] = written
+    else:
+        gens = np.array(buf.slot_gen, copy=True)
+    first = since if since is not None and 0 <= since <= counter else 0
+    rows = np.flatnonzero(live & (gens >= first))
+    rows = rows[np.argsort(gens[rows], kind="stable")]
+    if len(rows) == k:
         whole = {n: np.array(a, copy=True) for n, a in buf.parts.items()}
-        parts = {n: a[[g % k for g in gens]] for n, a in whole.items()}
+        parts = {n: a[rows] for n, a in whole.items()}
     else:
         parts = {
             n: np.stack([
-                np.array(_slot_row(a, jnp.asarray(g % k, jnp.int32)),
-                         copy=True)
-                for g in gens
-            ]) if len(gens) else np.zeros((0, buf.groups), a.dtype)
+                np.array(_slot_row(a, jnp.asarray(r, jnp.int32)), copy=True)
+                for r in rows
+            ]) if len(rows) else np.zeros((0, buf.groups), a.dtype)
             for n, a in buf.parts.items()
         }
     return {
@@ -2396,8 +2456,8 @@ def _snapshot_partials(
         "keys": [np.array(a, copy=True) for a in buf.keys],
         "used": np.array(buf.used, copy=True),
         "slot_ts": np.array(buf.slot_ts, copy=True),
-        "slot_live": np.array(buf.slot_live, copy=True),
-        "first_gen": first, "parts": parts,
+        "slot_live": live, "slot_gen": gens,
+        "first_gen": first, "rows": rows, "parts": parts,
     }
 
 
@@ -2415,6 +2475,8 @@ def _restore_partials(
         (like.used, saved.get("used")),
         (like.slot_ts, saved.get("slot_ts")),
         (like.slot_live, saved.get("slot_live")),
+        *([(like.slot_gen, saved.get("slot_gen"))]
+          if like.slot_gen is not None else []),
         *((a, saved.get("parts", {}).get(n)) for n, a in like.parts.items()),
     ]
     if len(saved.get("keys", ())) != len(like.keys) \
@@ -2428,6 +2490,7 @@ def _restore_partials(
         tuple(put(a) for a in saved["keys"]), put(saved["used"]),
         {n: put(a) for n, a in saved["parts"].items()},
         put(saved["slot_ts"]), put(saved["slot_live"]),
+        None if like.slot_gen is None else put(saved["slot_gen"]),
     )
 
 
@@ -2738,11 +2801,19 @@ class PendingBatch:
             # partials, the slots inside its window this batch (the
             # counts vector's tail: one a view, the widest reported)
             metrics["Window_State_Bytes"] = float(proc.window_state_bytes())
+            tail = bc.counts[1 + 3 * len(self.out_names)
+                             + len(self.target_names):]
             n_live = len(proc.window_states)
             if n_live:
-                metrics["Window_Slots_Live"] = float(
-                    max(bc.counts[len(bc.counts) - n_live:])
-                )
+                metrics["Window_Slots_Live"] = float(max(tail[:n_live]))
+            if proc.pipeline.event_tables:
+                # event-time windows (runtime/timewindow.py): accepted
+                # rows stamped over an interval before the batch's time,
+                # rows the watermark refused, slots written
+                late, too_late, wrote = tail[n_live:n_live + 3]
+                metrics["Window_Late_Rows"] = float(late)
+                metrics["Window_TooLate_Rows_Dropped"] = float(too_late)
+                metrics["Window_Slots_Touched"] = float(wrote)
         if proc.mesh is not None:
             # the chips the step's own output lies on: a mesh conf that
             # silently stepped on one chip reads 1

@@ -126,7 +126,7 @@ class BufferSanitizer:
             for i, a in enumerate(saved.get("keys", ())):
                 pairs.append((view, f"key{i}", a,
                               live.keys[i] if live else None))
-            for field_ in ("used", "slot_ts", "slot_live"):
+            for field_ in ("used", "slot_ts", "slot_live", "slot_gen"):
                 pairs.append((view, field_, saved.get(field_),
                               getattr(live, field_, None)))
             for pname, a in saved.get("parts", {}).items():

@@ -19,6 +19,7 @@ only recompiles the change.
 from __future__ import annotations
 
 import json
+import math
 import re
 import threading
 import time
@@ -86,8 +87,19 @@ class Kernel:
             conf["datax.job.process.watermark"] = "0 second"
             # the kernel runs ONE batch; sizing the interval to the max
             # window keeps the ring at 2 slots instead of window/1s
+            interval_s = float(max(1, int(max_window_s)))
+            if self._ts_in_schema:
+                # event-time windows (runtime/timewindow.py) live on the
+                # interval's grid and trail the batch by the watermark: a
+                # 60th of the widest window a slot, and a watermark as
+                # wide as that window, so the sample's rows within a
+                # window of its newest are all accepted (``execute``
+                # reads the window once they have passed the watermark)
+                interval_s = math.ceil(max_window_s * 1000 / 60) / 1000
+                conf["datax.job.process.watermark"] = \
+                    f"{math.ceil(max_window_s * 1000)} ms"
             conf["datax.job.input.default.streaming.intervalinseconds"] = str(
-                max(1, int(max_window_s))
+                interval_s
             )
         conf.update(self.refdata_conf)
         if self.debug:
@@ -120,6 +132,8 @@ class Kernel:
                         break
             except (ValueError, KeyError):
                 pass
+            # a time the rows bring: windows over it are event-time
+            self._ts_in_schema = col is not None
             if col is None:
                 m = re.search(
                     r"current_timestamp\(\)\s+AS\s+(\w+)",
@@ -236,6 +250,17 @@ class Kernel:
         base_ms = self._sample_base_ms()
         raw = proc.encode_rows(self.sample_rows, (base_ms // 1000) * 1000)
         datasets, _metrics = proc.process_batch(raw, batch_time_ms=base_ms)
+        clock = next(iter(proc.pipeline.event_tables.values()), None)
+        if clock is not None:
+            # an event-time window trails its batch by the watermark, so
+            # the batch that brought the sample does not read it yet.
+            # The sample again, one interval past the watermark: every
+            # row is now too late to be counted twice, the batch's own
+            # table still shows them all, and the window is the rows
+            # within a window's length of the sample's newest
+            later = base_ms + (clock.lag + 1) * clock.interval_ms
+            raw = proc.encode_rows(self.sample_rows, (later // 1000) * 1000)
+            datasets, _metrics = proc.process_batch(raw, batch_time_ms=later)
         rows = datasets.get(target, [])[:max_rows]
         headers = list(rows[0].keys()) if rows else []
         return {"headers": headers, "result": rows, "table": target}

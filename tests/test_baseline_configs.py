@@ -70,23 +70,36 @@ def test_config2_window_count_avg_accumulates_across_batches(tmp_path):
                                [base, base, base]), base),
         base,
     )
-    # batch 2 (3 s later, still inside the 10 s window): device 1 again
+    # batch 2 (3 s later, batch 1 still inside the 10 s window): device 1
+    # again. The timestamp column comes from the payload, so this is an
+    # event-time window (runtime/timewindow.py): with a watermark of 0 it
+    # holds the 10 whole seconds that end one before the batch's own (a
+    # batch's on-time rows are stamped in its own second and the one
+    # before), and a row is read once the batch is two seconds past it
     datasets, _ = proc.process_batch(
         proc.encode_rows(_rows([1], [30.0], [base + 3000]), base + 3000),
         base + 3000,
     )
     agg = {r["deviceId"]: r for r in datasets["WinAgg"]}
-    assert agg[1]["Cnt"] == 3
-    assert agg[1]["AvgT"] == pytest.approx(20.0)
+    assert agg[1]["Cnt"] == 2
+    assert agg[1]["AvgT"] == pytest.approx(15.0)
     assert agg[2]["Cnt"] == 1
 
-    # batch 3, 12 s after batch 1: batch-1 rows fell out of the window
+    # batch 3, 12 s after batch 1: batch-1 rows fell out of the window,
+    # batch 2's row is in it, batch 3's own not yet
     datasets, _ = proc.process_batch(
         proc.encode_rows(_rows([2], [50.0], [base + 12000]), base + 12000),
         base + 12000,
     )
     agg = {r["deviceId"]: r for r in datasets["WinAgg"]}
-    assert 1 not in agg or agg[1]["Cnt"] == 1  # device 1's old rows evicted
+    assert set(agg) == {1}  # device 1's old rows and device 2's evicted
+    assert agg[1]["Cnt"] == 1 and agg[1]["AvgT"] == pytest.approx(30.0)
+
+    # batch 4, two seconds later: batch 3's row has entered
+    datasets, _ = proc.process_batch(
+        proc.encode_rows(_rows([], [], []), base + 14000), base + 14000,
+    )
+    agg = {r["deviceId"]: r for r in datasets["WinAgg"]}
     assert agg[2]["Cnt"] == 1 and agg[2]["AvgT"] == pytest.approx(50.0)
 
 

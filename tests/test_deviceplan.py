@@ -262,11 +262,13 @@ def test_abstract_eval_produces_no_arrays(tmp_path):
         report = analyze_processor(proc)
     finally:
         jax.eval_shape = orig
-    # one eval_shape per compiled view (projection + transform)
+    # one eval_shape per compiled view (projection + transform), and one
+    # for the combined groups of a window held as partial aggregates
     n_views = sum(len(v) for v in proc.projection_views.values()) + len(
         proc.pipeline.views
     )
-    assert calls["n"] == n_views
+    assert len(proc.window_states) == 1
+    assert calls["n"] == n_views + len(proc.window_states)
     assert report.stages
 
 

@@ -142,8 +142,11 @@ def test_partition_plan_axes_follow_the_mesh_layout():
     """The inferred plan mirrors dist/mesh.py's documented layout:
     rows/rings/windows shard, state replicates, group outputs
     replicate with a modeled gather at the window boundary."""
+    # (a flow whose window a plain SELECT reads too, so the planner keeps
+    # the raw-row ring; clean_config2_window_agg's GROUP BY alone is held
+    # as replicated partial aggregates: the next test)
     report = analyze_flow_mesh(
-        load_flow("clean_config2_window_agg"), chips=8, lower=False
+        load_flow("dx702_perchip_hbm"), chips=8, lower=False
     )
     by = {s.name: s for s in report.stages}
     assert by["input:default"].axis == "data"
@@ -162,6 +165,20 @@ def test_partition_plan_axes_follow_the_mesh_layout():
     assert by["ring:DataXProcessedInput"].per_chip_bytes == (
         -(-by["ring:DataXProcessedInput"].hbm_bytes // 8)
     )
+
+
+def test_a_window_held_as_partial_aggregates_is_replicated():
+    """A windowed GROUP BY alone, over a payload time column: per-slot
+    partial aggregates of an event-time window, replicated on every chip
+    (no ring, no window stage to gather)."""
+    report = analyze_flow_mesh(
+        load_flow("clean_config2_window_agg"), chips=8, lower=False
+    )
+    by = {s.name: s for s in report.stages}
+    assert not [n for n in by if n.startswith("ring:")]
+    state = by["window-state:WinAgg"]
+    assert (state.axis, state.scaling) == ("replicated", "replicated")
+    assert state.per_chip_bytes == state.hbm_bytes
 
 
 def test_state_join_right_side_replicates_without_reshard():

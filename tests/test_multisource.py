@@ -179,11 +179,13 @@ def test_join_overflow_metric_and_configured_capacity(tmp_path):
             _wx_rows([1] * 4, [50.0] * 4, [BASE] * 4), BASE, source="wx")},
         BASE,
     )
+    # (the weather window goes by the rows' own time: it reads a second
+    # once the batch is two past it, runtime/timewindow.py)
     datasets, metrics = proc.process_batch(
         {"default": proc.encode_rows(
-            _iot_rows([1] * 8, [20.0] * 8, [BASE + 1000] * 8),
-            BASE + 1000)},
-        BASE + 1000,
+            _iot_rows([1] * 8, [20.0] * 8, [BASE + 2000] * 8),
+            BASE + 2000)},
+        BASE + 2000,
     )
     assert len(datasets["Joined"]) == 8
     assert metrics["Output_Joined_Events_Count"] == 8.0
@@ -193,8 +195,8 @@ def test_join_overflow_metric_and_configured_capacity(tmp_path):
     # for outputs that track no join at all)
     datasets, metrics = proc.process_batch(
         {"default": proc.encode_rows(
-            _iot_rows([1], [20.0], [BASE + 2000]), BASE + 2000)},
-        BASE + 2000,
+            _iot_rows([1], [20.0], [BASE + 3000]), BASE + 3000)},
+        BASE + 3000,
     )
     assert metrics["Output_Joined_JoinRowsDropped"] == 0.0
 
@@ -290,18 +292,22 @@ def test_window_state_survives_restart(tmp_path):
         BASE + 3000,
     )
     agg = {r["deviceId"]: r["Cnt"] for r in datasets["WinAgg"]}
-    assert agg[5] == 3  # 2 pre-restart rows + 1 post-restart row
+    # the 2 pre-restart rows; the post-restart row is stamped in its
+    # batch's own second, which an event-time window (the timestamp
+    # column comes from the payload: runtime/timewindow.py) reads once
+    # the batch is two seconds past it
+    assert agg[5] == 2
 
     # ...and eviction still works off the restored (rebased) timestamps:
-    # at +11 s the 10 s window spans [+1 s, +11 s] — the two BASE rows
-    # restored from the snapshot are out, +3 s and +11 s remain
+    # at +12 s the 10 s window holds seconds +1 .. +10 — the two BASE
+    # rows restored from the snapshot are out, the +3 s row remains
     datasets, _ = proc2.process_batch(
-        proc2.encode_rows(_iot_rows([5], [4.0], [BASE + 11000]),
-                          BASE + 11000),
-        BASE + 11000,
+        proc2.encode_rows(_iot_rows([5], [4.0], [BASE + 12000]),
+                          BASE + 12000),
+        BASE + 12000,
     )
     agg = {r["deviceId"]: r["Cnt"] for r in datasets["WinAgg"]}
-    assert agg[5] == 2
+    assert agg[5] == 1
 
 
 def test_window_state_restart_preserves_string_ids(tmp_path):
@@ -352,7 +358,9 @@ def test_window_state_restart_preserves_string_ids(tmp_path):
         BASE + 3000,
     )
     agg = {r["site"]: r["Cnt"] for r in datasets["BySite"]}
-    assert agg == {"sea": 3, "ams": 1}
+    # the restored rows under their own strings (the batch's own row is
+    # read once the batch is two seconds past it)
+    assert agg == {"sea": 2, "ams": 1}
 
 
 def test_window_snapshot_rejected_on_shape_change(tmp_path):
@@ -397,16 +405,33 @@ def test_streaming_host_restores_window_state(tmp_path):
 
     import time as _time
 
-    now = int(_time.time() * 1000)
-    write_events("b1.json", _iot_rows([5, 5], [1.0, 2.0], [now] * 2))
-    host1 = StreamingHost(conf("h1"))
-    host1.run_batch()
-    host1.stop()
+    def just_past_a_seconds_edge():
+        """Now, in ms, within 200 ms after a second's edge: a row stamped
+        300 ms ago then lies in the second before the batch's own."""
+        while _time.time() % 1 > 0.2:
+            _time.sleep(0.02)
+        return int(_time.time() * 1000)
 
-    write_events("b2.json", _iot_rows([5], [3.0],
-                                      [int(_time.time() * 1000)]))
+    # the file's watermark is 0, upstream's default: rows stamped a few
+    # hundred ms before their batch, on the other side of a second's
+    # edge, are on time (a batch's rows came in over the interval before
+    # its time: runtime/timewindow.py)
+    host1 = StreamingHost(conf("h1"))
+    now = just_past_a_seconds_edge()
+    write_events("b1.json", _iot_rows([5, 5], [1.0, 2.0],
+                                      [now - 300, now - 700]))
+    metrics = host1.run_batch()
+    host1.stop()
+    assert metrics["Window_TooLate_Rows_Dropped"] == 0.0
+    assert metrics["Window_Late_Rows"] == 0.0
+
+    # an event-time window trails its batch: the first host's rows are
+    # read once the batch is two seconds past theirs
+    _time.sleep(2.1)
     host2 = StreamingHost(conf("h2"))
     assert host2.processor._slot_counter > 0  # snapshot restored
+    write_events("b2.json", _iot_rows([5], [3.0],
+                                      [int(_time.time() * 1000)]))
     collected = {}
 
     orig = host2.dispatcher.dispatch
@@ -419,4 +444,4 @@ def test_streaming_host_restores_window_state(tmp_path):
     host2.run_batch()
     host2.stop()
     agg = {r["deviceId"]: r["Cnt"] for r in collected["WinAgg"]}
-    assert agg[5] == 3
+    assert agg[5] == 2  # the rows the first host took, restored

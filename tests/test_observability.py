@@ -710,6 +710,68 @@ def test_the_window_partials_sort_a_batch_not_the_window(
     assert 131_072 + min(262_144, 131_072) <= 262_144
 
 
+@pytest.fixture(scope="module")
+def eventtime_processor(tmp_path_factory):
+    """The benchmark's event-time deployment (PR 34: the sample's
+    5-minute window on the sensors' own clock, 10 s watermark) at a
+    2,048-row width and 4,096 group slots: 312 slots of partial
+    aggregates, a slot a second of event time."""
+    from benchmark import run as bench, served
+    from data_accelerator_tpu.core.config import SettingDictionary
+    from data_accelerator_tpu.runtime.processor import FlowProcessor
+
+    run_dir = str(tmp_path_factory.mktemp("eventtime"))
+    cell = bench.load_cell("homeautomation-5m-eventtime.paced")
+    conf_path = served.write_conf(run_dir, cell["config"], 2048,
+                                  served.free_port(), None)
+    with open(conf_path, encoding="utf-8") as f:
+        conf = dict(ln.rstrip("\n").split("=", 1) for ln in f if "=" in ln)
+    conf["datax.job.process.projection"] = conf[
+        "datax.job.process.projection"].replace("\\n", "\n")
+    conf["datax.job.process.maxgroups"] = "4096"
+    proc = FlowProcessor(SettingDictionary(conf))
+    state = proc.window_states["HeatAvg"]
+    assert (state.slots, state.groups, state.clock.lag) == (312, 4096, 11)
+    assert not proc.ring_slots
+    return proc
+
+
+def test_the_event_time_fold_sorts_a_batch_and_writes_twelve_slot_rows(
+        eventtime_processor, v5e_chip):
+    """An event-time window's fold in the program the v5e runs: the
+    sorts are the batch's rows (by key and second) and the key
+    directory's groups, as for a processing-time window; nothing sorts,
+    gathers or scatters the state's 312 x 4,096 cells: what is indexed by
+    row is the batch (each (second, key) total to its place in the 12 x
+    4,096 block of the seconds a batch can reach: its own, the one
+    before and the watermark's 10), and those 12 slot rows are read and
+    written as rows."""
+    text = _compiled_for(eventtime_processor, v5e_chip)
+    width, slots, groups, touched = 2048, 312, 4096, 12
+    sorts = [(int(n), ln) for ln in text.splitlines()
+             for n in re.findall(r"= \(?\w+\[(\d+)\][^=]* sort\(%", ln)[:1]]
+    in_window = {n for n, ln in sorts
+                 if "dx.window.partial" in ln or "dx.window.combine" in ln}
+    assert width in in_window
+    assert in_window <= {width, groups, groups + min(width, groups)}
+    shapes = {name: [int(d) for d in dims.split(",") if d]
+              for name, dims in re.findall(
+                  r"^\s*(?:ROOT )?(%[\w.-]+) = \w+\[([\d,]*)\]", text, re.M)}
+    indexed = []
+    for ln in text.splitlines():
+        m = re.search(r" (gather|scatter)\((%[\w.-]+), (%[\w.-]+)", ln)
+        if m and "dx.window.partial" in ln:
+            indexed.append((m.group(1), shapes[m.group(2)],
+                            shapes[m.group(3)][0]))
+    assert indexed
+    # no operand a gather or scatter walks is the whole state, and none
+    # takes more indices than the batch has rows or the directory groups
+    assert all(operand != [slots, groups] or n <= touched
+               for _k, operand, n in indexed), indexed
+    assert max(n for _k, _o, n in indexed) <= max(width, groups), indexed
+    assert ("scatter", [touched * groups], width) in indexed
+
+
 def test_the_group_by_gathers_and_scatters_by_group_not_by_row(
         raw_ring_processor, v5e_raw_ring_step_text):
     """Over a raw-row ring ``dx.view.HeatAvg`` groups the whole ring (6

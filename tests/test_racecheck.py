@@ -229,6 +229,11 @@ def test_seeded_pr13_bug_caught_dynamically(tmp_path):
         "--DataXQuery--\n"
         "WinAgg = SELECT deviceId, COUNT(*) AS Cnt "
         "FROM DataXProcessedInput_10seconds GROUP BY deviceId\n"
+        # a reader of the window's rows: the planner keeps the raw-row
+        # ring, whose snapshot the seeded bug aliases
+        "--DataXQuery--\n"
+        "Rows = SELECT deviceId FROM DataXProcessedInput_10seconds "
+        "WHERE deviceId > 100\n"
     )
     schema = json.dumps({"type": "struct", "fields": [
         {"name": "deviceId", "type": "long", "nullable": False,

@@ -1,9 +1,10 @@
 """Window state as per-slot partial aggregates (PR 32).
 
 A GROUP BY of COUNT / SUM / AVG / MIN / MAX over a ``TIMEWINDOW`` table
-whose rows carry their batch's one time is held as K slots of per-group
-partial aggregates (``runtime/timewindow.py WindowPartials``), not as K
-batches of rows. The raw-row ring is the reference: the same events
+is held as K slots of per-group partial aggregates
+(``runtime/timewindow.py WindowPartials``), not as K batches of rows;
+here the processing-time windows, whose rows carry their batch's one
+time (tests/test_window_eventtime.py has the event-time ones). The raw-row ring is the reference: the same events
 through both give the same rows. Which of the two a window keeps is the
 planner's choice from the statements alone; the state is checkpointed a
 slot at a time and laid out over a mesh like the ring is.
@@ -236,9 +237,11 @@ def test_the_planner_keeps_raw_rows_for(tmp_path, why):
     assert proc.ring_slots == {"DataXProcessedInput": 4}
 
 
-def test_the_planner_keeps_raw_rows_for_a_payload_timestamp_column(tmp_path):
-    """The rows of a batch carry times of their own: a slot can straddle
-    the window's far edge, so the rows are needed."""
+def test_the_planner_keeps_partials_for_a_payload_timestamp_column(tmp_path):
+    """The rows of a batch carry times of their own: an event-time window
+    (PR 34), whose slot is a second of event time, not a batch. It is held
+    as partial aggregates all the same (tests/test_window_eventtime.py
+    holds both states to the rule)."""
     schema = json.loads(SCHEMA)
     schema["fields"].append({"name": "eventTimeStamp", "type": "timestamp",
                              "nullable": False, "metadata": {}})
@@ -246,8 +249,14 @@ def test_the_planner_keeps_raw_rows_for_a_payload_timestamp_column(tmp_path):
     c["datax.job.input.default.blobschemafile"] = json.dumps(schema)
     del c["datax.job.process.projection"]
     proc = FlowProcessor(SettingDictionary(c), batch_capacity=CAP)
-    assert not proc.window_states
-    assert proc.ring_slots == {"DataXProcessedInput": 4}
+    assert proc.pipeline.partial_windows == ("DataXProcessedInput_W",)
+    assert not proc.ring_slots
+    plan = proc.window_states["HeatAvg"]
+    assert plan.slots == 5 and plan.clock is not None
+    assert (plan.clock.interval_ms, plan.clock.watermark_ms) == (1000, 0)
+    # the current_timestamp() projection is the other kind
+    uniform = FlowProcessor(conf(tmp_path, SAMPLE, 3), batch_capacity=CAP)
+    assert uniform.window_states["HeatAvg"].clock is None
 
 
 def test_the_planner_keeps_partials_for_the_sample(tmp_path):
@@ -358,6 +367,64 @@ def test_a_torn_checkpoint_falls_back_to_the_previous_one(tmp_path, torn):
     assert again.restore_window_state(snap)
     state = again.window_buffers["PerDevice"]
     assert int(np.asarray(state.slot_live).sum()) == snap["slot_counter"]
+
+
+def test_a_head_written_before_slots_had_generations_restores(tmp_path):
+    """A checkpoint of PR 32's form (the head names [first generation,
+    last generation, file] a file and carries no ``slot_gen``: a slot was
+    the batch of counter g, in row g - first of its file) restores after
+    an upgrade: the accepted 5-minute deployment does not restart with an
+    empty window."""
+    import json
+
+    c = conf(tmp_path, PER_DEVICE, 7)
+    proc = FlowProcessor(c, batch_capacity=CAP, output_datasets=["PerDevice"])
+    ck = WindowStateCheckpointer(str(tmp_path / "ck"))
+    stream = simple_batches(10, 1)
+    feed(proc, stream[:3])
+    ck.save(proc.snapshot_window_state(since=ck.landed_counter))
+    feed(proc, stream[3:7], t0=T0 + 3000)
+    ck.save(proc.snapshot_window_state(since=ck.landed_counter))
+    # the same checkpoint as PR 32 wrote it
+    named = []
+    for path in (ck.path, ck.backup_path):
+        with np.load(path) as z:
+            arrays = {k: z[k] for k in z.files}
+        del arrays["partial/PerDevice/slot_gen"]
+        head = json.loads(
+            arrays["partial/PerDevice/files_json"].tobytes().decode())
+        by_file = {}
+        for slot, gen, name, row in head["files"]:
+            assert slot == gen % head["slots"]
+            by_file.setdefault(name, []).append((gen, row))
+        head["files"] = []
+        for name, held in sorted(by_file.items(), key=lambda e: min(e[1])):
+            first = min(g for g, _r in held)
+            assert sorted(held) == [(first + i, i) for i in range(len(held))]
+            head["files"].append([first, first + len(held) - 1, name])
+        arrays["partial/PerDevice/files_json"] = np.frombuffer(
+            json.dumps(head).encode(), np.uint8)
+        with open(path, "wb") as f:
+            np.savez(f, **arrays)
+        named.append([e[:2] for e in head["files"]])
+    assert named == [[[0, 2], [3, 6]], [[0, 2]]]
+
+    ck2 = WindowStateCheckpointer(str(tmp_path / "ck"))
+    snap = ck2.load()
+    assert snap["slot_counter"] == 7
+    again = FlowProcessor(c, batch_capacity=CAP,
+                          output_datasets=["PerDevice"])
+    assert again.restore_window_state(snap)
+    assert sorted(snap["partials"]["PerDevice"]["slot_gen"]) == \
+        [-1, *range(7)]
+    first = [rows for rows, _m in feed(proc, stream[7:], t0=T0 + 7000)]
+    got = [rows for rows, _m in feed(again, stream[7:], t0=T0 + 7000)]
+    assert got == first and got[-1]
+    # and its next checkpoint builds on the files the old head named
+    ck2.save(again.snapshot_window_state(since=ck2.landed_counter))
+    assert ck2.last_slots == 3
+    assert WindowStateCheckpointer(str(tmp_path / "ck")).load()[
+        "slot_counter"] == 10
 
 
 def test_a_snapshot_the_processor_did_not_take_is_forgotten(tmp_path):
