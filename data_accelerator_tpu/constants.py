@@ -148,6 +148,10 @@ class MetricName:
         # times since the last batch that one was reallocated or a
         # delivered blob was copied a second time; 0 in steady state
         r"Source_Buffer_Grow_Count",
+        # decode-ahead of arrived lines during the paced wait
+        # (runtime/host.py _pace): the share of the batch's rows decoded
+        # before its poll, the host ms of those passes, and their count
+        r"Decode_Ahead_(Pct|Ms|Passes)",
         r"Output_[A-Za-z0-9_.]+_Events_Count",
         r"Output_[A-Za-z0-9_.]+_(GroupsDropped|JoinRowsDropped)",
         r"Sink_[a-z]+",

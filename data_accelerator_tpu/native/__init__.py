@@ -13,6 +13,7 @@ from .decoder import (
     load_library,
     native_available,
     native_crc32c,
+    packed_shard_bytes,
     scan_lines,
 )
 
@@ -26,5 +27,6 @@ __all__ = [
     "load_library",
     "native_available",
     "native_crc32c",
+    "packed_shard_bytes",
     "scan_lines",
 ]

@@ -27,7 +27,11 @@ Three decode surfaces:
   matrices come from a :class:`PackedBufferPool` (64-byte-aligned, so
   the CPU backend's ``jnp.asarray`` transfer is zero-copy) and are
   double-buffered against the pipelined in-flight window by the
-  processor (a slot is only reused after its batch lands or abandons);
+  processor (a slot is only reused after its batch lands or abandons).
+  A call takes the row slots from ``slot`` on, so a batch may be
+  decoded in passes as its lines arrive (the paced host's wait does:
+  ``runtime/processor.py decode_ahead``) and the one-shot decode is the
+  one-pass case;
 - ``decode_kafka_packed``: native Kafka v2 record-batch walking
   (varint framing, CRC-32C verification, control-batch skip,
   typed rejection of compressed batches) feeding each record value to
@@ -185,7 +189,7 @@ def load_library():
         ]
         lib.dx_decode_packed.restype = ctypes.c_int64
         lib.dx_decode_packed.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
             ctypes.c_void_p, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64), ctypes.c_int64, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64), ctypes.c_int32,
@@ -214,6 +218,8 @@ def load_library():
         ]
         lib.dx_bad_timestamps.restype = ctypes.c_int64
         lib.dx_bad_timestamps.argtypes = [ctypes.c_void_p]
+        lib.dx_packed_shard_bytes.restype = ctypes.c_int64
+        lib.dx_packed_shard_bytes.argtypes = []
         lib.dx_dict_size.restype = ctypes.c_int64
         lib.dx_dict_size.argtypes = [ctypes.c_void_p]
         lib.dx_dict_push.restype = ctypes.c_int32
@@ -244,6 +250,14 @@ def native_crc32c(data: bytes) -> Optional[int]:
     if not native_available():
         return None
     return int(load_library().dx_crc32c(data, len(data)))
+
+
+def packed_shard_bytes() -> int:
+    """The bytes from which ``NativeDecoder.decode_packed`` shards what
+    it is given (``shard_count`` over 1): a caller that decodes a batch
+    in passes keeps a pass at least this large, so that the passes stay
+    multi-threaded."""
+    return int(load_library().dx_packed_shard_bytes())
 
 
 def scan_lines(
@@ -573,39 +587,59 @@ class NativeDecoder:
 
     def _packed_args(
         self, matrix: np.ndarray, col_rows: Sequence[int], valid_row: int,
+        slot: int = 0,
     ):
         if matrix.dtype != np.int32 or not matrix.flags["C_CONTIGUOUS"]:
             raise ValueError("packed decode needs a C-contiguous int32 matrix")
         cr = (ctypes.c_int64 * len(self._cols))(*[int(r) for r in col_rows])
         return (
-            matrix.ctypes.data_as(ctypes.c_void_p),
+            ctypes.c_void_p(matrix.ctypes.data + 4 * slot),
             int(matrix.shape[1]), cr, int(valid_row),
         )
 
     def decode_packed(
         self,
-        data: bytes,
+        data,
         matrix: np.ndarray,
         col_rows: Sequence[int],
         valid_row: int,
         base_ms: int,
         max_rows: Optional[int] = None,
+        slot: int = 0,
     ) -> Tuple[int, int]:
         """Newline-JSON straight into the packed H2D matrix: column i
         of the schema writes matrix row ``col_rows[i]`` (floats
         bitcast, bools widened, timestamps rebased to int32
         batch-relative ms against ``base_ms``), validity into
-        ``matrix[valid_row]`` as int32 0/1. The decoder zeroes its own
-        rows first, so reused (dirty) pool matrices are fine. Returns
+        ``matrix[valid_row]`` as int32 0/1. The lines take the row
+        slots from ``slot`` on, at most ``max_rows`` of them (default:
+        all that are left), and the decoder zeroes exactly those slots
+        of its own rows first: reused (dirty) pool matrices are fine,
+        and a batch may be decoded in several calls, each from the slot
+        the one before stopped at (``max_rows`` that call's line count;
+        the last call, left at the default, zeroes the tail). ``data``:
+        ``bytes``, or any buffer of bytes that stays as it is for the
+        call (a view of the socket source's receive buffer). Returns
         (rows decoded, bytes consumed)."""
         self._push_python_entries()
-        base, stride, cr, vrow = self._packed_args(matrix, col_rows, valid_row)
-        cap = int(matrix.shape[1]) if max_rows is None else int(max_rows)
+        left = int(matrix.shape[1]) - slot
+        cap = left if max_rows is None else int(max_rows)
+        if not 0 <= cap <= left:
+            raise ValueError(
+                f"packed decode of {cap} rows at slot {slot} of "
+                f"{matrix.shape[1]}"
+            )
+        base, stride, cr, vrow = self._packed_args(
+            matrix, col_rows, valid_row, slot
+        )
+        # the address of any buffer, read-only ones included; ``raw``
+        # keeps the bytes alive for the call
+        raw = np.frombuffer(data, dtype=np.uint8)
         consumed = ctypes.c_int64(0)
         n_threads = self.shard_count()
         self.last_shards = n_threads
         rows = self._lib.dx_decode_packed(
-            self._d, data, len(data), cap, base, stride, cr, vrow,
+            self._d, raw.ctypes.data, raw.size, cap, base, stride, cr, vrow,
             int(base_ms), ctypes.byref(consumed), n_threads,
         )
         self.last_bad_timestamps = int(self._lib.dx_bad_timestamps(self._d))

@@ -26,6 +26,7 @@ import jax
 from ..constants import MetricName
 from ..core.config import SettingDictionary, SettingNamespace
 from ..core.confmanager import ConfigManager
+from ..native import packed_shard_bytes
 from ..obs import telemetry, tracing
 from ..obs.exposition import HealthState, ObservabilityServer
 from ..obs.histogram import HISTOGRAMS
@@ -37,6 +38,10 @@ from .sinks import OutputDispatcher, build_output_operators
 from .sources import LocalSource, StreamingSource, make_source
 
 logger = logging.getLogger(__name__)
+
+# The paced wait (``StreamingHost._pace``) wakes this often to decode the
+# lines that have arrived since its last pass.
+_AHEAD_SLICE_S = 0.02
 
 
 class StreamingHost:
@@ -136,6 +141,22 @@ class StreamingHost:
         # (the role maxRate plays statically in the reference — here the
         # effective rate adapts between maxrate/8 and maxrate)
         self._rate_scale = 1.0
+        # the sources whose lines the paced wait decodes as they arrive:
+        # those that can show them before the poll (``arrived_lines``:
+        # the socket source), where the step takes the packed matrix
+        # the passes fill (not under a mesh: its row-layout decode
+        # writes fresh arrays at the poll)
+        self._ahead_sources = {
+            name: src for name, src in self.sources.items()
+            if hasattr(src, "arrived_lines") and self.processor.mesh is None
+        }
+        # (bytes, seconds) of the latest passes: how long the next takes
+        self._ahead_passes: deque = deque(maxlen=8)
+        # a pass waits for the bytes from which the packed decoder
+        # shards, so that the passes stay multi-threaded
+        self._ahead_pass_bytes = (
+            packed_shard_bytes() if self._ahead_sources else 0
+        )
 
         # offset checkpointing (EventhubCheckpointer semantics)
         ckpt_dir = input_conf.get("eventhub.checkpointdir") or input_conf.get(
@@ -426,6 +447,14 @@ class StreamingHost:
         return depth
 
     # -- loop -------------------------------------------------------------
+    def _poll_limit(self, name: str) -> int:
+        """The most rows a poll asks the source for, before the pilot's
+        admission has its say."""
+        return min(
+            self.processor.specs[name].capacity,
+            max(1, int(self.max_rate * self.interval_s * self._rate_scale)),
+        )
+
     def _poll_and_encode(self):
         """Poll every source and encode one device batch per source;
         returns (raw dict, consumed offsets, batch_time_ms, t0)."""
@@ -434,11 +463,7 @@ class StreamingHost:
         raw: Dict[str, object] = {}
         consumed: Dict = {}
         for name, src in self.sources.items():
-            spec = self.processor.specs[name]
-            max_events = min(
-                spec.capacity,
-                max(1, int(self.max_rate * self.interval_s * self._rate_scale)),
-            )
+            max_events = self._poll_limit(name)
             if self.pilot is not None:
                 # source backpressure: the pilot's token bucket is the
                 # admission point — at full rate it grants pass-through,
@@ -471,10 +496,15 @@ class StreamingHost:
                 with tracing.span("source-poll"):
                     blob, _n, c = src.poll_raw(max_events)
                 received = _n
+                # what the wait decoded counts as far as the blob begins
+                # with it; the poll's cut is the batch either way
+                ahead_bytes = self.processor.decode_ahead_cursor(name)[0] \
+                    if getattr(src, "polled_arrived", False) else 0
                 raw[name] = self.processor.encode_json_bytes(
                     blob, (batch_time_ms // 1000) * 1000, source=name,
                     to_device=False,
                     fmt=getattr(src, "raw_format", "jsonl"),
+                    ahead_bytes=ahead_bytes,
                 )
             else:
                 with tracing.span("source-poll"):
@@ -941,6 +971,19 @@ class StreamingHost:
             ]
             if said:
                 trace.counters[counter] = float(sum(said))
+        # what the wait before this poll took off the batch's decode:
+        # the share of its rows decoded by then, and the passes' cost
+        ahead = [
+            self.processor.decode_ahead_stats[name]
+            for name in self._ahead_sources
+            if name in self.processor.decode_ahead_stats
+        ]
+        if ahead:
+            early, rows, ms, passes = map(sum, zip(*ahead))
+            trace.counters["Decode_Ahead_Pct"] = \
+                100.0 * early / rows if rows else 0.0
+            trace.counters["Decode_Ahead_Ms"] = float(ms)
+            trace.counters["Decode_Ahead_Passes"] = float(passes)
         return polled
 
     def _dispatch_traced(self, trace, raw, batch_time_ms):
@@ -997,6 +1040,85 @@ class StreamingHost:
             self.pilot.tick(batch_time_ms=int(time.time() * 1000))
         return metrics
 
+    def _pass_s(self, nbytes: Optional[int] = None) -> float:
+        """How long a pass over ``nbytes`` may take (the most that one
+        of the latest passes took, when None): at the slowest speed
+        among them, and half as long again. A whole slice, until a
+        pass has been timed."""
+        if not self._ahead_passes:
+            return _AHEAD_SLICE_S
+        if nbytes is None:
+            nbytes = max(b for b, _s in self._ahead_passes)
+        return 1.5 * nbytes * max(s / b for b, s in self._ahead_passes)
+
+    def _decode_arrived(self, deadline: float) -> None:
+        """One pass a source over the lines that have arrived since the
+        pass before: decoded into the next batch's matrix
+        (``FlowProcessor.decode_ahead``), against the base a poll at
+        ``deadline`` gets. No pass starts that the passes before it say
+        cannot end by ``deadline``: the poll starts when it always did.
+        """
+        for name, src in list(self._ahead_sources.items()):
+            at_byte, at_line = self.processor.decode_ahead_cursor(name)
+            got = src.arrived_lines(at_byte, at_line)
+            if got is None:
+                continue
+            data, lines = got
+            if at_line + lines > self._poll_limit(name):
+                continue  # a backlog: the poll cuts it, and decodes its cut
+            left = deadline - time.time()
+            if self._pass_s(len(data)) > left:
+                continue
+            if len(data) < self._ahead_pass_bytes and \
+                    self._pass_s(self._ahead_pass_bytes) < \
+                    left - _AHEAD_SLICE_S:
+                continue  # a later wake takes them with what comes
+            t0 = time.perf_counter()
+            try:
+                with tracing.annotation(
+                    "decode-ahead", bytes=len(data), lines=lines
+                ):
+                    decoded = self.processor.decode_ahead(
+                        data, lines, int(deadline) * 1000, source=name
+                    )
+            except Exception:  # noqa: BLE001
+                # nothing is staged any more; the poll decodes the
+                # lines, and what is wrong with them is its to raise
+                logger.exception(
+                    "decode-ahead pass failed: source %s is decoded at "
+                    "its polls from here on", name
+                )
+                del self._ahead_sources[name]
+                continue
+            if decoded and len(data) >= self._ahead_pass_bytes:
+                # (a smaller pass's time says what a call costs, not
+                # how fast the decoder is)
+                self._ahead_passes.append(
+                    (len(data), time.perf_counter() - t0)
+                )
+
+    def _pace(self, deadline: float) -> None:
+        """Wait for ``deadline`` (the interval's end). Where a source
+        can show its lines before the poll, the wait wakes in slices
+        and decodes what has arrived, so that the poll finds all but
+        the last slice's lines in the batch's matrix already; nothing
+        is delivered before the poll."""
+        if deadline - time.time() <= 0:
+            return
+        # pacing, measured in a capture instead of inferred from the
+        # hole between two batches; the passes lie inside it
+        with tracing.annotation("pace"):
+            while self._ahead_sources and not self._stop:
+                # the last wake leaves a pass the time to end
+                nap = deadline - time.time() - self._pass_s()
+                if nap <= 0:
+                    break
+                time.sleep(min(_AHEAD_SLICE_S, nap))
+                self._decode_arrived(deadline)
+            left = deadline - time.time()
+            if left > 0:
+                time.sleep(left)
+
     def run(self, max_batches: Optional[int] = None) -> None:
         """Paced loop (streaming.intervalInSeconds cadence,
         StreamingHost.scala:66-67)."""
@@ -1006,13 +1128,10 @@ class StreamingHost:
                 self.run_batch()
                 if max_batches is not None and self.batches_processed >= max_batches:
                     break
-                sleep = self.interval_s - (time.time() - start)
-                if sleep > 0:
-                    # pacing, measured in a capture instead of inferred
-                    # from the hole between two batches
-                    with tracing.annotation("pace"):
-                        time.sleep(sleep)
+                self._pace(start + self.interval_s)
         finally:
+            # a matrix the wait was filling goes back to its pool
+            self.processor.drop_decode_ahead()
             self._stop_profiler()
 
     def _stop_profiler(self) -> None:

@@ -382,6 +382,34 @@ def pack_from_matrix(
     )
 
 
+@dataclass
+class StagedBatch:
+    """A batch's pooled packed matrix while its lines are decoded into
+    it in passes (``FlowProcessor.decode_ahead``, then the poll's own
+    call): the counts after the latest pass, and after each one, so
+    that the passes past a cut can be dropped."""
+
+    pool: object  # the PackedBufferPool ``matrix`` goes back to
+    matrix: np.ndarray
+    base_ms: int  # what the time cells decoded so far are relative to
+    nbytes: int = 0  # of the lines decoded so far
+    slots: int = 0  # row slots they own: the next pass starts here
+    rows: int = 0  # valid rows among them
+    bad_ts: int = 0  # rows dropped for a timestamp that does not parse
+    seconds: float = 0.0  # in the decoder, dropped passes included
+    # (nbytes, slots, rows, bad_ts) after each pass before the poll
+    passes: List[Tuple[int, int, int, int]] = field(default_factory=list)
+
+    def keep(self, nbytes: int) -> None:
+        """Drop the passes that end past the batch's first ``nbytes``
+        bytes: the next call decodes from the last one kept (and zeroes
+        every slot from its end on)."""
+        while self.passes and self.passes[-1][0] > nbytes:
+            self.passes.pop()
+        self.nbytes, self.slots, self.rows, self.bad_ts = \
+            self.passes[-1] if self.passes else (0, 0, 0, 0)
+
+
 def build_step_fn(
     ts_col: Optional[str],
     windows: Dict[str, Tuple[str, float]],
@@ -1283,6 +1311,11 @@ class FlowProcessor:
         self._ingest_col_rows: Dict[str, List[int]] = {}
         self._decode_shards: Optional[int] = None
         self._decode_rows_per_sec: Optional[float] = None
+        # source -> its NEXT batch's matrix while ``decode_ahead`` fills
+        # it, and what the latest packed encode found decoded ahead:
+        # (rows kept, the batch's rows, ms of the passes, passes)
+        self._staged: Dict[str, StagedBatch] = {}
+        self.decode_ahead_stats: Dict[str, Tuple[int, int, float, int]] = {}
         # which decode engine served the last encode_json_bytes call:
         # "native-sharded" (packed pool path) / "native-mt" (row-layout
         # native, under a mesh)
@@ -1693,6 +1726,7 @@ class FlowProcessor:
         packed: Optional[bool] = None,
         to_device: bool = True,
         fmt: str = "jsonl",
+        ahead_bytes: int = 0,
     ) -> Union[TableData, "PackedRaw"]:
         """Native ingest hot path: raw wire bytes decoded by the C++
         decoder (native/decoder.cpp) straight into columnar buffers —
@@ -1716,23 +1750,19 @@ class FlowProcessor:
         Python objects, zero per-call column allocations, no pack
         copy. The matrix is reused only after its batch lands
         (PendingBatch releases the slot), double-buffering the pool
-        against the pipelined in-flight window."""
+        against the pipelined in-flight window.
+
+        ``ahead_bytes``: how many of ``data``'s leading bytes
+        ``decode_ahead`` has already decoded into this batch's matrix
+        (``_encode_packed_native``)."""
         spec = self._spec(source)
         if packed is None:
             packed = self.mesh is None
-
-        decoder = self._native_decoders.get(spec.name)
-        if decoder is None:
-            from ..native import NativeDecoder
-
-            decoder = NativeDecoder(
-                spec.schema, self.dictionary, threads=self.decoder_threads
-            )
-            self._native_decoders[spec.name] = decoder
-
+        decoder = self._native_decoder(spec)
+        self.decode_ahead_stats.pop(spec.name, None)
         if packed:
             return self._encode_packed_native(
-                decoder, data, base_ms, spec, fmt, to_device
+                decoder, data, base_ms, spec, fmt, to_device, ahead_bytes
             )
 
         # row-layout native path (mesh shardings want [capacity] leaves)
@@ -1741,7 +1771,7 @@ class FlowProcessor:
             data = self._kafka_values_to_lines(data)
         arrays, valid, rows, _consumed = decoder.decode(data, spec.capacity)
         self._decode_shards = decoder.last_shards
-        self._count_jsonl_malformed(data, _consumed, rows)
+        self._count_jsonl_malformed(data, 0, _consumed, rows)
         if decoder.last_bad_timestamps:
             self.ingest_stats["bad_timestamps"] = (
                 self.ingest_stats.get("bad_timestamps", 0)
@@ -1793,17 +1823,28 @@ class FlowProcessor:
             )
 
     # -- ingest fast-path helpers -----------------------------------------
-    def _count_jsonl_malformed(self, data: bytes, consumed: int,
+    def _native_decoder(self, spec: SourceSpec):
+        decoder = self._native_decoders.get(spec.name)
+        if decoder is None:
+            from ..native import NativeDecoder
+
+            decoder = NativeDecoder(
+                spec.schema, self.dictionary, threads=self.decoder_threads
+            )
+            self._native_decoders[spec.name] = decoder
+        return decoder
+
+    def _count_jsonl_malformed(self, data: bytes, start: int, consumed: int,
                                rows: int) -> None:
-        """Malformed lines in the consumed range = newline count minus
-        decoded rows (the decoder zero-gaps them); feeds the
-        Input_malformed_rows_Count metric and the pilot flood signal.
-        Allocation-free line count (bytes.count is C): blank lines are
-        rare enough that miscounting one as malformed can't move the
-        pilot's 30% flood threshold."""
-        consumed_blob = data[:consumed] if consumed else data
-        lines_seen = consumed_blob.count(b"\n")
-        if consumed_blob and not consumed_blob.endswith(b"\n"):
+        """Malformed lines in the range a decode from ``data[start]`` on
+        consumed = newline count minus decoded rows (the decoder
+        zero-gaps them); feeds the Input_malformed_rows_Count metric and
+        the pilot flood signal. Allocation-free line count (bytes.count
+        is C): blank lines are rare enough that miscounting one as
+        malformed can't move the pilot's 30% flood threshold."""
+        stop = start + consumed if consumed else len(data)
+        lines_seen = data.count(b"\n", start, stop)
+        if stop > start and data[stop - 1] != 0x0A:
             lines_seen += 1
         malformed = max(0, lines_seen - int(rows))
         if malformed:
@@ -1872,21 +1913,12 @@ class FlowProcessor:
         self._count_ingest("malformed_rows", malformed, malformed=True)
         return self.encode_rows(rows, base_ms, source=spec.name)
 
-    def _encode_packed_native(
-        self, decoder, data: bytes, base_ms: int, spec: SourceSpec,
-        fmt: str, to_device: bool,
-    ) -> "PackedRaw":
-        """The allocation-free hot path: acquire a pooled, persistent,
-        64-byte-aligned matrix already laid out as the packed H2D
-        transfer and let the decoder shards write straight into it.
-        The returned PackedRaw carries its pool slot; dispatch hands it
-        to the PendingBatch, which releases it when the batch lands (or
-        abandons) — never while the device step may still be reading
-        the zero-copied buffer."""
+    def _ingest_plan(self, spec: SourceSpec):
+        """Where a source's packed decode writes: (the pool of its
+        matrices, the layout, the matrix row of each schema column)."""
         from ..native import PackedBufferPool
 
         layout = packed_raw_layout(spec.raw_schema.types)
-        names = [c for c, _k in layout]
         n_rows = len(layout) + 1
         cap = spec.capacity
         pool = self._ingest_pools.get(spec.name)
@@ -1899,19 +1931,154 @@ class FlowProcessor:
             self._ingest_pools[spec.name] = pool
         col_rows = self._ingest_col_rows.get(spec.name)
         if col_rows is None:
-            index = {c: i for i, c in enumerate(names)}
+            index = {c: i for i, (c, _k) in enumerate(layout)}
             col_rows = [index[c.name] for c in spec.schema.columns]
             self._ingest_col_rows[spec.name] = col_rows
-        valid_row = len(layout)
-        mat = pool.acquire()
+        return pool, layout, col_rows
+
+    def _decode_pass(
+        self, decoder, data, staged: "StagedBatch", col_rows: List[int],
+        valid_row: int, lines: Optional[int] = None,
+    ) -> None:
+        """One call of the packed decoder into ``staged``'s matrix, from
+        its next free row slot on and against its base. With ``lines``
+        (a pass before the poll) ``data`` is that many whole lines and
+        the call owns exactly as many slots; without (the poll's own
+        call) ``data`` is the batch's blob, decoded from the byte the
+        passes stopped at into every slot that is left, so the tail is
+        zeroed, and its malformed lines are counted (a pass's are the
+        slots it owns less its rows: counted with the batch, once the
+        poll has said which passes are in it)."""
+        start = staged.nbytes if lines is None else 0
+        t0 = time.perf_counter()
+        rows, consumed = decoder.decode_packed(
+            memoryview(data)[start:] if start else data, staged.matrix,
+            col_rows, valid_row, staged.base_ms,
+            max_rows=lines, slot=staged.slots,
+        )
+        staged.seconds += time.perf_counter() - t0
+        if lines is None:
+            self._count_jsonl_malformed(data, start, consumed, rows)
+        else:
+            staged.slots += lines
+        staged.nbytes += consumed
+        staged.rows += rows
+        staged.bad_ts += decoder.last_bad_timestamps
+
+    def decode_ahead(
+        self, data, lines: int, base_ms: int, source: Optional[str] = None,
+    ) -> bool:
+        """Decode ``lines`` whole, non-blank lines of the source's NEXT
+        batch before its poll, into that batch's pooled matrix at the
+        next free row slot: what the paced host does with the lines a
+        socket source shows it while it waits for its interval.
+        ``base_ms``: the base the caller expects the batch to get (the
+        first pass fixes it; when the poll comes at another,
+        ``encode_json_bytes`` decodes the batch again, whole). ``data``
+        must stay as it is until the call returns. False, with nothing
+        decoded, when the matrix has no room for the lines. The caller's next
+        ``encode_json_bytes(..., ahead_bytes=)`` finishes the batch;
+        ``drop_decode_ahead`` gives the matrix back without one."""
+        spec = self._spec(source)
+        pool, layout, col_rows = self._ingest_plan(spec)
+        staged = self._staged.get(spec.name)
+        if staged is not None and staged.pool is not pool:
+            self.drop_decode_ahead(spec.name)
+            staged = None
+        if lines > spec.capacity - (staged.slots if staged else 0):
+            return False
+        if staged is None:
+            staged = self._staged[spec.name] = StagedBatch(
+                pool, pool.acquire(), base_ms
+            )
         try:
+            self._decode_pass(
+                self._native_decoder(spec), data, staged, col_rows,
+                len(layout), lines,
+            )
+        except Exception:
+            self.drop_decode_ahead(spec.name)
+            raise
+        staged.passes.append(
+            (staged.nbytes, staged.slots, staged.rows, staged.bad_ts)
+        )
+        return True
+
+    def decode_ahead_cursor(self, source: Optional[str] = None
+                            ) -> Tuple[int, int]:
+        """(bytes, lines) of the source's next batch that
+        ``decode_ahead`` has decoded so far."""
+        staged = self._staged.get(self._spec(source).name)
+        return (staged.nbytes, staged.slots) if staged else (0, 0)
+
+    def drop_decode_ahead(self, source: Optional[str] = None) -> None:
+        """Give back the matrix ``decode_ahead`` was filling (every
+        source's when none is named): its lines are decoded again by
+        the batch that is handed them."""
+        for name in [self._spec(source).name] if source else \
+                list(self._staged):
+            staged = self._staged.pop(name, None)
+            if staged is not None:
+                staged.pool.release(staged.matrix)
+
+    def _encode_packed_native(
+        self, decoder, data: bytes, base_ms: int, spec: SourceSpec,
+        fmt: str, to_device: bool, ahead_bytes: int = 0,
+    ) -> "PackedRaw":
+        """The allocation-free hot path: acquire a pooled, persistent,
+        64-byte-aligned matrix already laid out as the packed H2D
+        transfer and let the decoder shards write straight into it.
+        The returned PackedRaw carries its pool slot; dispatch hands it
+        to the PendingBatch, which releases it when the batch lands (or
+        abandons) — never while the device step may still be reading
+        the zero-copied buffer.
+
+        ``ahead_bytes``: how many of ``data``'s leading bytes are the
+        lines ``decode_ahead`` was given since the last call, in its
+        order (0: none, and what it decoded is decoded again here, as
+        it is when the passes decoded against another base than
+        ``base_ms``). The passes that end inside them are kept; the
+        rest of ``data`` is decoded from their last slot on. The
+        matrix is the one this call alone would have written: the same
+        valid rows in the same order with the same cells, every other
+        slot zero (where a line is malformed its empty slot lies at the
+        end of its pass)."""
+        pool, layout, col_rows = self._ingest_plan(spec)
+        names = [c for c, _k in layout]
+        valid_row = len(layout)
+        staged = self._staged.pop(spec.name, None)
+        if staged is not None and staged.pool is not pool:
+            staged.pool.release(staged.matrix)
+            staged = None
+        # (one expression, so that the race lint sees the pool's matrix
+        # reach the hand-off below: analysis/racecheck.py)
+        mat = pool.acquire() if staged is None else staged.matrix
+        if staged is None:
+            staged = StagedBatch(pool, mat, base_ms)
+        ahead_ms, ahead_passes = staged.seconds * 1000.0, len(staged.passes)
+        try:
+            # a poll that cut before the passes' end is right: the
+            # passes past its cut go, and their lines come with a later
+            # one. So is a poll in another second than the passes
+            # expected (a second's edge between the deadline and the
+            # poll): their time cells are on the wrong base, all go
+            if fmt != "jsonl" or staged.base_ms != base_ms:
+                ahead_bytes = 0
+            staged.keep(min(ahead_bytes, len(data)))
+            staged.base_ms = base_ms
+            ahead_rows = staged.rows
+            self._count_ingest(
+                "malformed_rows", staged.slots - ahead_rows, malformed=True
+            )
             # the interval Decode_RowsPerSec times, as a span
             with _trace_span("native-decode"):
-                t0 = time.perf_counter()
                 if fmt == "kafka-v2":
-                    rows, kstats = decoder.decode_kafka_packed(
-                        data, mat, col_rows, valid_row, base_ms, max_rows=cap
+                    t0 = time.perf_counter()
+                    staged.rows, kstats = decoder.decode_kafka_packed(
+                        data, mat, col_rows, valid_row, base_ms,
+                        max_rows=spec.capacity,
                     )
+                    staged.seconds += time.perf_counter() - t0
                     self._count_ingest(
                         "malformed_rows", kstats["malformed"], malformed=True
                     )
@@ -1923,23 +2090,23 @@ class FlowProcessor:
                         "kafka_overflow_rows", kstats["overflow_dropped"]
                     )
                 else:
-                    rows, consumed = decoder.decode_packed(
-                        data, mat, col_rows, valid_row, base_ms, max_rows=cap
+                    self._decode_pass(
+                        decoder, data, staged, col_rows, valid_row
                     )
-                    self._count_jsonl_malformed(data, consumed, rows)
-                dt = time.perf_counter() - t0
         except Exception:
             pool.release(mat)
             raise
+        rows = staged.rows
+        self._count_ingest("bad_timestamps", staged.bad_ts)
         self.last_decoder_path = "native-sharded"
         self._decode_shards = decoder.last_shards
-        if dt > 0 and rows:
-            self._decode_rows_per_sec = rows / dt
-        if decoder.last_bad_timestamps:
-            self.ingest_stats["bad_timestamps"] = (
-                self.ingest_stats.get("bad_timestamps", 0)
-                + decoder.last_bad_timestamps
-            )
+        if staged.seconds > 0 and rows:
+            # the batch's rows over all its passes, the dropped ones'
+            # time included: the decoder's speed, wherever it ran
+            self._decode_rows_per_sec = rows / staged.seconds
+        self.decode_ahead_stats[spec.name] = (
+            ahead_rows, rows, ahead_ms, ahead_passes
+        )
         # rows the decoder doesn't own (Properties/SystemProperties):
         # the pool hands back dirty matrices, so (re)fill them per call
         # — one vectorized fill per extra row, not a fresh allocation

@@ -53,6 +53,11 @@ class UnackedFifo:
         with self._lock:
             return self._redeliver.pop(0) if self._redeliver else None
 
+    def redelivery_waits(self) -> bool:
+        """Whether the next ``next_redelivery`` returns a batch."""
+        with self._lock:
+            return bool(self._redeliver)
+
     def deliver(self, item) -> None:
         with self._lock:
             self._inflight.append(item)
@@ -333,6 +338,10 @@ class SocketSource(StreamingSource):
         # releases the oldest — a pipelined host holds several in flight
         self._fifo = UnackedFifo()
         self._seq = 0
+        # the connection whose lines ``arrived_lines`` has shown since the
+        # latest poll_raw, and whether that poll's blob began with them
+        self._shown: Optional[_Receiver] = None
+        self.polled_arrived = False
         self._server = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._server.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self._server.bind((host, port))
@@ -380,6 +389,39 @@ class SocketSource(StreamingSource):
         with self._lock:
             return sum(rx.rows for rx in self._receivers)
 
+    def arrived_lines(
+        self, skip_bytes: int = 0, skip_lines: int = 0
+    ) -> Optional[Tuple[memoryview, int]]:
+        """The whole lines that have arrived and are not yet delivered,
+        past the first ``skip_bytes`` bytes (``skip_lines`` lines) of
+        them: (their bytes, their count), for a caller that works on
+        lines before their poll (the host decodes them while it waits
+        for its interval). Nothing is delivered: the next ``poll_raw``
+        cuts where it would have cut, and says in ``polled_arrived``
+        whether its blob begins with the bytes shown here.
+
+        None when there is nothing to show, or when the next blob would
+        not begin with it: a requeued batch goes first, more than one
+        connection holds lines (the blob joins them), or blank lines
+        wait (the blob is rewritten without them).
+
+        The bytes are a view of the connection's receive buffer. They
+        stay as they are until the next ``poll_raw`` on the caller's
+        thread: the readers write only past the last whole line, and a
+        buffer that is replaced stays alive under the view."""
+        if self._fifo.redelivery_waits():
+            return None
+        with self._lock:
+            holding = [rx for rx in self._receivers if rx.rows or rx.blank]
+            if len(holding) != 1 or holding[0].blank:
+                return None
+            rx = holding[0]
+            start = rx.head + skip_bytes
+            if start >= rx.whole:
+                return None
+            self._shown = rx
+            return rx.view[start:rx.whole], rx.rows - skip_lines
+
     def poll_raw(self, max_events: int) -> Tuple[bytes, int, Offsets]:
         """Up to max_events raw JSON lines as one blob of whole,
         newline-terminated lines for the native decoder — no per-event
@@ -394,17 +436,21 @@ class SocketSource(StreamingSource):
         (at-least-once within the process; cross-restart replay needs a
         replayable upstream like the file/blob source)."""
         requeued = self._fifo.next_redelivery()
+        with self._lock:
+            shown, self._shown = self._shown, None
+            self.polled_arrived = False
         if requeued is not None:
             frm, blob, n = requeued
         else:
             with self._lock:
-                parts, n, blank = [], 0, 0
+                parts, n, blank, first = [], 0, 0, None
                 for rx in self._receivers:
                     part, rows, blanks = rx.take(max_events - n)
                     if rows:
                         parts.append(part)
                         n += rows
                         blank += blanks
+                        first = first or rx
                 blob = b"".join(parts)  # the batch's one copy
                 if blank:
                     # rare: what ``line.strip()`` did, by a second copy
@@ -413,6 +459,9 @@ class SocketSource(StreamingSource):
                         if line.strip()
                     )
                     self._grows += 1
+                # the shown connection's lines lead the blob as they lay
+                self.polled_arrived = \
+                    first is not None and first is shown and not blank
                 for rx in self._receivers:
                     rx.rewind()
                 self._receivers = [

@@ -1235,13 +1235,20 @@ int64_t dx_decode_mt(void* dv, const char* buf, int64_t len,
                         1 << 20);
 }
 
+// From this many bytes a packed decode that was asked for shards takes
+// them (a plain one from 1 MiB).
+constexpr int64_t kPackedShardBytes = 256 << 10;
+
 // Packed decode: newline-delimited JSON straight into the caller's
 // persistent [*, capacity] int32 H2D matrix (the pack_raw layout —
 // floats bitcast, bools widened, timestamps rebased to int32
 // batch-relative ms against base_ms, validity int32). col_rows[i] maps
 // decoder column i to its matrix row; valid_row is the validity row.
 // The decoder zeroes its own rows for [0, max_rows) first, so the
-// buffer pool can hand back reused (dirty) matrices for free.
+// buffer pool can hand back reused (dirty) matrices for free. `matrix`
+// may point at a later row slot of the caller's matrix (row_stride is
+// the whole matrix's): a batch decoded in passes hands each pass the
+// slot the one before stopped at, and max_rows its line count.
 // n_threads > 1 shards the decode (same dictionary-delta merge as
 // dx_decode_mt) with a lower engage threshold — the conf'd shard
 // count is an explicit ask.
@@ -1261,8 +1268,12 @@ int64_t dx_decode_packed(void* dv, const char* buf, int64_t len,
   memset(vrow, 0, (size_t)max_rows * 4);
   OutBufs out{ptrs.data(), nullptr, vrow, max_rows, true, base_ms};
   return decode_mt_impl(d, buf, len, max_rows, &out, consumed, n_threads,
-                        n_threads > 1 ? (256 << 10) : (1 << 20));
+                        n_threads > 1 ? kPackedShardBytes : (1 << 20));
 }
+
+// kPackedShardBytes, for a caller that decodes a batch in passes and
+// wants every pass multi-threaded.
+int64_t dx_packed_shard_bytes() { return kPackedShardBytes; }
 
 // Kafka v2 fast path: walk record batches (CRC-32C verified; corrupt
 // batches skipped + counted; control batches skipped; compressed

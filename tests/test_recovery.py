@@ -509,8 +509,8 @@ def test_decode_buffer_pool_safe_under_pipelined_window(tmp_path, depth):
         violations = []
         orig_encode = host.processor._encode_packed_native
 
-        def spy_encode(decoder, data, base_ms, spec, fmt, to_device):
-            pr = orig_encode(decoder, data, base_ms, spec, fmt, to_device)
+        def spy_encode(*args):
+            pr = orig_encode(*args)
             pool, mat = pr._ingest_pool
             if id(mat) in outstanding:
                 violations.append(id(mat))

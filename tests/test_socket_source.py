@@ -212,7 +212,116 @@ def polling_allocates_no_object_a_line(src):
     assert after - before < 300
 
 
+def arrived_lines_are_whole_lines_not_yet_delivered(src):
+    with _connect(src) as conn:
+        assert src.arrived_lines() is None  # nothing has arrived
+        conn.sendall(_line(1) + _line(2) + _line(3)[:20])
+        _wait_rows(src, 2)
+        time.sleep(0.05)
+        data, lines = src.arrived_lines()
+        # the unterminated tail is not shown, nor is anything delivered
+        assert (bytes(data), lines) == (_line(1) + _line(2), 2)
+        assert src.buffered_rows == 2
+        # a caller that has worked on them is shown what came since
+        assert src.arrived_lines(len(data), lines) is None
+        conn.sendall(_line(3)[20:] + _line(4))
+        _wait_rows(src, 4)
+        more, n = src.arrived_lines(len(data), lines)
+        assert (bytes(more), n) == (_line(3) + _line(4), 2)
+        # the poll delivers what it delivers without the views: the blob
+        # begins with what was shown
+        blob, n, offsets = src.poll_raw(3)
+        assert (blob, n) == (_line(1) + _line(2) + _line(3), 3)
+        assert offsets == {("socket", 0): (0, 3)}
+        assert src.polled_arrived and src.backlog_rows == 1
+        # what the cut left is shown again from its first byte
+        rest, n = src.arrived_lines()
+        assert (bytes(rest), n) == (_line(4), 1)
+        assert src.poll_raw(3)[:2] == (_line(4), 1) and src.polled_arrived
+        # a poll nothing was shown before says so
+        conn.sendall(_line(5))
+        _wait_rows(src, 1)
+        assert src.poll_raw(3)[:2] == (_line(5), 1)
+        assert not src.polled_arrived
+
+
+def arrived_lines_survive_a_replaced_buffer_and_a_rewind(src):
+    width = 8192  # 50 B a line: three widths outgrow the first buffer
+    lines = [_line(i) for i in range(4 * width)]
+    with _connect(src) as conn:
+        conn.sendall(b"".join(lines[:width]))
+        _wait_rows(src, width)
+        first, n = src.arrived_lines()
+        assert n == width
+        held = src._receivers[0].buf
+        conn.sendall(b"".join(lines[width:3 * width]))
+        _wait_rows(src, 3 * width)
+        # the buffer was replaced under the first view, which still
+        # reads the bytes it was given; the cursor holds in the new one
+        assert src._receivers[0].buf is not held
+        assert bytes(first) == b"".join(lines[:width])
+        more, n = src.arrived_lines(len(first), width)
+        assert n == 2 * width
+        assert bytes(more) == b"".join(lines[width:3 * width])
+        blob, n, _ = src.poll_raw(3 * width)
+        assert n == 3 * width and blob == b"".join(lines[:3 * width])
+        assert src.polled_arrived and src.buffer_grows > 0
+        # everything was delivered: the buffer was rewound, and the
+        # next lines are shown from its front
+        assert src._receivers[0].head == 0
+        conn.sendall(b"".join(lines[3 * width:]))
+        _wait_rows(src, width)
+        data, n = src.arrived_lines()
+        assert n == width and bytes(data) == b"".join(lines[3 * width:])
+        assert src.poll_raw(width)[0] == bytes(data) and src.polled_arrived
+
+
+def arrived_lines_are_withheld_when_the_blob_would_not_begin_with_them(src):
+    with _connect(src) as conn:
+        conn.sendall(_line(1) + _line(2))
+        _wait_rows(src, 2)
+        first = src.poll_raw(1)
+        src.requeue_unacked()
+        # a requeued batch goes first: its blob is its own bytes
+        assert src.arrived_lines() is None
+        assert src.poll_raw(1) == first and not src.polled_arrived
+        assert bytes(src.arrived_lines()[0]) == _line(2)
+        # blank lines wait: the blob is rewritten without them
+        conn.sendall(b"\n" + _line(3))
+        _wait_rows(src, 2)
+        assert src.arrived_lines() is None
+        blob, n, _ = src.poll_raw(10)
+        assert (blob, n) == (_line(2) + _line(3), 2)
+        assert not src.polled_arrived
+        # a second connection that holds lines: the blob joins the two
+        conn.sendall(_line(4))
+        _wait_rows(src, 1)
+        assert src.arrived_lines() is not None
+        with _connect(src) as other:
+            other.sendall(_line(5, "Heating"))
+            _wait_rows(src, 2)
+            assert src.arrived_lines() is None
+            # the lines shown before it came still lead the blob
+            blob, n, _ = src.poll_raw(10)
+            assert (blob, n) == (_line(4) + _line(5, "Heating"), 2)
+            assert src.polled_arrived
+            # an idle connection holds nothing back, and one that gets
+            # the blob's first lines after others were shown is told apart
+            conn.sendall(_line(6))
+            _wait_rows(src, 1)
+            assert bytes(src.arrived_lines()[0]) == _line(6)
+            other.sendall(_line(7, "Heating"))
+            _wait_rows(src, 2)
+            blob, n, _ = src.poll_raw(10)
+            assert n == 2 and sorted(blob.splitlines(True)) == sorted(
+                [_line(6), _line(7, "Heating")])
+            assert src.polled_arrived == blob.startswith(_line(6))
+
+
 CASES = [
+    arrived_lines_are_whole_lines_not_yet_delivered,
+    arrived_lines_survive_a_replaced_buffer_and_a_rewind,
+    arrived_lines_are_withheld_when_the_blob_would_not_begin_with_them,
     line_split_across_two_sends,
     crlf_and_blank_lines,
     unterminated_tail_waits_for_end_of_stream,
