@@ -152,6 +152,22 @@ class MetricName:
         # (runtime/host.py _pace): the share of the batch's rows decoded
         # before its poll, the host ms of those passes, and their count
         r"Decode_Ahead_(Pct|Ms|Passes)",
+        # the event's wait, taken where it waits (runtime/sources.py
+        # SocketSource stamps what each recv ended; runtime/host.py
+        # _traced_poll, _finish_tail): how long the batch's rows had
+        # been in the source when the poll cut (median, 95th percentile
+        # and the oldest, over the rows), and how old they were when
+        # the batch's sinks had landed them
+        r"Source_Wait_(P50|P95|Max)_Ms",
+        r"Event_Landing_(P50|P95)_Ms",
+        # what of a batch's chain, from its trace's begin to its emit,
+        # none of the spans decode, dispatch, device-step, collect and
+        # sinks holds; how far past its interval's end the paced loop
+        # began the batch; and the process's involuntary context
+        # switches since the batch before reported itself
+        r"Batch_Unspanned_Ms",
+        r"Loop_Late_Ms",
+        r"Host_Preempted_Count",
         r"Output_[A-Za-z0-9_.]+_Events_Count",
         r"Output_[A-Za-z0-9_.]+_(GroupsDropped|JoinRowsDropped)",
         r"Sink_[a-z]+",

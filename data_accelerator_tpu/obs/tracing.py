@@ -148,9 +148,9 @@ def add(**props) -> None:
     stack = getattr(_local, "stack", None)
     if stack:
         ctx, span_id = stack[-1]
-        open_props = ctx._open.get(span_id)
-        if open_props is not None:
-            open_props.update(props)
+        open_span = ctx._open.get(span_id)
+        if open_span is not None:
+            open_span[0].update(props)
 
 
 class TraceContext:
@@ -197,14 +197,37 @@ class TraceContext:
         # event (the poll's Source_Backlog_Rows): the trace is what
         # travels with a batch from its poll to its tail
         self.counters: Dict[str, float] = {}
-        # properties of the child spans still open, by span id (``add``)
-        self._open: Dict[str, Dict] = {}
+        # (properties, ``perf_counter`` at the start) of the child spans
+        # still open, by span id (``add``, ``unspanned_ms``)
+        self._open: Dict[str, tuple] = {}
+        # ms the root's closed children took, by name (a span seen more
+        # than once adds up)
+        self.child_ms: Dict[str, float] = {}
 
     # -- root ------------------------------------------------------------
     def add(self, **props) -> None:
         """Attach properties to the root span (e.g. batchTime once the
         poll has determined it)."""
         self._props.update(props)
+
+    def prop(self, name: str):
+        """A property of the root span (None when it was never added)."""
+        return self._props.get(name)
+
+    def unspanned_ms(self, names) -> float:
+        """What of the batch so far no span holds: the ms from the
+        trace's begin to the start of the innermost span open on THIS
+        thread (to now, where none is), less what the root's closed
+        children ``names`` took. With every stage of the chain among
+        ``names`` the rest is the time between spans. From what the
+        trace holds: no clock is read while a span is open."""
+        stack = getattr(_local, "stack", None)
+        open_span = self._open.get(stack[-1][1]) \
+            if stack and stack[-1][0] is self else None
+        upto = open_span[1] if open_span else time.perf_counter()
+        return (upto - self._start_pc) * 1000.0 - sum(
+            self.child_ms.get(name, 0.0) for name in names
+        )
 
     def end(self, **props) -> None:
         """Close the root span (idempotent — a retry path may race the
@@ -262,6 +285,7 @@ class TraceContext:
         """A span whose boundaries were measured externally (e.g. the
         device-step interval between dispatch return and completion
         sync, whose endpoints the host observed at different places)."""
+        self.child_ms[name] = self.child_ms.get(name, 0.0) + duration_ms
         self.tracer._emit_span(
             self, name, str(next(self._span_counter)), self.root_span_id,
             start_ts, duration_ms, props,
@@ -278,7 +302,7 @@ class TraceContext:
             # nest further children under this span on the same thread
             stack.append((self, span_id))
             pushed = True
-        self._open[span_id] = props
+        self._open[span_id] = (props, t0)
         try:
             with annotation(
                 name, batch=self._props.get("batchTime", self.trace_id)
@@ -288,9 +312,12 @@ class TraceContext:
             if pushed:
                 stack.pop()
             del self._open[span_id]
+            duration_ms = (time.perf_counter() - t0) * 1000.0
+            if parent_id == self.root_span_id:
+                self.child_ms[name] = \
+                    self.child_ms.get(name, 0.0) + duration_ms
             self.tracer._emit_span(
-                self, name, span_id, parent_id, start_ts,
-                (time.perf_counter() - t0) * 1000.0, props,
+                self, name, span_id, parent_id, start_ts, duration_ms, props,
             )
 
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import logging
 import os
+import resource
 import sys
 import tempfile
 import time
@@ -35,13 +36,22 @@ from ..obs.tracing import Tracer
 from .checkpoint import OffsetCheckpointer, WindowStateCheckpointer
 from .processor import FlowProcessor
 from .sinks import OutputDispatcher, build_output_operators
-from .sources import LocalSource, StreamingSource, make_source
+from .sources import LocalSource, StreamingSource, make_source, row_waits_ms
 
 logger = logging.getLogger(__name__)
 
 # The paced wait (``StreamingHost._pace``) wakes this often to decode the
 # lines that have arrived since its last pass.
 _AHEAD_SLICE_S = 0.02
+
+# The root's children that make up a batch's chain up to its ``emit``:
+# what of that stretch none of them holds is ``Batch_Unspanned_Ms``.
+_CHAIN_SPANS = ("decode", "dispatch", "device-step", "collect", "sinks")
+
+
+def _preemptions() -> int:
+    """Involuntary context switches of this process so far."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw
 
 
 class StreamingHost:
@@ -369,6 +379,12 @@ class StreamingHost:
 
         self.batches_processed = 0
         self._stop = False
+        # how far past its interval's end the paced loop began the pass
+        # that is about to start its batch (``run`` -> ``_start_batch``)
+        self._loop_late_ms: Optional[float] = None
+        # the process's involuntary context switches when the latest
+        # batch reported itself (Host_Preempted_Count)
+        self._preempted = _preemptions()
 
         # background result landing (the device-resident result path):
         # in the pipelined loop the only BLOCKING device read per batch
@@ -672,12 +688,13 @@ class StreamingHost:
         pm = self.protocol_monitor
         try:
             with trace.activate():
-                land_t0 = time.time()
                 with tracing.span("collect"):
                     datasets, metrics = handle.collect_tables()
-                land_ms = (time.time() - land_t0) * 1000.0
                 with tracing.span("sinks"):
                     self.dispatcher.dispatch(datasets, batch_time_ms)
+                    # when a reader could see the batch's rows: the
+                    # instant their age is taken at (Event_Landing_*)
+                    landed_ts = time.time()
                 if pm is not None:
                     pm.record("SINK_EMIT", detail="dispatcher.dispatch")
                 self.processor.commit()
@@ -728,13 +745,32 @@ class StreamingHost:
                         self.window_checkpointer.last_slots
                     )
             metrics.update(trace.counters)
+            polled_ts = trace.prop("polledTs")
+            if polled_ts is not None:
+                # the program's own alert latency: every row of the
+                # batch landed at one instant, so its age there is its
+                # wait at the poll's cut plus the chain from the cut
+                chain_ms = (landed_ts - polled_ts) * 1000.0
+                for q in ("P50", "P95"):
+                    metrics[f"Event_Landing_{q}_Ms"] = \
+                        metrics[f"Source_Wait_{q}_Ms"] + chain_ms
+            # the time between the chain's spans (commit, acks, the
+            # recorder's begin event; a pause that fell between two)
+            metrics["Batch_Unspanned_Ms"] = trace.unspanned_ms(_CHAIN_SPANS)
+            # since the batch before reported itself, its wait included
+            preempted = _preemptions()
+            metrics["Host_Preempted_Count"] = float(
+                preempted - self._preempted
+            )
+            self._preempted = preempted
             if backlog is not None:
                 # background landing accounting: landings still queued when
                 # this one was submitted (sustained > pipeline depth is the
                 # default backlog alert), and the ms this batch's streamed
                 # tables took to resolve on the landing thread
                 metrics["Transfer_Background_Pending"] = float(backlog)
-                metrics["Transfer_Background_LandMs"] = land_ms
+                metrics["Transfer_Background_LandMs"] = \
+                    trace.child_ms["collect"]
             self.health.record_stall(stall_ms)
             # the calibrated machine profile rides every batch as Calib_*
             # gauges (constant per process — dashboards see the machine
@@ -971,6 +1007,16 @@ class StreamingHost:
             ]
             if said:
                 trace.counters[counter] = float(sum(said))
+        # how long the batch's rows had waited in the sources that stamp
+        # arrivals when the poll cut, over the rows; the cut's time goes
+        # with the root span, for the age the tail takes at the landing
+        waits = row_waits_ms(self.sources.values())
+        if waits is not None:
+            polled_ts, (p50, p95), oldest = waits
+            trace.add(polledTs=polled_ts)
+            trace.counters["Source_Wait_P50_Ms"] = p50
+            trace.counters["Source_Wait_P95_Ms"] = p95
+            trace.counters["Source_Wait_Max_Ms"] = oldest
         # what the wait before this poll took off the batch's decode:
         # the share of its rows decoded by then, and the passes' cost
         ahead = [
@@ -1006,6 +1052,9 @@ class StreamingHost:
         (bad payload, re-trace error) requeues the polled batch so a
         later batch's ack can't release it unprocessed."""
         trace = self.tracer.begin("streaming/batch")
+        late_ms, self._loop_late_ms = self._loop_late_ms, None
+        if late_ms is not None:
+            trace.counters["Loop_Late_Ms"] = late_ms
         try:
             raw, consumed, batch_time_ms, t0 = self._traced_poll(trace)
             handle = self._dispatch_traced(trace, raw, batch_time_ms)
@@ -1122,13 +1171,19 @@ class StreamingHost:
     def run(self, max_batches: Optional[int] = None) -> None:
         """Paced loop (streaming.intervalInSeconds cadence,
         StreamingHost.scala:66-67)."""
+        deadline = None
         try:
             while not self._stop:
                 start = time.time()
+                if deadline is not None:
+                    # the sleep's overshoot, or the overrun of a batch
+                    # that was busy for longer than its interval
+                    self._loop_late_ms = (start - deadline) * 1000.0
                 self.run_batch()
                 if max_batches is not None and self.batches_processed >= max_batches:
                     break
-                self._pace(start + self.interval_s)
+                deadline = start + self.interval_s
+                self._pace(deadline)
         finally:
             # a matrix the wait was filling goes back to its pool
             self.processor.drop_decode_ahead()

@@ -27,6 +27,8 @@ import time
 from datetime import datetime, timedelta, timezone
 from typing import Dict, Iterator, List, Optional, Tuple
 
+import numpy as np
+
 from ..core.schema import Schema, StringDictionary
 from ..native import load_library, scan_lines
 from ..utils import fs
@@ -84,6 +86,11 @@ class StreamingSource:
     # reallocate its receive buffer or copy a delivered blob a second
     # time (Source_Buffer_Grow_Count); None: no such buffer
     buffer_grows: Optional[int] = None
+    # when the latest poll cut its batch (``time.time()``) and when that
+    # batch's rows had arrived: (polled_ts, [arrival], [rows that arrived
+    # then]), oldest first (``row_waits_ms`` reads it); None: the source
+    # has no notion of an arrival
+    wait_stamps: Optional[Tuple[float, List[float], List[int]]] = None
 
     def start(self, positions: Dict[Tuple[str, int], int]) -> None:
         """Apply checkpointed starting positions (source, partition)->seq."""
@@ -235,6 +242,13 @@ class FileSource(StreamingSource):
 _RECV_BUFFER_BYTES = 1 << 20
 _RECV_ROOM_BYTES = 1 << 16
 _ALL_LINES = 1 << 62
+# Lines that arrive within this long of a connection's newest arrival
+# stamp share it (a second holds at most ~1,000 stamps, whatever the
+# kernel's segmenting), and a connection nobody polls keeps at most
+# _STAMP_LIMIT of them: neighbours then merge, each pair under the
+# earlier one's time.
+_STAMP_MERGE_S = 0.001
+_STAMP_LIMIT = 4096
 
 
 class _Receiver:
@@ -242,14 +256,19 @@ class _Receiver:
     ``[head, whole)`` is whole lines not yet delivered (``rows`` of them
     hold more than whitespace, ``blank`` do not), ``[whole, tail)`` the
     unterminated tail of the newest line. No object a line: lines are
-    counted where they lie (``native.scan_lines``). Every field is
-    guarded by the source's lock."""
+    counted where they lie (``native.scan_lines``). ``stamp_ts`` /
+    ``stamp_rows`` say when the ``rows`` waiting lines arrived: the
+    clock at the ``recv`` that ended them and how many it ended, oldest
+    first (rows, not bytes: ``make_room`` and ``rewind`` leave them as
+    they are). Every field is guarded by the source's lock."""
 
     def __init__(self):
         self.buf = bytearray(_RECV_BUFFER_BYTES)
         self.view = memoryview(self.buf)
         self.head = self.whole = self.tail = 0
         self.rows = self.blank = 0
+        self.stamp_ts: List[float] = []
+        self.stamp_rows: List[int] = []
         self.closed = False
 
     def make_room(self) -> bool:
@@ -274,11 +293,25 @@ class _Receiver:
         newline = self.buf.rfind(b"\n", self.tail, self.tail + n)
         self.tail += n
         if newline >= 0:
+            now = time.time()  # the spans' clock
             rows, self.whole, blank = scan_lines(
                 self.buf, self.whole, newline + 1, _ALL_LINES
             )
             self.rows += rows
             self.blank += blank
+            if rows:
+                self._stamp(now, rows)
+
+    def _stamp(self, now: float, rows: int) -> None:
+        ts, counts = self.stamp_ts, self.stamp_rows
+        if ts and now - ts[-1] < _STAMP_MERGE_S:
+            counts[-1] += rows
+            return
+        if len(ts) >= _STAMP_LIMIT:
+            counts[:] = [a + b for a, b in zip(counts[::2], counts[1::2])]
+            ts[:] = ts[::2]
+        ts.append(now)
+        counts.append(rows)
 
     def end_of_stream(self) -> None:
         """The peer closed: a last line without its newline is
@@ -288,22 +321,38 @@ class _Receiver:
             self.received(1)
         self.closed = True
 
-    def take(self, max_lines: int) -> Tuple[memoryview, int, int]:
+    def take(
+        self, max_lines: int
+    ) -> Tuple[memoryview, int, int, List[float], List[int]]:
         """Hand over the oldest waiting lines, at most ``max_lines``
         non-blank ones: (their bytes, the non-blank lines among them,
-        the blank ones). The bytes are a view: copy them before the
-        lock is released or ``rewind`` is called."""
+        the blank ones, their arrival stamps' times and rows). The
+        bytes are a view: copy them before the lock is released or
+        ``rewind`` is called. A cut inside a stamp's rows leaves the
+        rest of them, with their arrival time, for the next take."""
         if self.rows <= max_lines:
             rows, cut, blank = self.rows, self.whole, self.blank
+            ts, counts = self.stamp_ts, self.stamp_rows
+            self.stamp_ts, self.stamp_rows = [], []
         else:
             rows, cut, blank = scan_lines(
                 self.buf, self.head, self.whole, max_lines
             )
+            k, left = 0, rows
+            while left and self.stamp_rows[k] <= left:
+                left -= self.stamp_rows[k]
+                k += 1
+            ts, counts = self.stamp_ts[:k], self.stamp_rows[:k]
+            del self.stamp_ts[:k], self.stamp_rows[:k]
+            if left:
+                ts.append(self.stamp_ts[0])
+                counts.append(left)
+                self.stamp_rows[0] -= left
         part = self.view[self.head:cut]
         self.head = cut
         self.rows -= rows
         self.blank -= blank
-        return part, rows, blank
+        return part, rows, blank, ts, counts
 
     def rewind(self) -> None:
         """Once every whole line is delivered the next bytes land at
@@ -334,8 +383,9 @@ class SocketSource(StreamingSource):
         self._lock = threading.Lock()
         self._receivers: List[_Receiver] = []
         self._grows = 0
-        # un-acked delivered batches (from_seq, blob, rows); ack()
-        # releases the oldest — a pipelined host holds several in flight
+        # un-acked delivered batches (from_seq, blob, rows, the rows'
+        # arrival stamps); ack() releases the oldest — a pipelined host
+        # holds several in flight
         self._fifo = UnackedFifo()
         self._seq = 0
         # the connection whose lines ``arrived_lines`` has shown since the
@@ -434,23 +484,32 @@ class SocketSource(StreamingSource):
         after ``requeue_unacked()`` (a failed batch) the next polls
         re-deliver the un-acked batches byte for byte, in order
         (at-least-once within the process; cross-restart replay needs a
-        replayable upstream like the file/blob source)."""
+        replayable upstream like the file/blob source).
+
+        ``wait_stamps`` then says when the poll cut and when the
+        batch's rows had arrived; a re-delivered batch keeps the stamps
+        of its first arrival."""
         requeued = self._fifo.next_redelivery()
         with self._lock:
             shown, self._shown = self._shown, None
             self.polled_arrived = False
         if requeued is not None:
-            frm, blob, n = requeued
+            frm, blob, n, stamps = requeued
+            polled_ts = time.time()
         else:
             with self._lock:
                 parts, n, blank, first = [], 0, 0, None
+                stamps: Tuple[List[float], List[int]] = ([], [])
                 for rx in self._receivers:
-                    part, rows, blanks = rx.take(max_events - n)
+                    part, rows, blanks, ts, counts = rx.take(max_events - n)
                     if rows:
                         parts.append(part)
                         n += rows
                         blank += blanks
                         first = first or rx
+                        stamps[0].extend(ts)
+                        stamps[1].extend(counts)
+                polled_ts = time.time()  # the cut
                 blob = b"".join(parts)  # the batch's one copy
                 if blank:
                     # rare: what ``line.strip()`` did, by a second copy
@@ -475,7 +534,8 @@ class SocketSource(StreamingSource):
                 self.buffer_grows, self._grows = self._grows, 0
                 frm = self._seq
                 self._seq += n
-        self._fifo.deliver((frm, blob, n))
+        self._fifo.deliver((frm, blob, n, stamps))
+        self.wait_stamps = (polled_ts, *stamps)
         return blob, n, {(self.name, 0): (frm, frm + n)}
 
     def ack(self) -> None:
@@ -985,6 +1045,41 @@ class KafkaSource(StreamingSource):
             self._consumer.close()
         except Exception:  # noqa: BLE001
             pass
+
+
+def row_waits_ms(
+    sources, quantiles=(0.5, 0.95)
+) -> Optional[Tuple[float, List[float], float]]:
+    """How long the rows of the batch that ``sources`` just polled had
+    waited in them when its last source cut: (that cut's time, the
+    waits' ``quantiles`` over the batch's rows in ms, the oldest row's
+    wait), from the ``wait_stamps`` of the sources that report them. A
+    stamp counts once for each of its rows, and a quantile is the one
+    ``numpy.percentile`` (linear) gives over the rows. None when no
+    source reports an arrival, or the batch has no row."""
+    said = [s.wait_stamps for s in sources if s.wait_stamps is not None]
+    arrivals = [t for _polled, ts, _rows in said for t in ts]
+    if not arrivals:
+        return None
+    polled_ts = max(polled for polled, _ts, _rows in said)
+    waits = (polled_ts - np.asarray(arrivals, np.float64)) * 1000.0
+    order = np.argsort(waits, kind="stable")
+    waits = waits[order]
+    # rows up to and with each stamp, youngest first
+    upto = np.cumsum(np.asarray(
+        [n for _polled, _ts, rows in said for n in rows], np.int64
+    )[order])
+    last = int(upto[-1]) - 1
+    at = np.asarray(quantiles, np.float64) * last
+    lo = np.floor(at).astype(np.int64)
+    # the k-th row (from 0) lies in the first stamp that ends past k
+    below = waits[np.searchsorted(upto, lo, side="right")]
+    above = waits[np.searchsorted(upto, np.minimum(lo + 1, last), side="right")]
+    return (
+        polled_ts,
+        [float(q) for q in below + (above - below) * (at - lo)],
+        float(waits[-1]),
+    )
 
 
 def make_source(conf, schema: Schema, source: str = "default") -> StreamingSource:

@@ -7,7 +7,9 @@ step, a sink or a checkpoint can see.
     the provisional base or one or two seconds past it, against the
     one-shot ``decode_packed`` of the same blob at that base;
 (b) is in ``tests/test_socket_source.py`` (the arrived-lines view);
-(c) ``StreamingHost.run`` on a socket fed across the wait."""
+(c) ``StreamingHost.run`` on a socket fed across the wait;
+(d) what every batch of such a host says of its rows' wait, of the time
+    none of its spans holds and of how late the loop began it."""
 
 import json
 import random
@@ -699,3 +701,185 @@ def test_a_batch_off_the_packed_path_leaves_no_stale_ahead_stats(tmp_path):
     assert proc.decode_ahead_stats["default"] == (0, 1, 0.0, 0)
     proc.encode_json_bytes(line, BASE_MS, packed=False)
     assert proc.decode_ahead_stats == {}
+
+
+# -- (d) the event's wait, and what no span holds ----------------------------
+
+WAIT = ("Source_Wait_P50_Ms", "Source_Wait_P95_Ms", "Source_Wait_Max_Ms")
+LANDING = ("Event_Landing_P50_Ms", "Event_Landing_P95_Ms")
+CHAIN = ("decode", "dispatch", "device-step", "collect", "sinks")
+
+
+class _Recorder:
+    """A flight recorder in memory: a batch's end event and its spans."""
+
+    def __init__(self, host):
+        self.records = []
+        host.telemetry.writers.append(self)
+
+    def write(self, record):
+        self.records.append(record)
+
+    def batches(self):
+        """(the end event's measurements, name -> (start s, ms) of the
+        batch's spans, the root span's properties) a batch, in order."""
+        spans, roots = {}, {}
+        for r in self.records:
+            if r["type"] == "span":
+                spans.setdefault(r["trace"], {})[r["name"]] = (
+                    r["startTs"], r["durationMs"])
+                if r["name"] == "streaming/batch":
+                    roots[r["properties"]["batchTime"]] = r
+        return [
+            (r["measurements"],
+             spans[roots[r["properties"]["batchTime"]]["trace"]],
+             roots[r["properties"]["batchTime"]]["properties"])
+            for r in self.records if r.get("name") == "streaming/batch/end"]
+
+
+def _unspanned_by_the_spans(spans):
+    begin = spans["streaming/batch"][0]
+    return (spans["emit"][0] - begin) * 1000.0 - sum(
+        spans[name][1] for name in CHAIN)
+
+
+def test_every_batch_says_how_old_its_rows_were_and_what_no_span_holds(
+    tmp_path
+):
+    host, src, sink, metrics = _host(tmp_path)
+    rec = _Recorder(host)
+    groups = [range(0, 300), range(300, 900), range(900, 1500),
+              range(1500, 1800)]
+    try:
+        feeder = _send_after_each_landing(src, sink, groups)
+        _wait_rows(src, 300)
+        time.sleep(0.1)
+        host.run(max_batches=5)  # _host() ran the first
+        feeder.join(30)
+        batches = rec.batches()
+        assert [m for m, _sp, _root in batches] == metrics
+        assert [m[INPUT_ROWS] for m in metrics] == [300, 600, 600, 300]
+        for i, (m, spans, root) in enumerate(batches):
+            assert all(k in m for k in WAIT + LANDING), sorted(m)
+            assert 0.0 <= m["Source_Wait_P50_Ms"] <= m["Source_Wait_P95_Ms"] \
+                <= m["Source_Wait_Max_Ms"]
+            # every row of a batch lands at one instant: its age there
+            # is its wait at the cut plus the chain from the cut to the
+            # end of ``sinks``
+            sinks_end = spans["sinks"][0] + spans["sinks"][1] / 1000.0
+            chain_ms = (sinks_end - root["polledTs"]) * 1000.0
+            assert chain_ms > 0.0
+            for q in ("P50", "P95"):
+                assert m[f"Event_Landing_{q}_Ms"] - m[f"Source_Wait_{q}_Ms"] \
+                    == pytest.approx(chain_ms, abs=0.1)
+            # the cut lies inside the poll
+            poll = spans["source-poll"]
+            assert poll[0] <= root["polledTs"] <= poll[0] + poll[1] / 1000.0
+            assert m["Batch_Unspanned_Ms"] >= 0.0
+            assert m["Batch_Unspanned_Ms"] == pytest.approx(
+                _unspanned_by_the_spans(spans), abs=0.1)
+            assert m["Batch_Unspanned_Ms"] < m["Latency-Batch"]
+            assert m["Host_Preempted_Count"] >= 0.0
+            # a run's first batch follows no wait: nothing to be late for
+            assert ("Loop_Late_Ms" in m) == (i > 0)
+            assert m.get("Loop_Late_Ms", 0.0) >= 0.0
+            assert m.get("Loop_Late_Ms", 0.0) < INTERVAL_S * 1000.0
+        # the first group waited 100 ms and more for the run to begin;
+        # the others were sent in the ~60 ms after a landing and waited
+        # out what was left of the interval
+        assert metrics[0]["Source_Wait_P50_Ms"] >= 100.0
+        for m in metrics[1:]:
+            assert 0.5 * INTERVAL_S * 1000.0 < m["Source_Wait_P50_Ms"] \
+                < INTERVAL_S * 1000.0
+            assert m["Event_Landing_P50_Ms"] < 1.5 * INTERVAL_S * 1000.0
+        assert _pool_is_whole(host)
+    finally:
+        host.stop()
+
+
+def test_a_redelivered_batch_is_as_old_as_its_first_arrival(tmp_path):
+    host, src, sink, metrics = _host(tmp_path)
+    real_step = host.processor._step
+    calls = []
+
+    def failing_step(*a, **kw):
+        calls.append(1)
+        if len(calls) == 1:
+            raise RuntimeError("dispatch boom")
+        return real_step(*a, **kw)
+
+    try:
+        host.processor._step = failing_step
+        conn = socket.create_connection(("127.0.0.1", src.port), 5.0)
+        conn.sendall(b"".join(_payload(range(200))))
+        _wait_rows(src, 200)
+        with pytest.raises(RuntimeError, match="dispatch boom"):
+            host.run(max_batches=2)
+        time.sleep(0.3)
+        host.run(max_batches=2)
+        conn.close()
+        assert sink.batches == [list(range(200))]
+        # the rows' age runs from when they first came in, not from
+        # their second poll
+        assert metrics[0]["Source_Wait_P50_Ms"] >= 300.0
+        assert metrics[0]["Event_Landing_P50_Ms"] \
+            > metrics[0]["Source_Wait_P50_Ms"]
+    finally:
+        host.stop()
+
+
+def test_the_unpaced_loop_has_no_deadline_to_be_late_for(tmp_path):
+    host, src, _sink, _metrics = _host(tmp_path)
+    rec = _Recorder(host)
+    try:
+        conn = socket.create_connection(("127.0.0.1", src.port), 5.0)
+        conn.sendall(b"".join(_payload(range(900))))
+        _wait_rows(src, 900)
+        host._rate_scale = 0.2  # a poll admits 400 rows
+        host.run_pipelined(max_batches=4)  # _host() ran the first
+        conn.close()
+        batches = rec.batches()
+        assert [m[INPUT_ROWS] for m, _sp, _root in batches] == [400, 400, 100]
+        for m, spans, _root in batches:
+            assert all(k in m for k in WAIT + LANDING)
+            assert "Loop_Late_Ms" not in m
+            assert m["Batch_Unspanned_Ms"] == pytest.approx(
+                _unspanned_by_the_spans(spans), abs=0.1)
+            # the landing thread's time to resolve the streamed tables
+            # is the ``collect`` span's: one measurement, not two
+            assert m["Transfer_Background_LandMs"] == pytest.approx(
+                spans["collect"][1], abs=1e-3)
+        # a backlog's rows are older at each poll
+        waits = [m["Source_Wait_P50_Ms"] for m, _sp, _root in batches]
+        assert waits == sorted(waits)
+    finally:
+        host.stop()
+
+
+def test_a_source_with_no_notion_of_arrival_reports_no_wait(tmp_path):
+    t = tmp_path / "local.transform"
+    t.write_text(TRANSFORM)
+    host = StreamingHost(SettingDictionary({
+        "datax.job.name": "LocalWait",
+        "datax.job.input.default.inputtype": "local",
+        "datax.job.input.default.blobschemafile": SCHEMA_JSON,
+        "datax.job.input.default.eventhub.maxrate": "100",
+        "datax.job.input.default.streaming.intervalinseconds": "0.2",
+        "datax.job.process.transform": str(t),
+        "datax.job.process.batchcapacity": "64",
+        "datax.job.output.Out.console.maxrows": "0",
+    }))
+    rec = _Recorder(host)
+    try:
+        host.run(max_batches=3)
+        batches = rec.batches()
+        assert len(batches) == 3
+        for i, (m, spans, root) in enumerate(batches):
+            assert not any(k in m for k in WAIT + LANDING)
+            assert "polledTs" not in root
+            assert m["Batch_Unspanned_Ms"] == pytest.approx(
+                _unspanned_by_the_spans(spans), abs=0.1)
+            assert m["Host_Preempted_Count"] >= 0.0
+            assert ("Loop_Late_Ms" in m) == (i > 0)
+    finally:
+        host.stop()

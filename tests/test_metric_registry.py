@@ -106,6 +106,9 @@ def test_every_runtime_metric_is_registered(running_flow_store):
     assert any(m.startswith("Input_") for m in metrics)
     assert any(m.startswith("Output_") for m in metrics)
     assert any(m.startswith("Sink_") for m in metrics)
+    # what every batch says of itself, whatever its source
+    assert {"Batch_Unspanned_Ms", "Host_Preempted_Count",
+            "Loop_Late_Ms"} <= metrics
 
 
 def test_stage_names_round_trip_to_registered_metrics():
@@ -212,6 +215,28 @@ def test_background_transfer_metrics_are_registered():
         assert not MetricName.is_runtime_metric(m), m
     assert not MetricName.is_runtime_metric("Transfer_Background_Bogus")
     assert not MetricName.is_runtime_metric("Sync_Bogus")
+
+
+def test_event_wait_metrics_are_registered():
+    """The event's wait and what no span holds (runtime/sources.py
+    arrival stamps; runtime/host.py _traced_poll, _finish_tail, run)
+    resolve through the registry; emission-side coverage is
+    tests/test_decode_ahead.py (d) and the store check above, whose
+    local-source flow emits the three that need no arrival."""
+    for m in (
+        "Source_Wait_P50_Ms",
+        "Source_Wait_P95_Ms",
+        "Source_Wait_Max_Ms",
+        "Event_Landing_P50_Ms",
+        "Event_Landing_P95_Ms",
+        "Batch_Unspanned_Ms",
+        "Loop_Late_Ms",
+        "Host_Preempted_Count",
+    ):
+        assert MetricName.is_runtime_metric(m), m
+    for m in ("Source_Wait_P99_Ms", "Event_Landing_Max_Ms",
+              "Batch_Unspanned", "Loop_Late"):
+        assert not MetricName.is_runtime_metric(m), m
 
 
 def test_state_partition_metrics_are_registered():

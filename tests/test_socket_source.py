@@ -7,6 +7,7 @@ import gc
 import json
 import socket
 import sys
+import threading
 import time
 
 import numpy as np
@@ -14,7 +15,13 @@ import pytest
 
 from data_accelerator_tpu.core.schema import Schema, StringDictionary
 from data_accelerator_tpu.native import NativeDecoder
-from data_accelerator_tpu.runtime.sources import SocketSource
+from data_accelerator_tpu.runtime import sources
+from data_accelerator_tpu.runtime.sources import (
+    LocalSource,
+    SocketSource,
+    _Receiver,
+    row_waits_ms,
+)
 
 SCHEMA = Schema.from_spark_json(json.dumps({
     "type": "struct",
@@ -318,7 +325,191 @@ def arrived_lines_are_withheld_when_the_blob_would_not_begin_with_them(src):
             assert src.polled_arrived == blob.startswith(_line(6))
 
 
+def _stamps_count_the_waiting_rows(src):
+    with src._lock:
+        for rx in src._receivers:
+            assert sum(rx.stamp_rows) == rx.rows
+            assert rx.stamp_ts == sorted(rx.stamp_ts)
+            assert len(rx.stamp_ts) == len(rx.stamp_rows)
+
+
+def wait_quantiles_weigh_a_stamp_by_its_rows(src):
+    with _connect(src) as conn:
+        first_sent = time.time()
+        conn.sendall(b"".join(_line(i) for i in range(100)))
+        _wait_rows(src, 100)
+        time.sleep(0.2)
+        second_sent = time.time()
+        conn.sendall(b"".join(_line(i) for i in range(100, 400)))
+        _wait_rows(src, 400)
+        time.sleep(0.1)
+        _stamps_count_the_waiting_rows(src)
+        before = time.time()
+        assert src.poll_raw(1000)[1] == 400
+        polled_ts, ts, rows = src.wait_stamps
+        assert before <= polled_ts <= time.time()
+        assert sum(rows) == 400 and ts == sorted(ts)
+        assert first_sent <= ts[0] and second_sent <= ts[-1] <= before
+        # 300 of the 400 rows came with the second chunk: the median is
+        # theirs, the 95th percentile and the oldest the first chunk's
+        old = (polled_ts - first_sent) * 1000.0
+        new = (polled_ts - second_sent) * 1000.0
+        assert old - new >= 200.0
+        got_ts, (p50, p95, p10), oldest = row_waits_ms(
+            [src], (0.5, 0.95, 0.1))
+        assert got_ts == polled_ts
+        assert new - 30.0 <= p10 <= p50 <= new
+        assert old - 30.0 <= p95 <= oldest <= old
+        # what numpy says of the rows, one value a row
+        per_row = np.repeat((polled_ts - np.asarray(ts)) * 1000.0, rows)
+        np.testing.assert_allclose(
+            [p10, p50, p95, oldest],
+            list(np.percentile(per_row, [10, 50, 95])) + [per_row.max()])
+        # a poll that finds nothing says when it cut, and of no row
+        assert src.poll_raw(1000)[1] == 0
+        assert src.wait_stamps[1:] == ([], []) and row_waits_ms([src]) is None
+
+
+def a_cut_leaves_the_rest_of_a_stamps_rows_their_arrival(src):
+    with _connect(src) as conn:
+        conn.sendall(b"".join(_line(i) for i in range(10)))
+        _wait_rows(src, 10)
+        with src._lock:
+            came = list(src._receivers[0].stamp_ts)  # when the ten did
+        assert src.poll_raw(4)[1] == 4
+        polled, ts, rows = src.wait_stamps
+        assert sum(rows) == 4 and set(ts) <= set(came)
+        _stamps_count_the_waiting_rows(src)
+        time.sleep(0.1)
+        conn.sendall(_line(10))
+        _wait_rows(src, 7)
+        assert src.poll_raw(4)[1] == 4
+        later, ts2, rows2 = src.wait_stamps
+        # the backlog's rows are as old as they are: they came with the
+        # first send, not with this poll nor with the line sent since
+        assert sum(rows2) == 4 and set(ts2) <= set(came)
+        assert later - polled >= 0.1
+        assert row_waits_ms([src], (0.0,))[1][0] >= 100.0
+        _stamps_count_the_waiting_rows(src)
+        assert src.poll_raw(4)[1] == 3
+        _polled, ts3, rows3 = src.wait_stamps
+        assert sum(rows3) == 3 and rows3[-1] == 1
+        assert ts3[0] in came and ts3[-1] - came[-1] >= 0.1
+        assert src._receivers[0].stamp_ts == []
+
+
+def a_requeued_batch_is_as_old_as_its_first_arrival(src):
+    with _connect(src) as conn:
+        conn.sendall(b"".join(_line(i) for i in range(6)))
+        _wait_rows(src, 6)
+        first = src.poll_raw(3)
+        polled, ts, rows = src.wait_stamps
+        waited = row_waits_ms([src])
+        second = src.poll_raw(3)
+        stamps2 = src.wait_stamps[1:]
+        src.requeue_unacked()
+        time.sleep(0.1)
+        assert src.poll_raw(3) == first
+        again, ts_again, rows_again = src.wait_stamps
+        assert (ts_again, rows_again) == (ts, rows) and again - polled >= 0.1
+        assert row_waits_ms([src])[2] >= waited[2] + 100.0
+        assert src.poll_raw(3) == second and src.wait_stamps[1:] == stamps2
+
+
+def two_connections_stamps_pool(src):
+    with _connect(src) as a, _connect(src) as b:
+        a.sendall(_line(1) + _line(2) + _line(3))
+        _wait_rows(src, 3)
+        time.sleep(0.15)
+        b.sendall(b"".join(_line(i, "Heating") for i in range(5)))
+        _wait_rows(src, 8)
+        assert src.poll_raw(100)[1] == 8
+        polled, ts, rows = src.wait_stamps
+        assert sum(rows) == 8
+        by_arrival = sorted(zip(ts, rows))
+        assert by_arrival[-1][0] - by_arrival[0][0] >= 0.15
+        assert sum(n for t, n in by_arrival
+                   if t - by_arrival[0][0] < 0.1) == 3
+        # five of the eight rows are the younger ones
+        _ts, (p50,), oldest = row_waits_ms([src], (0.5,))
+        assert oldest - p50 >= 150.0
+        # a source with no notion of an arrival is not pooled, nor asked
+        local = LocalSource(SCHEMA)
+        assert local.wait_stamps is None
+        assert row_waits_ms([local]) is None
+        assert row_waits_ms([local, src]) == row_waits_ms([src])
+
+
+def a_replaced_buffer_and_a_rewind_lose_no_stamp(src):
+    width = 8192  # 50 B a line: three widths outgrow the first buffer
+    lines = [_line(i) for i in range(4 * width)]
+    with _connect(src) as conn:
+        conn.sendall(b"".join(lines[:3 * width]))
+        _wait_rows(src, 3 * width)
+        _stamps_count_the_waiting_rows(src)
+        first_arrival = src._receivers[0].stamp_ts[0]
+        grows = 0
+        for k in range(3):
+            assert src.poll_raw(width)[1] == width
+            grows += src.buffer_grows
+            polled, ts, rows = src.wait_stamps
+            assert sum(rows) == width and ts == sorted(ts)
+            assert first_arrival <= ts[0] and ts[-1] <= polled
+            _stamps_count_the_waiting_rows(src)
+        assert grows > 0  # make_room took a fresh buffer on the way in
+        # everything was delivered: the buffer was rewound
+        assert src._receivers[0].head == 0
+        assert src._receivers[0].stamp_ts == []
+        conn.sendall(b"".join(lines[3 * width:]))
+        _wait_rows(src, width)
+        _stamps_count_the_waiting_rows(src)
+        assert src.poll_raw(width)[1] == width
+        assert sum(src.wait_stamps[2]) == width
+        assert src.wait_stamps[1][0] > polled
+
+
+def stamps_hold_under_many_senders_and_a_polling_thread(src):
+    """More senders than cores, the interpreter switching threads every
+    10 us: every poll's stamps count its rows, and none is lost."""
+    senders, per_sender = 16, 1500
+    conns = [_connect(src) for _ in range(senders)]
+
+    def send(conn, k):
+        data = b"".join(_line(k * per_sender + i) for i in range(per_sender))
+        for at in range(0, len(data), 997):
+            conn.sendall(data[at:at + 997])
+        conn.close()
+
+    threads = [threading.Thread(target=send, args=(c, k), daemon=True)
+               for k, c in enumerate(conns)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        got, deadline = 0, time.time() + 60
+        while got < senders * per_sender and time.time() < deadline:
+            n = src.poll_raw(700)[1]
+            polled, ts, rows = src.wait_stamps
+            assert sum(rows) == n and len(ts) == len(rows)
+            assert all(r > 0 for r in rows) and all(t <= polled for t in ts)
+            _stamps_count_the_waiting_rows(src)
+            got += n
+        for t in threads:
+            t.join(30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert got == senders * per_sender and src.buffered_rows == 0
+
+
 CASES = [
+    stamps_hold_under_many_senders_and_a_polling_thread,
+    wait_quantiles_weigh_a_stamp_by_its_rows,
+    a_cut_leaves_the_rest_of_a_stamps_rows_their_arrival,
+    a_requeued_batch_is_as_old_as_its_first_arrival,
+    two_connections_stamps_pool,
+    a_replaced_buffer_and_a_rewind_lose_no_stamp,
     arrived_lines_are_whole_lines_not_yet_delivered,
     arrived_lines_survive_a_replaced_buffer_and_a_rewind,
     arrived_lines_are_withheld_when_the_blob_would_not_begin_with_them,
@@ -340,3 +531,44 @@ def test_socket_source(case):
         case(src)
     finally:
         src.close()
+
+
+def _receive(rx, data: bytes):
+    rx.make_room()
+    rx.buf[rx.tail:rx.tail + len(data)] = data
+    rx.received(len(data))
+
+
+@pytest.mark.parametrize("step_s, most", [
+    (0.0001, 1 + 10_000 // 10),  # ten recvs a millisecond share a stamp
+    (0.002, sources._STAMP_LIMIT),  # nobody polls: neighbours merge
+], ids=["within_a_millisecond", "over_the_limit"])
+def test_arrival_stamps_coalesce_and_stay_bounded(monkeypatch, step_s, most):
+    clock = [1_700_000_000.0]
+
+    def ticking():
+        clock[0] += step_s
+        return clock[0]
+
+    monkeypatch.setattr(sources.time, "time", ticking)
+    rx = _Receiver()
+    for i in range(10_000):
+        # a recv that ends no line is not stamped, nor is a blank line
+        _receive(rx, _line(i)[:20])
+        _receive(rx, _line(i)[20:] + (b"\n" if i % 100 == 0 else b""))
+        assert len(rx.stamp_ts) <= most
+    assert rx.rows == 10_000 == sum(rx.stamp_rows) and rx.blank == 100
+    assert rx.stamp_ts == sorted(set(rx.stamp_ts))
+    assert len(rx.stamp_ts) == len(rx.stamp_rows) > most // 2 - 1
+    if step_s < sources._STAMP_MERGE_S:
+        # a stamp holds the lines of the millisecond after it
+        assert min(np.diff(rx.stamp_ts)) >= sources._STAMP_MERGE_S
+    newest = rx.stamp_ts[-1]
+    # rows are consumed oldest first, whatever merged
+    taken = 0
+    while rx.rows:
+        _part, rows, _blank, ts, counts = rx.take(999)
+        assert rows == sum(counts) == min(999, 10_000 - taken)
+        assert ts == sorted(ts) and len(ts) == len(counts)
+        taken += rows
+    assert taken == 10_000 and ts[-1] == newest and rx.stamp_ts == []
