@@ -57,7 +57,6 @@ from ..runtime.processor import (
     build_step_fn,
     load_reference_data_tables,
     packed_raw_struct,
-    source_raw_form,
     step_compile_entry,
 )
 from .deviceplan import (
@@ -156,21 +155,6 @@ class CompileSurfaceReport:
 # Static step-input avals (the analyzer's mirror of
 # FlowProcessor._step_input_avals, derived from the flow config alone)
 # ---------------------------------------------------------------------------
-def _source_types(gui: dict) -> Dict[str, str]:
-    """input type per source name — decides the raw transfer form
-    (packed single-matrix vs per-column), which is part of the step's
-    trace signature (``source_raw_form``)."""
-    out: Dict[str, str] = {}
-    iprops = (gui.get("input") or {}).get("properties") or {}
-    if iprops.get("inputSchemaFile"):
-        out["default"] = (gui.get("input") or {}).get("type") or "local"
-    for src in (gui.get("input") or {}).get("sources") or []:
-        sname = src.get("id") or src.get("name")
-        if sname:
-            out[sname] = src.get("type") or "local"
-    return out
-
-
 def _refdata_avals(gui: dict) -> Dict[str, object]:
     """Reference-data table avals: the CSVs load through the SAME
     ``load_reference_data_tables`` the runtime uses (their row count is
@@ -204,10 +188,11 @@ def _step_input_avals(bundle: FlowDevicePlan, gui: dict) -> tuple:
     """The 9-argument aval tuple of the fused step, built statically —
     the same structure ``FlowProcessor._step_input_avals`` derives from
     its live device state."""
-    stypes = _source_types(gui)
     raw: Dict[str, object] = {}
     for sname, (raw_schema, cap) in bundle.raw_schemas.items():
-        if source_raw_form(stypes.get(sname)) == "packed":
+        # the raw transfer form is part of the step's trace signature:
+        # the bundle has it from ``source_raw_form``, as the runtime does
+        if bundle.raw_packed.get(sname):
             raw[sname] = jax.tree_util.tree_map(
                 _aval, packed_raw_struct(dict(raw_schema.types), cap)
             )

@@ -55,6 +55,13 @@ def table_bytes(types: Dict[str, str], rows: int) -> int:
     return sum(column_width(t) * rows for t in types.values()) + rows
 
 
+def packed_raw_bytes(types: Dict[str, str], rows: int) -> int:
+    """HBM bytes of a raw batch shipped as the one packed matrix
+    (``runtime/processor.py PackedRaw``): an int32 row a column,
+    whatever its type, and one for the validity."""
+    return (len(types) + 1) * rows * 4
+
+
 def row_bytes(types: Dict[str, str]) -> int:
     return table_bytes(types, 1)
 

@@ -51,6 +51,7 @@ from ..runtime.processor import (
     event_time_table,
     projection_select,
     schema_to_view,
+    source_raw_form,
     window_inputs,
     window_target,
 )
@@ -59,6 +60,7 @@ from ..serve.flowbuilder import RuleDefinitionGenerator
 from .costmodel import (
     DEFAULT_MATCH_MATRIX_BUDGET,
     d2h_transfer_bytes,
+    packed_raw_bytes,
     row_bytes,
     stage_flops,
     stage_ici_bytes,
@@ -353,6 +355,18 @@ class FlowDevicePlan:
     # datasets routed to sinks — the views whose tables cross the
     # device->host boundary every batch (the D2H term + DX206 surface)
     output_datasets: List[str] = field(default_factory=list)
+    # source -> its raw batch comes as the one packed matrix, not a
+    # leaf a column (``source_raw_form`` of its input type): what the
+    # byte models price and the step's trace signature carries
+    raw_packed: Dict[str, bool] = field(default_factory=dict)
+
+    def raw_batch_bytes(self, source: str) -> int:
+        """Device bytes of a source's raw batch in the form it is
+        shipped in."""
+        raw_schema, cap = self.raw_schemas[source]
+        if self.raw_packed.get(source):
+            return packed_raw_bytes(raw_schema.types, cap)
+        return table_bytes(raw_schema.types, cap)
 
 
 def _declared_cardinality(schema: Schema) -> Tuple[Dict[str, int], int]:
@@ -432,14 +446,21 @@ def _plan_from_gui(
 
     # -- sources ---------------------------------------------------------
     sources: List[Tuple[str, dict, str]] = []  # (source, props, target)
+    # input type per source: decides the raw transfer form (the one
+    # packed matrix or a leaf a column), which the byte models price
+    # and the step's trace signature carries (``source_raw_form``)
+    raw_packed: Dict[str, bool] = {}
     if iprops.get("inputSchemaFile"):
         sources.append(("default", iprops, DatasetName.DataStreamProjection))
+        raw_packed["default"] = source_raw_form(
+            (gui.get("input") or {}).get("type")) == "packed"
     for src in (gui.get("input") or {}).get("sources") or []:
         sname = src.get("id") or src.get("name")
         if not sname:
             continue
         sprops = src.get("properties") or {}
         sources.append((sname, sprops, sprops.get("target") or sname))
+        raw_packed[sname] = source_raw_form(src.get("type")) == "packed"
     if not sources:
         diags.append(make(
             "DX291", "",
@@ -660,6 +681,7 @@ def _plan_from_gui(
         or _jobconf_int(jobconf, "jobNumChips", "jobNumExecutors")
         or DEFAULT_CHIPS,
         output_datasets=out_datasets,
+        raw_packed=raw_packed,
     )
 
 
@@ -713,6 +735,10 @@ def flow_plan_from_processor(proc, chips: Optional[int] = None) -> FlowDevicePla
         interval_s=proc.interval_s,
         chips=chips or conf_chips or DEFAULT_CHIPS,
         output_datasets=list(proc.output_datasets),
+        raw_packed={
+            s.name: proc._source_raw_form(s) == "packed"
+            for s in proc.specs.values()
+        },
     )
 
 
@@ -776,11 +802,16 @@ def _stage_walk(
     for source, views in plan.projection_views.items():
         raw_schema, cap = plan.raw_schemas[source]
         raw = make_table(raw_schema, cap)
-        b = _table_data_bytes(raw)
+        packed = plan.raw_packed.get(source)
+        model = plan.raw_batch_bytes(source)
+        # a packed batch is one int32 matrix whatever evaluates the
+        # walk: the step splits it into the table the projections read
         stages.append(StageCost(
             name=f"input:{source}", kind="input", rows=cap,
-            hbm_bytes=b, model_bytes=table_bytes(raw_schema.types, cap),
-            detail="raw ingest batch",
+            hbm_bytes=model if packed else _table_data_bytes(raw),
+            model_bytes=model,
+            detail="raw ingest batch"
+            + (" (one packed matrix)" if packed else ""),
         ))
         penv: Dict[str, object] = {
             "Raw": raw, DatasetName.DataStreamRaw: raw,

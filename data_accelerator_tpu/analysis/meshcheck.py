@@ -336,13 +336,17 @@ def _infer_plan(
 
     # raw ingest + per-source projection chains: rows shard end to end
     for source, views in bundle.projection_views.items():
-        raw_schema, cap = bundle.raw_schemas[source]
-        raw_bytes = table_bytes(raw_schema.types, cap)
+        cap = bundle.raw_schemas[source][1]
+        # a column a leaf, rows sharded, or the one packed matrix, its
+        # capacity axis sharded: a chip holds its rows' share either way
+        raw_bytes = bundle.raw_batch_bytes(source)
         stages.append(MeshStage(
             name=f"input:{source}", kind="input", axis=AXIS_DATA,
             scaling=SCALE_SHARDED, rows=cap, hbm_bytes=raw_bytes,
             per_chip_bytes=_per_chip(raw_bytes, AXIS_DATA, chips),
-            detail="raw ingest batch (rows shard on arrival)",
+            detail="raw ingest batch (rows shard on arrival"
+            + (", one packed matrix)" if bundle.raw_packed.get(source)
+               else ")"),
         ))
         for v in views:
             b = _view_model_bytes(v)

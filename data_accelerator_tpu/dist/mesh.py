@@ -68,7 +68,15 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def step_shardings(mesh: Mesh, rings=(), partials=()):
+def packed_sharding(mesh: Mesh) -> NamedSharding:
+    """A source's packed raw matrix is [columns + 1, capacity]: shard
+    capacity, so a chip's block holds every column of its rows and a
+    row of the matrix, sliced inside the step, is that chip's row
+    shard of the column (no collective)."""
+    return NamedSharding(mesh, P(None, DATA_AXIS))
+
+
+def step_shardings(mesh: Mesh, rings=(), partials=(), packed=None):
     """(in_shardings, out_shardings) pytree prefixes for
     ``FlowProcessor``'s step signature:
 
@@ -82,6 +90,11 @@ def step_shardings(mesh: Mesh, rings=(), partials=()):
     The prefixes apply leaf-wise over the dict pytrees, so N sources and
     N rings inherit the same layout without per-flow sharding code.
 
+    ``packed``: by source name, whether its raw table comes as the one
+    packed matrix (``runtime/processor.py source_raw_form``), whose
+    rows lie on axis 1, and not as a column a leaf; None where every
+    source comes in columns.
+
     The window-state argument also carries, by view name, the per-slot
     partial aggregates of the windowed GROUP BYs the planner decomposed
     (``partials``; their leaves are [slots, groups], [groups] and
@@ -94,7 +107,10 @@ def step_shardings(mesh: Mesh, rings=(), partials=()):
     rep = replicated(mesh)
     if partials:
         ring = {**{t: ring for t in rings}, **{v: rep for v in partials}}
-    in_shardings = (row, ring, rep, rep, rep, rep, rep, rep, rep)
+    raw = {
+        s: packed_sharding(mesh) if p else row for s, p in packed.items()
+    } if packed else row
+    in_shardings = (raw, ring, rep, rep, rep, rep, rep, rep, rep)
     out_shardings = (rep, ring, rep, rep)
     return in_shardings, out_shardings
 

@@ -153,12 +153,11 @@ class StreamingHost:
         self._rate_scale = 1.0
         # the sources whose lines the paced wait decodes as they arrive:
         # those that can show them before the poll (``arrived_lines``:
-        # the socket source), where the step takes the packed matrix
-        # the passes fill (not under a mesh: its row-layout decode
-        # writes fresh arrays at the poll)
+        # the socket source); the step takes the packed matrix the
+        # passes fill, on one chip as under a mesh
         self._ahead_sources = {
             name: src for name, src in self.sources.items()
-            if hasattr(src, "arrived_lines") and self.processor.mesh is None
+            if hasattr(src, "arrived_lines")
         }
         # (bytes, seconds) of the latest passes: how long the next takes
         self._ahead_passes: deque = deque(maxlen=8)
@@ -508,7 +507,8 @@ class StreamingHost:
                 # source declares raw_format="kafka-v2"); the packed
                 # matrix stays numpy (to_device=False) so the
                 # decode-ahead worker never touches jax off-thread —
-                # the jitted step's call transfers it
+                # the jitted step's call transfers it (under a mesh the
+                # encode puts it, a block a chip: ``shard-put``)
                 with tracing.span("source-poll"):
                     blob, _n, c = src.poll_raw(max_events)
                 received = _n

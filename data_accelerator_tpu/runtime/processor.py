@@ -309,6 +309,25 @@ class PackedRaw:
     data: jnp.ndarray  # [len(layout)+1, capacity] int32; last row = valid
     layout: Tuple[Tuple[str, str], ...]  # (column, kind: i32|f32|bool)
 
+    # Code that rewrites the step's arguments outside it handles every
+    # table among them by ``cols`` / ``valid`` and rebuilds it as
+    # ``type(t)(cols, valid)`` (the window states are laid out so for
+    # the same reason, runtime/timewindow.py; the benchmark's planted
+    # mesh fault masks the validity that way). A packed batch reads as
+    # the table it unpacks to, and that table, rebuilt, is a TableData.
+    def __new__(cls, data=None, layout=None):
+        if isinstance(data, dict):
+            return TableData(data, layout)
+        return super().__new__(cls)
+
+    @property
+    def cols(self) -> Dict[str, jnp.ndarray]:
+        return self.unpack().cols
+
+    @property
+    def valid(self) -> jnp.ndarray:
+        return self.data[len(self.layout)] != 0
+
     def tree_flatten(self):
         return (self.data,), self.layout
 
@@ -366,14 +385,22 @@ def pack_raw(
 
 def pack_from_matrix(
     matrix: np.ndarray, layout: Tuple[Tuple[str, str], ...],
-    to_device: bool = True,
+    to_device: bool = True, sharding=None,
 ) -> PackedRaw:
     """PackedRaw over an ALREADY-packed matrix — the zero-copy sibling
     of ``pack_raw`` for the native decoder's pooled ingest buffers,
     which are written in the transfer layout to begin with. On the CPU
     backend ``jnp.asarray`` of the 64-byte-aligned pool matrix is a
     zero-copy view, which is exactly why the pool may only reuse a
-    matrix after its batch has landed (PendingBatch slot release)."""
+    matrix after its batch has landed (PendingBatch slot release).
+
+    ``sharding`` (a mesh's ``dist/mesh.py packed_sharding``): the
+    matrix goes to the devices now, whatever ``to_device`` says, each
+    chip's block of the capacity axis straight from the host (a
+    transfer a chip, not one a column a chip; jax reads the pooled
+    matrix behind the call, so the slot is pinned as above)."""
+    if sharding is not None:
+        return PackedRaw(jax.device_put(matrix, sharding), tuple(layout))
     # dx-race: param matrix=pool
     # dx-race: allow-zero-copy THE designed pooled zero-copy ingest site;
     # lifetime pinned by the PendingBatch owner-handoff
@@ -582,23 +609,22 @@ def build_step_fn(
     return step
 
 
-def source_raw_form(input_type: Optional[str], mesh=None) -> str:
+def source_raw_form(input_type: Optional[str]) -> str:
     """``packed`` when production dispatch ships a source of this input
-    type as the single-matrix PackedRaw (native decoder hot path:
-    single chip, non-local input), else ``columns``. The ONE definition
-    both the runtime (``FlowProcessor._source_raw_form``) and the
-    compile-surface analyzer use — the raw form is part of the step's
-    trace signature, so the two may never disagree."""
+    type as the single-matrix PackedRaw (native decoder hot path: a
+    non-local input, on one chip as under a mesh, where the matrix is
+    put with its capacity axis sharded), else ``columns``. The ONE
+    definition both the runtime (``FlowProcessor._source_raw_form``)
+    and the compile-surface analyzer use — the raw form is part of the
+    step's trace signature, so the two may never disagree."""
     itype = (input_type or "local").lower()
-    if mesh is not None or itype in ("", "local"):
+    if itype in ("", "local"):
         return "columns"
     return "packed"
 
 
 # raw-schema type -> PackedRaw row kind (the bitcast pack_raw applies)
 _PACK_KINDS = {"double": "f32", "boolean": "bool"}
-# raw-schema type -> the numpy dtype the ingest encoders materialize
-_RAW_NP_DTYPES = {"double": np.float32, "boolean": np.bool_}
 
 
 def packed_raw_layout(raw_types: Dict[str, str]) -> Tuple[Tuple[str, str], ...]:
@@ -1049,6 +1075,13 @@ class FlowProcessor:
 
         self._build_pipeline(output_datasets)
         self._init_device_state()
+        # source -> whether its raw batch comes as the one packed
+        # matrix: what the input type says (``source_raw_form``) until a
+        # dispatch is handed the other form (``_note_raw_forms``)
+        self._raw_packed: Dict[str, bool] = {
+            name: self._source_raw_form(spec) == "packed"
+            for name, spec in self.specs.items()
+        }
         self._jit_step()
         if self.aot_enabled:
             self._aot_warm()
@@ -1369,6 +1402,15 @@ class FlowProcessor:
 
         return jax.device_put(a, row_sharding(self.mesh))
 
+    def _packed_sharding(self):
+        """Where the step wants a source's packed matrix under a mesh
+        (``pack_from_matrix``'s ``sharding``); None on one chip."""
+        if self.mesh is None:
+            return None
+        from ..dist.mesh import packed_sharding
+
+        return packed_sharding(self.mesh)
+
     def reset_state(self) -> None:
         """Zero device state (rings, slot counter, time base; state
         tables reload from their location). For re-entrant uses like
@@ -1630,6 +1672,7 @@ class FlowProcessor:
             in_shardings, out_shardings = step_shardings(
                 self.mesh, rings=tuple(self.ring_slots),
                 partials=tuple(self.window_states),
+                packed=self._raw_packed,
             )
             self._step = jax.jit(
                 step,
@@ -1723,7 +1766,7 @@ class FlowProcessor:
         data: bytes,
         base_ms: int,
         source: Optional[str] = None,
-        packed: Optional[bool] = None,
+        packed: bool = True,
         to_device: bool = True,
         fmt: str = "jsonl",
         ahead_bytes: int = 0,
@@ -1743,21 +1786,22 @@ class FlowProcessor:
         batches, rejects compressed ones with a typed error, and feeds
         record values to the JSON column decoder in the same call).
 
-        ``packed`` (default: auto — on for single-chip, off under a
-        mesh, whose row shardings expect [capacity] leaves): decoder
-        shards write directly into a persistent 64-byte-aligned pooled
-        matrix in the single-transfer PackedRaw layout — zero per-row
-        Python objects, zero per-call column allocations, no pack
-        copy. The matrix is reused only after its batch lands
-        (PendingBatch releases the slot), double-buffering the pool
-        against the pipelined in-flight window.
+        ``packed`` (the default, on one chip as under a mesh: bytes
+        off the wire are a non-local input, which ``source_raw_form``
+        ships packed): decoder shards write directly into a persistent
+        64-byte-aligned pooled matrix in the single-transfer PackedRaw
+        layout — zero per-row Python objects, zero per-call column
+        allocations, no pack copy. The matrix is reused only after its
+        batch lands (PendingBatch releases the slot), double-buffering
+        the pool against the pipelined in-flight window. Under a mesh
+        the matrix is put here, each chip's block of its capacity axis
+        a transfer (span ``shard-put``). False: the row layout, a
+        fresh array a column, which only the parity tests ask for.
 
         ``ahead_bytes``: how many of ``data``'s leading bytes
         ``decode_ahead`` has already decoded into this batch's matrix
         (``_encode_packed_native``)."""
         spec = self._spec(source)
-        if packed is None:
-            packed = self.mesh is None
         decoder = self._native_decoder(spec)
         self.decode_ahead_stats.pop(spec.name, None)
         if packed:
@@ -1765,7 +1809,7 @@ class FlowProcessor:
                 decoder, data, base_ms, spec, fmt, to_device, ahead_bytes
             )
 
-        # row-layout native path (mesh shardings want [capacity] leaves)
+        # row-layout native path
         self.last_decoder_path = "native-mt"
         if fmt == "kafka-v2":
             data = self._kafka_values_to_lines(data)
@@ -1813,8 +1857,8 @@ class FlowProcessor:
                 np_cols.get(self.state_partition_key), valid, spec
             )
         # under a mesh every column goes to each chip's row shard, one
-        # transfer a column a shard: the mesh's own share of ``decode``
-        # (the host's time in the calls; jax completes them behind it)
+        # transfer a column a shard (the host's time in the calls; jax
+        # completes them behind it)
         with _trace_span("shard-put") if self.mesh is not None \
                 else contextlib.nullcontext():
             return TableData(
@@ -2132,7 +2176,15 @@ class FlowProcessor:
                 kv, mat[valid_row] != 0, spec
             )
             mat[valid_row] = new_valid.astype(np.int32)
-        pr = pack_from_matrix(mat, layout, to_device=to_device)
+        # under a mesh the matrix goes to the chips here, a block of
+        # its capacity axis each: the mesh's own share of ``decode`` (the
+        # host's time in the call; jax completes the transfers behind it)
+        sharding = self._packed_sharding()
+        with _trace_span("shard-put") if sharding is not None \
+                else contextlib.nullcontext():
+            pr = pack_from_matrix(
+                mat, layout, to_device=to_device, sharding=sharding
+            )
         # dx-race: owner-handoff pool slot rides the PackedRaw into the
         # PendingBatch, which releases it on land/abandon
         pr._ingest_pool = (pool, mat)
@@ -2174,8 +2226,17 @@ class FlowProcessor:
             )
         return TableData(cols, self._put_rows(valid))
 
-    def _empty_raw(self, spec: SourceSpec) -> TableData:
-        return self.encode_columns({}, 0, source=spec.name)
+    def _empty_raw(self, spec: SourceSpec) -> Union[TableData, PackedRaw]:
+        """A batch of no rows, in the form the step takes this source's
+        batches in (``_raw_packed``): one trace signature, and under a
+        mesh one sharding, whether the source brought rows or not."""
+        if not self._raw_packed[spec.name]:
+            return self.encode_columns({}, 0, source=spec.name)
+        layout = packed_raw_layout(spec.raw_schema.types)
+        return pack_from_matrix(
+            np.zeros((len(layout) + 1, spec.capacity), np.int32), layout,
+            sharding=self._packed_sharding(),
+        )
 
     def _filter_unowned(self, key_vals, valid: np.ndarray,
                         spec: SourceSpec) -> np.ndarray:
@@ -2261,6 +2322,7 @@ class FlowProcessor:
             name: raw.get(name) or self._empty_raw(spec)
             for name, spec in self.specs.items()
         }
+        self._note_raw_forms(raw)
         # per-interval UDF refresh hooks; state changes re-trace the step
         # (CommonProcessorFactory.scala:351-353 onInterval invocation).
         # A throwing hook skips its refresh (previous trace keeps
@@ -2358,6 +2420,23 @@ class FlowProcessor:
         handle.start_fetch()
         return handle
 
+    def _note_raw_forms(self, raw: Dict[str, object]) -> None:
+        """The form each source's batch came in. One that differs from
+        what the step was built for (a source of a non-local type that
+        hands rows and not bytes, a caller with tables of its own) is a
+        new trace signature; under a mesh it is also another sharding of
+        that argument, which a jit fixes when it is made: the step is
+        jitted again for the forms it is handed, and counted as the
+        re-trace it is."""
+        packed = {n: isinstance(r, PackedRaw) for n, r in raw.items()}
+        if packed == self._raw_packed:
+            return
+        self._raw_packed = packed
+        if self.mesh is not None:
+            self._jit_step()
+            self.retrace_count += 1
+            self._retrace_mark = None
+
     def process_batch(
         self,
         raw: Union[TableData, Dict[str, TableData]],
@@ -2418,22 +2497,14 @@ class FlowProcessor:
         """The raw transfer form (and therefore trace signature) the
         AOT warm must use for this source — same rule as production
         dispatch (module-level ``source_raw_form``)."""
-        return source_raw_form(spec.conf.get("inputtype"), self.mesh)
+        return source_raw_form(spec.conf.get("inputtype"))
 
     def _warm_raw(self) -> Dict[str, Union[TableData, PackedRaw]]:
         """Zero-filled per-source raw batches in the exact form (and
         therefore trace signature) production dispatch will use."""
-        raw: Dict[str, Union[TableData, PackedRaw]] = {}
-        for name, spec in self.specs.items():
-            if self._source_raw_form(spec) == "packed":
-                np_cols = {
-                    c: np.zeros(spec.capacity, _RAW_NP_DTYPES.get(t, np.int32))
-                    for c, t in spec.raw_schema.types.items()
-                }
-                raw[name] = pack_raw(np_cols, np.zeros(spec.capacity, np.bool_))
-            else:
-                raw[name] = self._empty_raw(spec)
-        return raw
+        return {
+            name: self._empty_raw(spec) for name, spec in self.specs.items()
+        }
 
     def _step_input_avals(self) -> tuple:
         """The 9-argument aval tuple of the fused step — the trace

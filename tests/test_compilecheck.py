@@ -196,6 +196,35 @@ def test_manifest_matches_runtime_lowering_byte_exact():
     assert any(d.code == "DX603" for d in diags)
 
 
+@pytest.mark.parametrize("chips", [None, 4], ids=["one-chip", "mesh4"])
+def test_a_socket_flows_manifest_carries_the_packed_matrix_on_both_layouts(
+    chips
+):
+    """The raw form follows the input type alone (``source_raw_form``,
+    the one definition the analyzer and the runtime share): a socket
+    flow's manifest entry carries the PackedRaw aval, and a processor of
+    that flow derives the same entry on one chip and under a mesh — no
+    DX603 drift for a four-chip job."""
+    flow = copy.deepcopy(load_flow("dx603_manifest_drift"))
+    flow["input"]["type"] = "socket"
+    static = analyze_flow_compile(flow)
+    assert static.ok and static.stable
+    (entry,) = static.entries
+    assert "PackedRaw" in entry["avals"]["tree"]
+    extra = {"datax.job.input.default.inputtype": "socket"}
+    if chips:
+        extra["datax.job.process.numchips"] = str(chips)
+    proc = FlowProcessor(conf_for_gui(flow, extra))
+    assert (proc.mesh.size if chips else proc.mesh) == chips
+    (runtime,) = proc.derive_compile_entries()
+    assert runtime["avals"] == entry["avals"]
+    assert runtime["donate"] == entry["donate"]
+    assert analyze_processor_compile(proc, manifest=static.manifest).ok
+    # the local twin of the flow keeps a leaf a column
+    local = analyze_flow_compile(load_flow("dx603_manifest_drift"))
+    assert "PackedRaw" not in local.entries[0]["avals"]["tree"]
+
+
 def test_step_entry_records_ring_donation_contract():
     from data_accelerator_tpu.runtime.processor import STEP_DONATE_ARGNUMS
 

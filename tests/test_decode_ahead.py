@@ -7,7 +7,9 @@ step, a sink or a checkpoint can see.
     the provisional base or one or two seconds past it, against the
     one-shot ``decode_packed`` of the same blob at that base;
 (b) is in ``tests/test_socket_source.py`` (the arrived-lines view);
-(c) ``StreamingHost.run`` on a socket fed across the wait;
+(c) ``StreamingHost.run`` on a socket fed across the wait, on one chip
+    and on a mesh of four of the suite's devices (the same matrix, put
+    with its capacity axis sharded);
 (d) what every batch of such a host says of its rows' wait, of the time
     none of its spans holds and of how late the loop began it."""
 
@@ -320,7 +322,11 @@ class _RecordingSink:
         return len(rows)
 
 
-def _host(tmp_path, extra=None):
+MESHES = pytest.mark.parametrize("mesh", [None, 4], ids=["one-chip", "mesh4"])
+
+
+def _host(tmp_path, extra=None, mesh=None):
+    tmp_path.mkdir(exist_ok=True)
     t = tmp_path / "ahead.transform"
     t.write_text(TRANSFORM)
     conf = {
@@ -337,8 +343,11 @@ def _host(tmp_path, extra=None):
         "datax.job.output.Out.console.maxrows": "0",
     }
     conf.update(extra or {})
+    if mesh:
+        conf["datax.job.process.numchips"] = str(mesh)
     src = SocketSource(port=0)
     host = StreamingHost(SettingDictionary(conf), source=src)
+    assert (host.processor.mesh.size if mesh else host.processor.mesh) == mesh
     # a pass waits for the bytes from which the decoder shards; a test's
     # lines are few: every wake of the wait makes a pass of them, as it
     # does at a deployment's rates, and not the last alone
@@ -420,8 +429,9 @@ def _offsets(host):
     return dict(host.checkpointer.starting_positions())
 
 
-def test_run_decodes_the_waits_arrivals_ahead_and_lands_the_same_rows(tmp_path):
-    host, src, sink, metrics = _host(tmp_path)
+@MESHES
+def test_run_decodes_the_waits_arrivals_ahead_and_lands_the_same_rows(tmp_path, mesh):
+    host, src, sink, metrics = _host(tmp_path, mesh=mesh)
     groups = [range(0, 300), range(300, 900), range(900, 1500),
               range(1500, 1800)]
     try:
@@ -452,13 +462,14 @@ def test_run_decodes_the_waits_arrivals_ahead_and_lands_the_same_rows(tmp_path):
         host.stop()
 
 
+@MESHES
 def test_a_poll_cut_below_what_was_staged_takes_its_rows_and_no_more(
-    tmp_path
+    tmp_path, mesh
 ):
     """The poll's admission falls below what the wait staged (the
     pilot's ``admit_events``, here the rate scale, said at the poll):
     the batch is the poll's cut, the rest the next batch's."""
-    host, src, sink, metrics = _host(tmp_path)
+    host, src, sink, metrics = _host(tmp_path, mesh=mesh)
     groups = [range(0, 100), range(100, 1000)]
     poll = host._poll_and_encode
 
@@ -544,8 +555,9 @@ def test_a_failed_pass_leaves_the_lines_to_the_poll(tmp_path):
         host.stop()
 
 
-def test_a_batch_failed_at_dispatch_after_staging_is_redelivered_whole(tmp_path):
-    host, src, sink, metrics = _host(tmp_path)
+@MESHES
+def test_a_batch_failed_at_dispatch_after_staging_is_redelivered_whole(tmp_path, mesh):
+    host, src, sink, metrics = _host(tmp_path, mesh=mesh)
     groups = [range(0, 200), range(200, 700)]
     real_step = host.processor._step
     calls = []
@@ -595,8 +607,9 @@ def test_a_batch_failed_at_dispatch_after_staging_is_redelivered_whole(tmp_path)
         host.stop()
 
 
-def test_two_connections_fall_back_to_the_decode_at_the_poll(tmp_path):
-    host, src, sink, metrics = _host(tmp_path)
+@MESHES
+def test_two_connections_fall_back_to_the_decode_at_the_poll(tmp_path, mesh):
+    host, src, sink, metrics = _host(tmp_path, mesh=mesh)
     groups = [range(0, 200), range(200, 800), range(800, 1400)]
     try:
         feeder = _send_after_each_landing(src, sink, groups, conns=2)
@@ -613,8 +626,9 @@ def test_two_connections_fall_back_to_the_decode_at_the_poll(tmp_path):
         host.stop()
 
 
-def test_a_stop_during_the_wait_releases_the_staged_slot(tmp_path):
-    host, src, sink, _metrics = _host(tmp_path)
+@MESHES
+def test_a_stop_during_the_wait_releases_the_staged_slot(tmp_path, mesh):
+    host, src, sink, _metrics = _host(tmp_path, mesh=mesh)
     groups = [range(0, 100), range(100, 400)]
     try:
         feeder = _send_after_each_landing(src, sink, groups)
@@ -641,26 +655,58 @@ def test_a_stop_during_the_wait_releases_the_staged_slot(tmp_path):
         host.stop()
 
 
-def test_no_pass_runs_under_a_mesh(tmp_path, monkeypatch):
-    host, src, sink, metrics = _host(
-        tmp_path, {"datax.job.process.numchips": "2"})
-    groups = [range(0, 100), range(100, 500)]
+def test_passes_under_a_mesh_land_what_one_decode_at_the_poll_lands(tmp_path):
+    """A four-device mesh host decodes the wait's arrivals into the
+    batch's matrix as a one-chip host does and puts it with its
+    capacity axis sharded, a block a device: the rows at the sinks, the
+    offsets and the input counts are those of the same host with the
+    passes off (one decode at each poll)."""
+    groups = [range(0, 300), range(300, 900), range(900, 1500),
+              range(1500, 1800)]
+    ran = {}
+    for passes in (True, False):
+        host, src, sink, metrics = _host(tmp_path / str(passes), mesh=4)
+        rec = _Recorder(host)
+        if not passes:
+            host._ahead_sources.clear()
+        try:
+            feeder = _send_after_each_landing(src, sink, groups)
+            _wait_rows(src, 300)
+            host.run(max_batches=5)
+            feeder.join(30)
+            assert not feeder.is_alive()
+            assert host.processor.last_decoder_path == "native-sharded"
+            assert host.processor.placement()["rawDevices"] == {"default": 4}
+            # the put is the mesh's own span, inside ``decode``
+            for _m, spans, _root in rec.batches():
+                lo, whole = spans["decode"]
+                start, ms = spans["shard-put"]
+                assert lo <= start and start + ms / 1e3 <= lo + whole / 1e3 + 1e-3
+            assert host.processor.buffer_sanitizer.poison_hits == 0
+            assert _pool_is_whole(host)
+            ran[passes] = (sink.batches, _offsets(host),
+                           [m[INPUT_ROWS] for m in metrics], metrics)
+        finally:
+            host.stop()
+    assert ran[True][:3] == ran[False][:3]
+    assert ran[True][0] == [list(g) for g in groups]
+    pct = [m["Decode_Ahead_Pct"] for m in ran[True][3]]
+    assert pct[0] == 0.0 and min(pct[1:]) > 0.0
+    assert all(m["Decode_Ahead_Passes"] >= 1 and m["Decode_Ahead_Ms"] > 0
+               for m in ran[True][3][1:])
+    assert all("Decode_Ahead_Pct" not in m for m in ran[False][3])
+    # the benchmark's two readers of the mesh cell read these counters;
+    # a program that decodes nothing ahead under a mesh (the parent of
+    # the PR that made it) has none, and the line leaves the metrics out
+    from benchmark import readers
 
-    def no_pass(*a, **kw):
-        raise AssertionError("decode_ahead under a mesh")
-
-    try:
-        monkeypatch.setattr(host.processor, "decode_ahead", no_pass)
-        assert host.processor.mesh is not None and not host._ahead_sources
-        feeder = _send_after_each_landing(src, sink, groups)
-        _wait_rows(src, 100)
-        host.run(max_batches=3)
-        feeder.join(30)
-        assert [sorted(b) for b in sink.batches] == [list(g) for g in groups]
-        assert all("Decode_Ahead_Pct" not in m for m in metrics)
-        assert host.processor.last_decoder_path == "native-mt"
-    finally:
-        host.stop()
+    ahead = {"measurements": ran[True][3][1:], "spans": []}
+    bare = {"measurements": ran[False][3], "spans": []}
+    assert readers.read_one("decode_ahead_pct.mesh4", {}, {}, ahead, {}) \
+        == float(np.median(pct[1:]))
+    assert readers.read_one("decode_ahead_ms.mesh4", {}, {}, ahead, {}) > 0.0
+    for name in ("decode_ahead_pct.mesh4", "decode_ahead_ms.mesh4"):
+        assert readers.read_one(name, {}, {}, bare, {}) is None
 
 
 def test_a_pass_that_cannot_end_by_the_deadline_does_not_start(tmp_path):

@@ -167,6 +167,51 @@ def test_partition_plan_axes_follow_the_mesh_layout():
     )
 
 
+def test_a_socket_sources_batch_is_priced_as_one_matrix_not_a_leaf_a_column():
+    """The raw form follows the input type (``source_raw_form``): a
+    non-local source's batch crosses as one int32 matrix, a row a
+    column and one for the validity, sharded on its capacity axis under
+    a mesh. The device plan and the mesh plan price that matrix; the
+    local twin of the flow keeps a column a leaf."""
+    import copy
+
+    from data_accelerator_tpu.analysis import analyze_flow_device
+
+    local = load_flow("dx702_perchip_hbm")
+    socketed = copy.deepcopy(local)
+    socketed["input"]["type"] = "socket"
+    stages = {}
+    for name, flow in (("local", local), ("socket", socketed)):
+        mesh = {s.name: s for s in
+                analyze_flow_mesh(flow, chips=4, lower=False).stages}
+        device = {s.name: s for s in analyze_flow_device(flow).stages}
+        stages[name] = (mesh["input:default"], device["input:default"])
+        # both tiers price the same bytes, sharded on their rows
+        assert mesh["input:default"].hbm_bytes \
+            == device["input:default"].hbm_bytes \
+            == device["input:default"].model_bytes
+        assert mesh["input:default"].axis == "data"
+        assert mesh["input:default"].per_chip_bytes \
+            == mesh["input:default"].hbm_bytes // 4
+    m_local, d_local = stages["local"]
+    m_socket, d_socket = stages["socket"]
+    rows = d_socket.rows
+    # every raw column is 4 bytes in this flow, so the matrix differs
+    # from the columns by the validity alone: an int32 row for a bool
+    assert m_socket.hbm_bytes % (4 * rows) == 0
+    assert m_socket.hbm_bytes - m_local.hbm_bytes == 3 * rows
+    assert "one packed matrix" in m_socket.detail
+    assert "one packed matrix" in d_socket.detail
+    assert "packed" not in m_local.detail and "packed" not in d_local.detail
+    # nothing downstream moves: the step splits the matrix into the
+    # table the projections read
+    def rest(flow):
+        return [(s.name, s.hbm_bytes)
+                for s in analyze_flow_device(flow).stages if s.kind != "input"]
+
+    assert rest(socketed) == rest(local)
+
+
 def test_a_window_held_as_partial_aggregates_is_replicated():
     """A windowed GROUP BY alone, over a payload time column: per-slot
     partial aggregates of an event-time window, replicated on every chip
