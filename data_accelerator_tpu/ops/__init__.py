@@ -14,7 +14,7 @@ from .groupby import (
     sort_groups,
 )
 from .join import inner_join_indices
-from .compact import compact_indices
+from .compact import compact_front
 
 __all__ = [
     "group_ids",
@@ -23,5 +23,5 @@ __all__ = [
     "segment_aggregate",
     "distinct_mask",
     "inner_join_indices",
-    "compact_indices",
+    "compact_front",
 ]

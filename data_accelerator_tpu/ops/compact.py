@@ -1,21 +1,55 @@
-"""Mask compaction: gather valid rows to the front with a static size."""
+"""Mask compaction: valid rows to the front with a static size."""
 
 from __future__ import annotations
 
-from typing import Tuple
+import math
+from typing import List, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 
-def compact_indices(
-    valid: jnp.ndarray, out_capacity: int
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Indices of valid rows packed to the front.
+def compact_front(
+    valid: jnp.ndarray, cols: Sequence[jnp.ndarray]
+) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
+    """The rows where ``valid`` holds, packed to the front in their order.
 
-    Returns (idx[out_capacity], out_valid[out_capacity]); gather columns
-    with ``col[idx]`` after masking by out_valid. Rows beyond
-    out_capacity drop (callers size capacity >= plausible counts).
+    Returns (cols_front, valid_front): one stable sort on the validity
+    bit with the columns riding along as its operands, so no column is
+    gathered by a row index. Valid rows keep their order, so the first
+    ``count`` rows are those of ``nonzero`` + a gather, bit for bit.
+    Rows past the count hold the invalid rows' values, in their order;
+    ``valid_front`` is False there and nothing should read them (the
+    host slices ``[:count]``).
+
+    A column whose leading dimension is ``valid``'s rides the sort; one
+    of shape ``[n, k, ...]`` rides as ``k...`` 1-D operands and is put
+    back after. Any other column (a scalar, another length) is returned
+    as it came.
     """
-    (idx,) = jnp.nonzero(valid, size=out_capacity, fill_value=-1)
-    out_valid = idx >= 0
-    return jnp.where(out_valid, idx, 0), out_valid
+    n = valid.shape[0]
+    # operands each column rides as (0: it does not ride)
+    widths = [
+        math.prod(c.shape[1:]) if c.shape[:1] == (n,) else 0 for c in cols
+    ]
+    flat = []
+    for c, k in zip(cols, widths):
+        if c.ndim == 1 and k:
+            flat.append(c)
+        elif k:
+            m = c.reshape(n, k)
+            flat.extend(m[:, j] for j in range(k))
+    key, *front = jax.lax.sort(
+        (jnp.where(valid, 0, 1).astype(jnp.int32), *flat),
+        num_keys=1, is_stable=True,
+    )
+    out, at = [], 0
+    for c, k in zip(cols, widths):
+        if not k:
+            out.append(c)
+        elif c.ndim == 1:
+            out.append(front[at])
+        else:
+            out.append(jnp.stack(front[at:at + k], axis=1).reshape(c.shape))
+        at += k
+    return out, key == 0

@@ -561,7 +561,7 @@ def build_step_fn(
         # host transfers only [:count] rows — the device->host hop is
         # the expensive boundary, so bytes AND round-trips are
         # minimized: all per-batch scalars ride ONE packed vector.
-        from ..ops.compact import compact_indices
+        from ..ops.compact import compact_front
 
         datasets = {}
         with jax.named_scope("dx.counts"):
@@ -569,12 +569,8 @@ def build_step_fn(
         for n in output_datasets:
             t = out[n]
             with jax.named_scope(f"dx.compact.{n}"):
-                idx, ov = compact_indices(t.valid, t.valid.shape[0])
-                datasets[n] = TableData(
-                    {c: v[idx] if v.shape[:1] == t.valid.shape else v
-                     for c, v in t.cols.items()},
-                    ov,
-                )
+                front, ov = compact_front(t.valid, list(t.cols.values()))
+                datasets[n] = TableData(dict(zip(t.cols, front)), ov)
             with jax.named_scope("dx.counts"):
                 counts.append(t.count())
         with jax.named_scope("dx.counts"):

@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from ..compile.planner import EventClock, TableData, ViewSchema
+from ..ops.compact import compact_front
 
 
 @jax.tree_util.register_pytree_node_class
@@ -360,17 +361,6 @@ def make_partials(
     )
 
 
-def _front(flag: jnp.ndarray, carry: Sequence[jnp.ndarray], n: int):
-    """The rows where ``flag`` holds, packed to the front in their order,
-    cut to ``n``: a stable sort on one bit, the columns riding along (no
-    gather by a row-count index)."""
-    out = jax.lax.sort(
-        (jnp.where(flag, 0, 1).astype(jnp.int32), *carry),
-        num_keys=1, is_stable=True,
-    )
-    return [a[:n] for a in out[1:]]
-
-
 def _merge_directory(
     dir_keys: Sequence[jnp.ndarray],  # [groups] a key column
     used: jnp.ndarray,  # [groups] columns that still hold a live key
@@ -487,7 +477,8 @@ def fold_partials(
             duration_ms, event,
         )
     n_batch = jnp.sum(last.astype(jnp.int32))
-    packed = _front(last, [*keys_s, *(run[n] for n in names)], gb)
+    packed = [a[:gb] for a in compact_front(
+        last, [*keys_s, *(run[n] for n in names)])[0]]
     bkeys, bvals = packed[:nk], dict(zip(names, packed[nk:]))
     bvalid = jnp.arange(gb) < n_batch
     dropped = jnp.maximum(n_batch - gb, 0)
@@ -561,7 +552,7 @@ def _fold_event(
         [jnp.ones((1,), jnp.bool_), changed])
     rank = jnp.cumsum(key_first.astype(jnp.int32)) - 1
     n_batch = jnp.sum(key_first.astype(jnp.int32))
-    bkeys = _front(key_first, keys_s, gb)
+    bkeys = [a[:gb] for a in compact_front(key_first, keys_s)[0]]
     bvalid = jnp.arange(gb) < n_batch
     dropped = jnp.maximum(n_batch - gb, 0)
 

@@ -409,17 +409,12 @@ def test_counters_are_on_an_empty_batch_and_registered(tmp_path):
 
 
 # -- both benchmark flows, 2,048 rows a batch, fixed seed and clock ---------
-@pytest.mark.parametrize("config_name", ["nexmark-q1", "homeautomation-5s"])
-def test_benchmark_flow_sink_files_are_the_per_row_bytes(
-    tmp_path, config_name
-):
-    """The files the benchmark reads back: each is byte for byte the
-    per-row code's payload over the same landed table, every output of
-    both flows takes the columnar path, and no dict is built."""
+def _benchmark_flow(tmp_path, config_name, n, extra=None):
+    """A processor of the benchmark's configuration at ``n`` rows a batch,
+    its flow module, and ``3 * n`` of its events due from a fixed base
+    (returned too) at 2,000 a second."""
     import importlib
     import os
-
-    import jax
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
@@ -427,6 +422,7 @@ def test_benchmark_flow_sink_files_are_the_per_row_bytes(
     ), encoding="utf-8") as f:
         config = json.load(f)
     flow = importlib.import_module("benchmark.flows." + config["flow"])
+    tmp_path.mkdir(parents=True, exist_ok=True)
     (tmp_path / "s.json").write_text(json.dumps(config["schema"]))
     (tmp_path / "t.transform").write_text(config["transform"])
     conf = {
@@ -434,7 +430,7 @@ def test_benchmark_flow_sink_files_are_the_per_row_bytes(
         "datax.job.input.default.blobschemafile": str(tmp_path / "s.json"),
         "datax.job.input.default.streaming.intervalinseconds":
             str(config["interval_s"]),
-        "datax.job.process.batchcapacity": "2048",
+        "datax.job.process.batchcapacity": str(n),
         "datax.job.process.transform": str(tmp_path / "t.transform"),
         "datax.job.process.watermark": config["guarantees"]["watermark"],
     }
@@ -442,15 +438,30 @@ def test_benchmark_flow_sink_files_are_the_per_row_bytes(
     conf.update(
         {k: v.replace("\\n", "\n") for k, v in config["conf"].items()}
     )
+    conf.update(extra or {})
     proc = FlowProcessor(
         SettingDictionary(conf), output_datasets=config["outputs"]
     )
-    n, base = 2048, 1_790_000_000_000
+    base = 1_790_000_000_000
     ev = flow.make_events(4_294_967_311, 3 * n, 0)
     ev["due_ms"] = base + np.arange(3 * n, dtype=np.int64) // 2
+    return proc, flow, ev, base
+
+
+@pytest.mark.parametrize("config_name", ["nexmark-q1", "homeautomation-5s"])
+def test_benchmark_flow_sink_files_are_the_per_row_bytes(
+    tmp_path, config_name
+):
+    """The files the benchmark reads back: each is byte for byte the
+    per-row code's payload over the same landed table, every output of
+    both flows takes the columnar path, and no dict is built."""
+    import jax
+
+    n = 2048
+    proc, flow, ev, base = _benchmark_flow(tmp_path, config_name, n)
     sinks = {
         o: FileSink(str(tmp_path / "out" / o), "none")
-        for o in config["outputs"]
+        for o in proc.output_datasets
     }
     total = 0
     for b in range(3):
@@ -477,6 +488,65 @@ def test_benchmark_flow_sink_files_are_the_per_row_bytes(
             assert path.read_bytes() == per_row_payload(reference)
             total += len(reference)
     assert total > 3 * 8  # HeatAvg's 8 groups a batch at the very least
+
+
+@pytest.mark.parametrize("layout", ["one_device", "mesh4"])
+def test_step_outputs_are_a_numpy_compaction_of_the_views(
+    tmp_path, monkeypatch, layout
+):
+    """Every output the step hands the host, at its whole capacity and
+    before the host's ``[:count]``, is the view's rows compacted the way
+    numpy would: the valid rows in their order, then the invalid ones in
+    theirs. The view's rows are read from a second processor of the same
+    flow whose step leaves its outputs uncompacted; on one device and on
+    a four-device mesh, whose outputs are replicated."""
+    import jax
+
+    from data_accelerator_tpu.ops import compact
+
+    n = 2048
+    extra = {"datax.job.process.numchips": "4"} if layout == "mesh4" else {}
+    proc, flow, ev, base = _benchmark_flow(
+        tmp_path / "step", "homeautomation-5s", n, extra
+    )
+    with monkeypatch.context() as m:
+        m.setattr(compact, "compact_front",
+                  lambda valid, cols: (list(cols), valid))
+        bare, _f, _e, _b = _benchmark_flow(
+            tmp_path / "bare", "homeautomation-5s", n, extra
+        )
+        views = []
+        for b in range(2):
+            t_ms = base + 1000 * (b + 1)
+            lines = flow.lines(ev, b * n, (b + 1) * n)
+            h = bare.dispatch_batch(bare.encode_json_bytes(lines, t_ms), t_ms)
+            views.append(jax.device_get(h.out_datasets))
+    assert (proc.mesh is not None) == (layout == "mesh4")
+    # the second step did leave its rows where the views put them
+    assert any(
+        np.asarray(v.valid).argmin() < np.asarray(v.valid).sum()
+        for view in views for v in view.values()
+    )
+    for b, view in enumerate(views):
+        t_ms = base + 1000 * (b + 1)
+        lines = flow.lines(ev, b * n, (b + 1) * n)
+        h = proc.dispatch_batch(proc.encode_json_bytes(lines, t_ms), t_ms)
+        counts = h.collect_counts().dataset_counts
+        got = jax.device_get(h.out_datasets)
+        for o in proc.output_datasets:
+            mask = np.asarray(view[o].valid)
+            assert counts[o] == mask.sum() > 0
+            np.testing.assert_array_equal(
+                got[o].valid, np.arange(len(mask)) < mask.sum()
+            )
+            assert got[o].cols.keys() == view[o].cols.keys()
+            for c, v in view[o].cols.items():
+                v = np.asarray(v)
+                want = (
+                    np.concatenate([v[mask], v[~mask]])
+                    if v.shape[:1] == mask.shape else v
+                )
+                np.testing.assert_array_equal(got[o].cols[c], want, (o, c))
 
 
 # -- sinks -------------------------------------------------------------------

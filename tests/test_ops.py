@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from data_accelerator_tpu.ops import (
-    compact_indices,
+    compact_front,
     distinct_mask,
     group_ids,
     inner_join_indices,
@@ -149,11 +149,51 @@ def test_left_join_unmatched():
     assert sorted(rows) == [(1, True), (2, False)]
 
 
-def test_compact():
-    valid = jnp.array([0, 1, 0, 1, 1], dtype=bool)
-    idx, out_valid = compact_indices(valid, 5)
-    assert np.asarray(idx)[:3].tolist() == [1, 3, 4]
-    assert np.asarray(out_valid).tolist() == [True, True, True, False, False]
+def _compact_case(name, n=64):
+    """(mask, columns) of one compaction case; every column's values are
+    distinct, so a row that moves to the wrong place shows."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    mask = {
+        "all_valid": np.ones(n, bool),
+        "none_valid": np.zeros(n, bool),
+    }.get(name, rng.random(n) < 0.3)
+    ints = rng.permutation(n).astype(np.int32)
+    cols = {
+        "all_valid": [ints],
+        "none_valid": [ints],
+        "random_mask": [ints],
+        "mixed_dtypes": [
+            ints, rng.standard_normal(n).astype(np.float32),
+            rng.random(n) < 0.5,
+        ],
+        "wide_column": [ints, np.arange(3 * n, dtype=np.int32).reshape(n, 3)],
+        "other_length": [ints, np.arange(7, dtype=np.float32)],
+    }[name]
+    return mask, cols
+
+
+@pytest.mark.parametrize("name", [
+    "all_valid", "none_valid", "random_mask", "mixed_dtypes", "wide_column",
+    "other_length",
+])
+def test_compact_front(name):
+    """The valid rows come first in their order (numpy's ``v[mask]``),
+    the invalid rows after them in theirs, ``valid_front`` marks the
+    first ``count``; a column of another length passes through."""
+    mask, cols = _compact_case(name)
+    front, valid_front = jax.jit(compact_front)(jnp.asarray(mask), cols)
+    count = int(mask.sum())
+    assert np.asarray(valid_front).tolist() == [True] * count + [False] * (
+        len(mask) - count
+    )
+    for col, got in zip(cols, front):
+        got = np.asarray(got)
+        assert got.dtype == col.dtype and got.shape == col.shape
+        if col.shape[:1] != mask.shape:
+            np.testing.assert_array_equal(got, col)
+            continue
+        np.testing.assert_array_equal(got[:count], col[mask])
+        np.testing.assert_array_equal(got[count:], col[~mask])
 
 
 def test_ops_jit_compatible():
