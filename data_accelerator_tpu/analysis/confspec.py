@@ -348,7 +348,10 @@ CONF_REGISTRY: Tuple[ConfKey, ...] = (
        description="native decoder worker threads (None = serial)"),
     # -- ops ---------------------------------------------------------------
     _K("maxgroups", "int", None, "ops", source="manual", min=1,
-       description="group-by capacity: max distinct groups per batch"),
+       description="group-by capacity: max distinct groups per batch; "
+                   "also the rows every statetable.* holds (the key "
+                   "bound; keys past it are counted in "
+                   "State_GroupsDropped)"),
     _K("groupcapacity", "int", None, "ops", source="manual", min=1,
        description="group-by capacity: max rows per group"),
     _K("joincapacity", "int", None, "ops", source="manual", min=1,
@@ -377,9 +380,14 @@ CONF_REGISTRY: Tuple[ConfKey, ...] = (
        description="ingest-time partition filtering (requires "
                    "state.partitions — see the constraint)"),
     _K("statetable.*.schema", "string", None, "state", source="template",
-       description="accumulator state-table schema ('k long, v double')"),
+       description="accumulator state-table schema ('k long, v double'); "
+                   "the table holds process.maxgroups rows where that is "
+                   "set, else 4 x batchcapacity"),
     _K("statetable.*.location", "path", None, "state", source="template",
-       description="state-table spill/snapshot directory"),
+       description="state-table A/B snapshot directory; unset: "
+                   "<input checkpointdir>/state/<table>, beside the offsets "
+                   "its committed side agrees with (/tmp/dxtpu-state/<table> "
+                   "for a job with no checkpoint directory)"),
     _K("statetable.*.partitionkey", "string", None, "state",
        source="manual",
        description="per-table partition column override"),

@@ -55,6 +55,7 @@ from ..runtime.processor import (
     window_inputs,
     window_target,
 )
+from ..runtime.statetable import state_capacity
 from ..runtime.timewindow import num_slots
 from ..serve.flowbuilder import RuleDefinitionGenerator
 from .costmodel import (
@@ -597,11 +598,12 @@ def _plan_from_gui(
             )
             table_slots[table] = max(table_slots.get(table, 1), slots)
 
-        # accumulation tables
+        # accumulation tables, at the capacity the runtime gives them
         state: Dict[str, Tuple[ViewSchema, int]] = {}
         for sname_, ddl in rc.accumulation_tables.items():
             state[sname_] = (
-                parse_state_table_schema(ddl), batch_capacity * 4
+                parse_state_table_schema(ddl),
+                state_capacity(batch_capacity, maxgroups),
             )
 
         inputs: Dict[str, Tuple[ViewSchema, int]] = {

@@ -55,6 +55,9 @@ class Pipeline:
     partial_windows: Tuple[str, ...] = ()
     # every TIMEWINDOW table as the runtime declared it
     windows: Dict[str, WindowInput] = field(default_factory=dict)
+    # statements that read or write a state table (assigned to one, or
+    # naming one in FROM / JOIN): they run under ``dx.state.<name>``
+    state_views: frozenset = frozenset()
 
     @property
     def event_tables(self) -> Dict[str, EventClock]:
@@ -97,8 +100,11 @@ class Pipeline:
             env["__aux"] = {}
         for view in self.views:
             # the view's name on every operation it lowers to: a device
-            # trace splits the step's time by view (metadata only)
-            with jax.named_scope(f"dx.view.{view.name}"):
+            # trace splits the step's time by view (metadata only), and
+            # a statement that reads or writes a state table from the
+            # flow's other queries
+            kind = "state" if view.name in self.state_views else "view"
+            with jax.named_scope(f"dx.{kind}.{view.name}"):
                 env[view.name] = view.fn(env, base_s, now_rel_ms)
         return env
 
@@ -234,6 +240,7 @@ class PipelineCompiler:
             state_names.append(name)
 
         views: List[CompiledView] = []
+        state_views = set()
         host_limited: Dict[str, str] = {}  # view name -> why
         for cmd in parsed.commands:
             if cmd.command_type != COMMAND_TYPE_QUERY or cmd.name is None:
@@ -260,6 +267,9 @@ class PipelineCompiler:
                 partial_windows=partial_windows,
             )
             view = compiler.compile_select(cmd.name, sel)
+            if cmd.name in state_names or set(state_names) & set(
+                    _referenced_tables(sel)):
+                state_views.add(cmd.name)
             if view.host_order and view.host_limit is not None:
                 host_limited[view.name] = "host-limited"
             elif view.name in host_limited:
@@ -277,4 +287,5 @@ class PipelineCompiler:
             aux_registry=self.aux,
             partial_windows=tuple(sorted(partial_windows)),
             windows=dict(windows),
+            state_views=frozenset(state_views),
         )

@@ -327,6 +327,12 @@ class MetricName:
         r"State_Snapshot_(Push|Pull)_Count",
         r"State_IngestFiltered_Count",
         r"State_WindowRows_Dropped_Count",
+        # accumulator tables on every batch (runtime/processor.py
+        # collect_tables): bytes the standby write put to disk, valid
+        # rows of the tables after the batch, keys past the bound
+        r"State_Write_Bytes",
+        r"State_Rows",
+        r"State_GroupsDropped",
         # fleet placement (serve/jobs.py FleetAdmissionGate, emitted
         # under the DATAX-Fleet app on every admission check / re-plan):
         # fleet-wide chip/flow counts, per-chip packed HBM and

@@ -235,6 +235,7 @@ class BatchHost:
                 self.processor.commit()
                 trace.end()
             except Exception as e:  # noqa: BLE001 — re-raised on the main pass
+                self.processor.settle_state_writes()
                 trace.end(status="error")
                 handle.abandon()
                 landing_failed.append(e)

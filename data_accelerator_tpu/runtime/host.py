@@ -46,7 +46,8 @@ _AHEAD_SLICE_S = 0.02
 
 # The root's children that make up a batch's chain up to its ``emit``:
 # what of that stretch none of them holds is ``Batch_Unspanned_Ms``.
-_CHAIN_SPANS = ("decode", "dispatch", "device-step", "collect", "sinks")
+_CHAIN_SPANS = ("decode", "dispatch", "device-step", "collect", "sinks",
+                "state-commit")
 
 
 def _preemptions() -> int:
@@ -705,6 +706,9 @@ class StreamingHost:
                     if pm is not None:
                         pm.record("FIFO_ACK", source=name)
         except Exception as e:
+            # a standby write still running beside the failed sinks ends
+            # before the window is requeued
+            self.processor.settle_state_writes()
             self.telemetry.track_exception(
                 e, {"event": "error/streaming/process", "batchTime": batch_time_ms}
             )
@@ -1100,13 +1104,14 @@ class StreamingHost:
             nbytes = max(b for b, _s in self._ahead_passes)
         return 1.5 * nbytes * max(s / b for b, s in self._ahead_passes)
 
-    def _decode_arrived(self, deadline: float) -> None:
+    def _decode_arrived(self, deadline: float) -> int:
         """One pass a source over the lines that have arrived since the
         pass before: decoded into the next batch's matrix
         (``FlowProcessor.decode_ahead``), against the base a poll at
         ``deadline`` gets. No pass starts that the passes before it say
         cannot end by ``deadline``: the poll starts when it always did.
-        """
+        Returns the passes made."""
+        made = 0
         for name, src in list(self._ahead_sources.items()):
             at_byte, at_line = self.processor.decode_ahead_cursor(name)
             got = src.arrived_lines(at_byte, at_line)
@@ -1139,12 +1144,14 @@ class StreamingHost:
                 )
                 del self._ahead_sources[name]
                 continue
+            made += bool(decoded)
             if decoded and len(data) >= self._ahead_pass_bytes:
                 # (a smaller pass's time says what a call costs, not
                 # how fast the decoder is)
                 self._ahead_passes.append(
                     (len(data), time.perf_counter() - t0)
                 )
+        return made
 
     def _pace(self, deadline: float) -> None:
         """Wait for ``deadline`` (the interval's end). Where a source
@@ -1157,13 +1164,20 @@ class StreamingHost:
         # pacing, measured in a capture instead of inferred from the
         # hole between two batches; the passes lie inside it
         with tracing.annotation("pace"):
+            made = 0
             while self._ahead_sources and not self._stop:
                 # the last wake leaves a pass the time to end
                 nap = deadline - time.time() - self._pass_s()
                 if nap <= 0:
                     break
                 time.sleep(min(_AHEAD_SLICE_S, nap))
-                self._decode_arrived(deadline)
+                made += self._decode_arrived(deadline)
+            if not made:
+                # a wait with no pass times none: one slow pass (the
+                # host preempted in it) would keep every later wait from
+                # making one, and leave each batch to its poll. The next
+                # wait measures anew
+                self._ahead_passes.clear()
             left = deadline - time.time()
             if left > 0:
                 time.sleep(left)

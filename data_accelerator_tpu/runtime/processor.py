@@ -29,6 +29,7 @@ import logging
 import os
 import threading
 import time
+from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple, Union
 
@@ -53,7 +54,7 @@ from ..obs.tracing import current_trace as _current_trace
 from ..obs.tracing import span as _trace_span
 from ..core.schema import ColType, Schema, StringDictionary
 from .materialize import ColumnBatch
-from .statetable import StateTable
+from .statetable import StateTable, state_capacity
 from .timewindow import (
     EventRows,
     WindowBuffers,
@@ -194,6 +195,15 @@ def window_inputs(
         )
         for wname, (table, dur_s) in windows.items()
     }
+
+
+def _conf_maxgroups(process_conf: SettingDictionary) -> Optional[int]:
+    """The flow's key bound (``process.maxgroups``, or its older name
+    ``groupcapacity``); None where the conf sets neither."""
+    return (
+        process_conf.get_int_option("maxgroups")
+        or process_conf.get_int_option("groupcapacity")
+    )
 
 
 def window_target(wname: str, targets: List[str]) -> str:
@@ -552,10 +562,20 @@ def build_step_fn(
         for sname in state_names:
             tables[sname] = state[sname]
 
-        # each view under ``dx.view.<name>`` (compile/pipeline.py)
+        # each view under ``dx.view.<name>``, a statement that reads or
+        # writes a state table under ``dx.state.<name>`` (compile/pipeline.py)
         out = pipeline.run(tables, base_s, now_rel_ms, aux=aux)
 
-        new_state = {n: out.get(n, state[n]) for n in state_names}
+        # a state table carries its own columns to the next batch: a
+        # view's internal ones (``__overflow.*``) would change the step's
+        # argument tree after the first batch, and re-trace it
+        new_state = {}
+        for n in state_names:
+            t = out.get(n, state[n])
+            new_state[n] = TableData(
+                {c: v for c, v in t.cols.items() if not c.startswith("__")},
+                t.valid,
+            )
 
         # compact outputs device-side (valid rows to the front) so the
         # host transfers only [:count] rows — the device->host hop is
@@ -596,6 +616,13 @@ def build_step_fn(
                 counts.append(sum(e.late for e in events.values()))
                 counts.append(sum(e.too_late for e in events.values()))
                 counts.append(sum(touched.get(t, 0) for t in events))
+            # state tables: valid rows after the batch, and keys the
+            # accumulating GROUP BY had no room for (-1: not tracked)
+            for n in state_names:
+                counts.append(new_state[n].count())
+                over = out[n].cols.get("__overflow.groups")
+                counts.append(jnp.asarray(-1, jnp.int32) if over is None
+                              else over[0])
             counts_vec = jnp.stack(
                 [jnp.asarray(c, jnp.int32) for c in counts]
             )
@@ -1040,19 +1067,31 @@ class FlowProcessor:
 
         # state tables — partitioned: each replica persists only its
         # owned key-range partitions, mirrored through objstore:// when
-        # process.state.snapshoturl is set (the rescale-handoff path)
+        # process.state.snapshoturl is set (the rescale-handoff path).
+        # A table holds the key bound process.maxgroups where it is set
+        # (``state_capacity``) and, with no location of its own,
+        # persists under the job's checkpoint directory, beside the
+        # offsets its committed side must agree with
         self.state_tables: Dict[str, StateTable] = {}
+        state_rows = state_capacity(
+            self.batch_capacity, _conf_maxgroups(process_conf)
+        )
+        ckpt_dir = input_conf.get("eventhub.checkpointdir") or \
+            input_conf.get("streaming.checkpointdir")
         for sname, sub in dict_.group_by_sub_namespace(
             SettingNamespace.JobProcessPrefix + "statetable."
         ).items():
             schema = parse_state_table_schema(sub.get_string("schema"))
-            location = sub.get_or_else("location", f"/tmp/dxtpu-state/{sname}")
+            location = sub.get("location") or (
+                os.path.join(ckpt_dir, "state", sname) if ckpt_dir
+                else f"/tmp/dxtpu-state/{sname}"
+            )
             key = sub.get("partitionkey") or (
                 self.state_partition_key
                 if self.state_partition_key in schema.types else None
             )
             self.state_tables[sname] = StateTable(
-                sname, schema, self.batch_capacity * 4, location,
+                sname, schema, state_rows, location,
                 partitions=self.state_partitions,
                 owned=self.state_owned,
                 partition_key=key,
@@ -1060,6 +1099,13 @@ class FlowProcessor:
                 stats=self.state_stats,
                 events=self.state_events,
             )
+        # a batch's standby write runs beside its sinks, one at a time
+        # in batch order; commit() waits for it before the pointers
+        self._state_writer = (
+            ThreadPoolExecutor(1, thread_name_prefix="dx-state-write")
+            if self.state_tables else None
+        )
+        self._state_writes: List[Future] = []
 
         # jit re-traces observed since the last collect (UDF-refresh
         # rebuilds + shape/dictionary-growth cache misses past the
@@ -1085,10 +1131,7 @@ class FlowProcessor:
     # -- build -----------------------------------------------------------
     def _planner_config(self, process_conf: SettingDictionary) -> PlannerConfig:
         kwargs = {}
-        maxgroups = (
-            process_conf.get_int_option("maxgroups")
-            or process_conf.get_int_option("groupcapacity")
-        )
+        maxgroups = _conf_maxgroups(process_conf)
         if maxgroups is not None:
             if maxgroups < 1:
                 raise EngineException(
@@ -2586,10 +2629,44 @@ class FlowProcessor:
         self._warm_step_mark = self._step_cache_size()
         self.compile_stats["ColdStart_Ms"] = (time.time() - t0) * 1000.0
 
+    def write_state(self, state: Dict[str, TableData]) -> None:
+        """Start writing a batch's state to each table's standby side
+        (``StateTable.overwrite``); ``commit`` waits for it. The write
+        runs beside the batch's sinks, off the path its rows land on."""
+        trace = _current_trace()
+
+        def write() -> int:
+            with trace.span("state-write") if trace is not None \
+                    else contextlib.nullcontext():
+                return sum(
+                    st.overwrite(state[sname], self.dictionary)
+                    for sname, st in self.state_tables.items()
+                )
+
+        self._state_writes.append(self._state_writer.submit(write))
+
+    def settle_state_writes(self) -> None:
+        """A failed batch's path: wait out the standby writes started
+        and forget them, their errors too (the batch is requeued; its
+        pointers were never committed)."""
+        writes, self._state_writes = self._state_writes, []
+        for f in writes:
+            f.exception()
+
     def commit(self) -> None:
-        """Commit state-table pointers after sinks succeed."""
-        for st in self.state_tables.values():
-            st.persist()
+        """Commit state-table pointers after sinks succeed, once the
+        batch's standby write has ended (a write that failed fails the
+        batch here, before any pointer moves)."""
+        if not self.state_tables:
+            return
+        with _trace_span("state-commit"):
+            writes, self._state_writes = self._state_writes, []
+            written = sum(f.result() for f in writes)
+            for st in self.state_tables.values():
+                st.persist()
+        trace = _current_trace()
+        if trace is not None and writes:
+            trace.counters["State_Write_Bytes"] = float(written)
 
     def step_devices(self) -> list:
         """The devices the step runs on: every mesh device, else the
@@ -2849,7 +2926,7 @@ class PendingBatch:
         inside collect(). Transfer errors are NOT swallowed — they
         propagate to the batch loop for retry."""
         self.counts_vec.copy_to_host_async()
-        for t in self.out_datasets.values():
+        for t in (*self.out_datasets.values(), *self.state.values()):
             for a in (*t.cols.values(), t.valid):
                 a.copy_to_host_async()
 
@@ -2968,10 +3045,11 @@ class PendingBatch:
                     self.base_ms, finish=finish,
                 )
 
-        # persist state tables (A/B overwrite; persist() is the caller's
-        # post-sink commit, see StreamingHost) — from THIS batch's state
-        for sname, st in proc.state_tables.items():
-            st.overwrite(self.state[sname], proc.dictionary)
+        # persist state tables (A/B overwrite, beside the sinks;
+        # persist() is the caller's post-sink commit, see StreamingHost)
+        # — from THIS batch's state
+        if proc.state_tables:
+            proc.write_state(self.state)
 
         elapsed_ms = (time.time() - self.t0) * 1000.0
         metrics = {
@@ -3110,6 +3188,16 @@ class PendingBatch:
         if proc.state_tables or proc.state_replica_count > 1:
             metrics["State_Partition_Count"] = float(proc.state_partitions)
             metrics["State_Partition_Owned"] = float(len(proc.state_owned))
+        if proc.state_tables:
+            # the tables' valid rows after the batch and the keys their
+            # GROUP BY had no room for (the counts vector's last two
+            # slots a table); the standby write's bytes ride the trace
+            # from commit()
+            tail = counts[len(counts) - 2 * len(proc.state_tables):]
+            metrics["State_Rows"] = float(tail[0::2].sum())
+            dropped = [int(d) for d in tail[1::2] if d >= 0]
+            if dropped:
+                metrics["State_GroupsDropped"] = float(sum(dropped))
         if proc.state_stats:
             for k, v in proc.state_stats.items():
                 metrics[f"State_{k}"] = float(v)

@@ -25,7 +25,7 @@ Pieces:
 - **snapshot stores**: the per-partition A/B + pointer layout
   (``<prefix>/p<NN>/{A,B}/<file>`` + ``<prefix>/p<NN>/pointer``) over
   two backends — the local filesystem (power-loss durable: tmp-write +
-  fsync + directory fsync, ``runtime/checkpoint._durable_replace``)
+  fsync + rename + directory fsync)
   and the shared object store (``objstore://`` — what lets a successor
   replica on ANOTHER host warm its partitions). Object-store I/O is
   **fail-closed**: state is correctness, so push/pull retries
@@ -194,9 +194,9 @@ def reassigned_partitions(
 # ---------------------------------------------------------------------------
 class LocalSnapshotStore:
     """The on-disk partition layout with the checkpointers' power-loss
-    durability: every file lands via tmp-write + fsync +
-    ``_durable_replace`` (file AND directory fsynced), and the pointer
-    commit — the exactly-once point — gets the same treatment."""
+    durability: every file lands via tmp-write + fsync + rename + an
+    fsync of its directory, and the pointer commit — the exactly-once
+    point — gets the same treatment."""
 
     def __init__(self, root: str):
         self.root = root
@@ -208,18 +208,24 @@ class LocalSnapshotStore:
 
     def put_files(self, prefix: str, side: str,
                   files: Dict[str, bytes]) -> None:
-        from .checkpoint import _durable_replace
-
+        """Each file written to a tmp and fsynced, all renamed, then
+        the side's directory fsynced once: one fsync a file and one a
+        side."""
         d = self._dir(prefix, side)
         os.makedirs(d, exist_ok=True)
         for fn, data in files.items():
-            path = os.path.join(d, fn)
-            tmp = path + ".tmp"
-            with open(tmp, "wb") as f:
+            with open(os.path.join(d, fn) + ".tmp", "wb") as f:
                 f.write(data)
                 f.flush()
                 os.fsync(f.fileno())
-            _durable_replace(tmp, path)
+        for fn in files:
+            path = os.path.join(d, fn)
+            os.replace(path + ".tmp", path)
+        dir_fd = os.open(d, os.O_RDONLY)
+        try:
+            os.fsync(dir_fd)
+        finally:
+            os.close(dir_fd)
 
     def get_file(self, prefix: str, side: str, name: str) -> Optional[bytes]:
         try:
@@ -229,8 +235,6 @@ class LocalSnapshotStore:
             return None
 
     def put_pointer(self, prefix: str, side: str) -> None:
-        from .checkpoint import _durable_replace
-
         d = self._dir(prefix)
         os.makedirs(d, exist_ok=True)
         path = os.path.join(d, "pointer")
@@ -239,7 +243,12 @@ class LocalSnapshotStore:
             f.write(side)
             f.flush()
             os.fsync(f.fileno())
-        _durable_replace(tmp, path)
+        os.replace(tmp, path)
+        dir_fd = os.open(d, os.O_RDONLY)
+        try:
+            os.fsync(dir_fd)
+        finally:
+            os.close(dir_fd)
 
     def get_pointer(self, prefix: str) -> Optional[str]:
         try:

@@ -21,7 +21,7 @@ dir or the shared ``objstore://`` mirror) instead of a state loss.
 
 Durability (the PR 4 checkpointer contract, previously missing here):
 every snapshot file AND the pointer commit go through tmp-write +
-fsync + ``_durable_replace`` (file and directory fsynced), so a torn
+fsync + rename + directory fsync, so a torn
 write after power loss can never surface as the active snapshot.
 Corrupt/truncated snapshots no longer kill the host: ``load()`` falls
 back to the standby side (counted in ``State_LoadFallback_Count``,
@@ -31,6 +31,11 @@ the standby was missing.
 
 A partition snapshot is a ``.npz`` of compacted row columns + a JSON
 sidecar with types and the string-dictionary entries its ids reference.
+
+Capacity (``state_capacity``): a table holds as many rows as the
+deployment's key bound, ``process.maxgroups``, where the conf sets one
+(the accumulating GROUP BY then folds the batch into a table of the same
+shape every batch); else four batch widths.
 """
 
 from __future__ import annotations
@@ -56,6 +61,19 @@ from .statepartition import (
 )
 
 logger = logging.getLogger(__name__)
+
+# a table's rows, a width of the batch, where no key bound is set
+DEFAULT_BATCH_WIDTHS = 4
+
+
+def state_capacity(batch_capacity: int, maxgroups: Optional[int]) -> int:
+    """Rows a state table holds: the key bound ``process.maxgroups``
+    where the conf sets one, else ``DEFAULT_BATCH_WIDTHS`` batch widths.
+    The one rule the runtime and the device plan (analysis/deviceplan.py)
+    both price a table by."""
+    if maxgroups is not None and maxgroups >= 1:
+        return int(maxgroups)
+    return int(batch_capacity) * DEFAULT_BATCH_WIDTHS
 
 
 @dataclass
@@ -252,12 +270,13 @@ class StateTable:
         valid[: min(n_rows, self.capacity)] = True
         return TableData(cols, jnp.asarray(valid))
 
-    def overwrite(self, table: TableData, dictionary: StringDictionary) -> None:
+    def overwrite(self, table: TableData, dictionary: StringDictionary) -> int:
         """Write new state into each owned partition's standby side and
-        flip in memory (StateTableHandler.scala:99-115, per partition).
-        Rows hash onto partitions by the key column; rows of un-owned
-        partitions are NOT persisted here (a key-routed ingest never
-        produces them — see ``process.state.filteringest``)."""
+        flip in memory (StateTableHandler.scala:99-115, per partition);
+        returns the bytes written. Rows hash onto partitions by the key
+        column; rows of un-owned partitions are NOT persisted here (a
+        key-routed ingest never produces them — see
+        ``process.state.filteringest``)."""
         cols = {k: np.asarray(v) for k, v in table.cols.items()}
         valid = np.asarray(table.valid)
         pids = partition_ids(
@@ -267,6 +286,7 @@ class StateTable:
         string_cols = [
             c for c, t in self.schema.types.items() if t == "string"
         ]
+        written = 0
         for p in self.owned:
             member = valid & (pids == p)
             idx = np.nonzero(member)[0]
@@ -289,8 +309,10 @@ class StateTable:
             }
             side = other_side(self._active.get(p, "A"))
             self._local.put_files(self._prefix(p), side, files)
+            written += sum(map(len, files.values()))
             self._active[p] = side  # flip in memory; persist() commits
             self._pending[p] = side
+        return written
 
     def persist(self) -> None:
         """Commit the pointers after outputs succeed

@@ -740,6 +740,31 @@ def test_a_pass_that_cannot_end_by_the_deadline_does_not_start(tmp_path):
         host.stop()
 
 
+@pytest.mark.parametrize("slow", [
+    (1 << 20, 10.0),  # by it no pass fits in the whole wait
+    (1 << 10, 0.1),   # the wait wakes, and every pass is too long for it
+])
+def test_a_wait_that_makes_no_pass_measures_anew(tmp_path, slow):
+    """One slow pass must not keep every later wait from making one: a
+    wait in which, by the passes before it, no pass could end by the
+    deadline forgets them, and the next wait makes passes again."""
+    host, src, _sink, _metrics = _host(tmp_path)
+    passes = []
+    try:
+        host.processor.decode_ahead = lambda *a, **kw: passes.append(a) or True
+        conn = socket.create_connection(("127.0.0.1", src.port), 5.0)
+        conn.sendall(b"".join(_payload(range(1000))))
+        _wait_rows(src, 1000)
+        host._ahead_passes.append(slow)
+        host._pace(time.time() + 0.3)
+        assert passes == [] and not host._ahead_passes
+        host._pace(time.time() + 0.3)
+        assert passes and host._ahead_passes
+        conn.close()
+    finally:
+        host.stop()
+
+
 def test_a_batch_off_the_packed_path_leaves_no_stale_ahead_stats(tmp_path):
     proc = _proc(tmp_path, 64)
     line = _event(1, BASE_MS + 5) + b"\n"

@@ -113,8 +113,11 @@ def test_engine_is_protocol_clean_with_pinned_inventory():
     assert pd["analyzedFiles"] == len(paths) >= 24
     # 30 since the window checkpoint writes per-slot partial aggregates
     # a slot at a time: the slot file's fsync and its durable replace
-    # (runtime/checkpoint.py _save_slots) are two DURABLE_WRITE sites
-    assert pd["effectEvents"] == 30
+    # (runtime/checkpoint.py _save_slots) are two DURABLE_WRITE sites.
+    # 32 since the state store's file put and pointer commit fence their
+    # renames inline (fsync, os.replace, directory fsync: three sites
+    # each, where an fsync and ``_durable_replace`` were two)
+    assert pd["effectEvents"] == 32
     assert pd["postCommitSites"] == 3
     assert pd["requeueUpstreamSites"] == 1
     # the rescale handoff rides along with the engine set
